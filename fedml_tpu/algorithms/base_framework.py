@@ -29,10 +29,9 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from fedml_tpu.parallel.compat import shard_map
 from fedml_tpu.comm.backend import CommBackend, NodeManager
 from fedml_tpu.comm.inproc import InprocBus
 from fedml_tpu.comm.message import (

@@ -53,9 +53,6 @@ JIT_TRANSFORMS = {
     "jax.experimental.pjit.pjit",
     "jax.shard_map",
     "jax.experimental.shard_map.shard_map",
-    # the package's version-tolerant shim — call sites import the
-    # transform from here, and they are jit roots all the same
-    "fedml_tpu.parallel.compat.shard_map",
     # the partition-rule engine's jit entry point (jax.jit with
     # NamedSharding annotations): every function compiled through the
     # sharding subsystem is a jit root for the purity scan too

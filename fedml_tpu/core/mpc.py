@@ -23,8 +23,6 @@ from functools import partial
 from typing import Sequence, Tuple
 
 import jax
-
-from fedml_tpu.parallel.compat import enable_x64
 import jax.numpy as jnp
 import numpy as np
 
@@ -64,7 +62,7 @@ def gen_lagrange_coeffs(
 
 # --- device-side bulk share arithmetic --------------------------------------
 #
-# All jnp work below runs under ``enable_x64()`` (compat shim): without the x64
+# All jnp work below runs under ``jax.enable_x64(True)``: without the x64
 # flag jnp silently truncates int64 → int32, which corrupts the field
 # math.  The context is entered per public call; compiled int64 kernels
 # are cached as usual.
@@ -88,7 +86,7 @@ def coeff_combine(U, X, p: int = DEFAULT_PRIME) -> jax.Array:
     the S share terms with a mod per step keeps every intermediate
     < 2⁶² + 2³¹ in int64.
     """
-    with enable_x64():
+    with jax.enable_x64(True):
         U = jnp.asarray(np.asarray(U), jnp.int64) % p
         X = jnp.asarray(np.asarray(X), jnp.int64) % p
         return _coeff_combine(U, X, p)
@@ -119,7 +117,7 @@ def bgw_encode(x: jax.Array, n: int, t: int, key: jax.Array,
     """Degree-t Shamir shares of ``x`` (field residues, any shape) for
     n parties at points α=1..n: share_i = Σ_k R_k·αᵢᵏ with R_0 = x
     (reference ``BGW_encoding:62-76``)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x = jnp.asarray(np.asarray(x), jnp.int64) % p
         R = jax.random.randint(key, (t,) + x.shape, 0, p, dtype=jnp.int64)
         coeffs = jnp.concatenate([x[None], R], axis=0)  # [t+1, ...]
@@ -147,7 +145,7 @@ def lcc_encode(x: jax.Array, n: int, k: int, t: int, key: jax.Array,
     """Split ``x`` (leading dim divisible by k) into k chunks + t random
     chunks, interpolate through β-points, evaluate at n α-points
     (reference ``LCC_encoding:110-135``).  Returns [n, m/k, ...]."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x = jnp.asarray(np.asarray(x), jnp.int64) % p
         m = x.shape[0]
         assert m % k == 0, f"leading dim {m} not divisible by K={k}"
@@ -182,7 +180,7 @@ def lcc_decode(shares: jax.Array, worker_idx: Sequence[int], n: int,
 def additive_shares(x: jax.Array, n: int, key: jax.Array,
                     p: int = DEFAULT_PRIME) -> jax.Array:
     """n shares summing to x mod p (reference ``Gen_Additive_SS:216-227``)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x = jnp.asarray(np.asarray(x), jnp.int64) % p
         r = jax.random.randint(key, (n - 1,) + tuple(x.shape), 0, p, dtype=jnp.int64)
         last = (x - r.sum(axis=0) % p) % p
@@ -191,7 +189,7 @@ def additive_shares(x: jax.Array, n: int, key: jax.Array,
 
 def field_sum(shares, p: int = DEFAULT_PRIME) -> jax.Array:
     """Σ over the leading axis, mod p (server-side share aggregation)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         s = jnp.asarray(np.asarray(shares), jnp.int64) % p
 
         def body(acc, row):

@@ -279,6 +279,9 @@ def _run_fedllm(cfg: ExperimentConfig, ds, t0, log_fn, metrics=None) -> dict:
             codec=codec, error_feedback=ef,
         )
         state = shard_state(state)
+        # the unsharded tree init left on the first device would
+        # otherwise stay there, whole, for the entire run
+        del variables
         mesh = rule_mesh
     elif cfg.tp_degree > 1:
         from fedml_tpu.parallel.gspmd import (
@@ -717,6 +720,9 @@ def _dispatch(cfg: ExperimentConfig, log_fn, metrics, t0) -> dict:
 
 
 def main(argv=None):
+    from fedml_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     cfg = parse_config(ExperimentConfig, argv)
     if cfg.algorithm not in ALGORITHMS:
         raise SystemExit(f"--algorithm must be one of {ALGORITHMS}")
@@ -724,6 +730,7 @@ def main(argv=None):
     setup_logging()
     from fedml_tpu.obs.jax_hooks import (install_jax_monitoring,
                                          record_device_memory)
+    from fedml_tpu.utils.device import device_report
 
     install_jax_monitoring()
     # pid suffix: two arms of a sweep launched in the same wall-clock
@@ -736,7 +743,10 @@ def main(argv=None):
     # context manager: the JSONL handle closes on EVERY exit path —
     # a crashed run still leaves a readable metrics.jsonl behind
     with MetricsLogger(run_dir=run_dir) as metrics:
-        metrics.log({"kind": "config",
+        # where it ran is part of the record: this entry point runs on
+        # any backend (tests use the CPU), so it reports, it does not
+        # refuse
+        metrics.log({"kind": "config", **device_report(),
                      **json.loads(config_to_json(cfg))})
         # log_fn=None: with INFO logging on, MetricsLogger already
         # surfaces every row on the console — print would double it

@@ -1,15 +1,20 @@
 """ctypes binding for the native row-gather packer (packer.cpp).
 
-Lazy build-and-cache: the shared library is compiled with the system
-``g++`` the first time it's needed and cached next to this file
-(rebuilt when packer.cpp is newer).  If no compiler is present or the
-build fails, ``gather_rows`` silently uses the numpy fallback — the
-native path is an optimization, never a requirement.
+The shared library is compiled from ``packer.cpp`` with the system
+``g++`` the first time it is needed and kept next to this file under a
+name that carries the sha256 of that source, so the library a process
+loads is always the one this checkout's source builds — never one left
+behind by another tree, an older source or a copy of the directory.
+Where no compiler is present or the build fails, ``gather_rows`` uses
+numpy and says so: one warning in the log, and ``native_status()`` for
+callers that report which implementation packed their cohorts.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -18,20 +23,29 @@ from typing import Optional
 
 import numpy as np
 
+logger = logging.getLogger("fedml_tpu")
+
 _HERE = Path(__file__).resolve().parent
 _SRC = _HERE / "packer.cpp"
-_LIB = _HERE / "_libpacker.so"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_fallback_reason = ""
 
 
-def _build() -> bool:
+def lib_path() -> Path:
+    """Where the library built from this checkout's ``packer.cpp`` lives."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _HERE / f"_libpacker-{digest}.so"
+
+
+def _build(lib: Path) -> str:
+    """Compile ``packer.cpp`` into ``lib``; returns "" or why it failed."""
     # compile to a process-unique temp path and rename into place:
     # concurrent processes (pytest-xdist, multi-process launches) must
     # never dlopen a partially-written .so
-    tmp = _LIB.with_suffix(f".tmp.{os.getpid()}.so")
+    tmp = lib.with_suffix(f".tmp.{os.getpid()}.so")
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-pthread",
         str(_SRC), "-o", str(tmp),
@@ -40,43 +54,76 @@ def _build() -> bool:
         subprocess.run(
             cmd, check=True, capture_output=True, timeout=120
         )
-        os.replace(tmp, _LIB)
-        return True
-    except (OSError, subprocess.SubprocessError):
+        os.replace(tmp, lib)
+    except FileNotFoundError:
+        return "g++ not found"
+    except subprocess.CalledProcessError as e:
         tmp.unlink(missing_ok=True)
-        return False
+        return f"g++ failed: {e.stderr.decode(errors='replace')[-300:]}"
+    except (OSError, subprocess.SubprocessError) as e:
+        tmp.unlink(missing_ok=True)
+        return f"build failed: {e}"
+    # libraries built from earlier versions of the source are dead weight
+    for old in _HERE.glob("_libpacker*.so"):
+        if old != lib and ".tmp." not in old.name:
+            old.unlink(missing_ok=True)
+    return ""
+
+
+def _open(path: Path) -> ctypes.CDLL:
+    """dlopen ``path`` — only under the name this checkout's source
+    hashes to."""
+    want = lib_path().name
+    if path.name != want:
+        raise ValueError(
+            f"{path.name} was not built from this checkout's packer.cpp "
+            f"(its library is {want})"
+        )
+    lib = ctypes.CDLL(str(path))
+    lib.gather_rows.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int32,
+    ]
+    lib.gather_rows.restype = None
+    return lib
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _fallback_reason
     with _lock:
         if _tried:
             return _lib
         _tried = True
         if os.environ.get("FEDML_TPU_NO_NATIVE"):
+            _fallback_reason = "FEDML_TPU_NO_NATIVE is set"
             return None
-        try:
-            stale = (not _LIB.exists()) or (
-                _SRC.stat().st_mtime > _LIB.stat().st_mtime
+        path = lib_path()
+        if not path.exists():
+            _fallback_reason = _build(path)
+        if not _fallback_reason:
+            try:
+                _lib = _open(path)
+            except OSError as e:
+                _fallback_reason = f"dlopen failed: {e}"
+        if _fallback_reason:
+            logger.warning(
+                "native packer unavailable (%s): packing with numpy",
+                _fallback_reason,
             )
-            if stale and not _build():
-                return None
-            lib = ctypes.CDLL(str(_LIB))
-            lib.gather_rows.argtypes = [
-                ctypes.c_char_p, ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_int32,
-            ]
-            lib.gather_rows.restype = None
-            _lib = lib
-        except OSError:
-            _lib = None
         return _lib
 
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def native_status() -> str:
+    """Which implementation ``gather_rows`` runs, and why if not native."""
+    if _load() is not None:
+        return f"native ({lib_path().name})"
+    return f"numpy ({_fallback_reason})"
 
 
 def gather_rows(

@@ -35,6 +35,10 @@ into the output cotangent analytically (d sum → broadcast, d sumsq →
 2·y) before the transpose convs run.  A Pallas dgrad/wgrad pair is the
 follow-up once the forward has a measured win.
 
+On a TPU the kernel is compiled by Mosaic and nothing runs in its place
+if Mosaic refuses it (``chip_smoke.py`` leg ``conv_mxu`` compiles every
+shape ResNet-56 uses and compares with ``_xla_conv3x3``).
+
 CPU/testing: ``interpret=None`` auto-selects Pallas interpret mode off
 the TPU backend (the ``ops/flash_attention.py`` precedent), so the full
 parity suite (``tests/test_conv_mxu.py``) runs in tier-1 on CPU and the
@@ -76,25 +80,29 @@ def _conv_kernel(x_ref, w_ref, mul_ref, add_ref, *out_refs, stride: int,
     [9·Cin, Cout] kernel, apply the affine(+ReLU) epilogue, and emit the
     block's per-channel moment partials.
 
-    The tap gather is a strided ``lax.slice`` of the VMEM-resident
-    padded block — stride 1 for the dense stages; stride 2 reads the
-    even-center windows of the baseline's explicit-padding convention
-    (out[i] ← padded rows 2i..2i+2), so the stride-2 stage transitions
-    compute the identical function."""
+    The tap gather is a unit-stride ``lax.slice`` of one PHASE of the
+    VMEM-resident padded block.  Mosaic has no strided slice of a value
+    (``vector.extract_strided_slice``: strides confined to [1, 2)), so
+    the wrapper splits the padded image into its stride² phases —
+    ``x_ref[:, py·s+px][a, b] = x_pad[s·a+py, s·b+px]`` — and tap
+    (ty, tx) reads phase (ty % s, tx % s) at offset (ty // s, tx // s).
+    Stride 1 is the one-phase case; stride 2 reads the even-center
+    windows of the baseline's explicit-padding convention (out[i] ←
+    padded rows 2i..2i+2), so the stride-2 stage transitions compute
+    the identical function."""
     if moments:
         o_ref, sum_ref, sq_ref, patch = out_refs
     else:
         o_ref, patch = out_refs
     bn, ho, wo, co = o_ref.shape
     ci = x_ref.shape[-1]
-    xb = x_ref[:]                                   # (bn, H+2, W+2, Ci)
+    phases = [x_ref[:, p] for p in range(stride * stride)]
     for t in range(9):
         ty, tx = divmod(t, 3)
+        oy, ox = ty // stride, tx // stride
         tap = jax.lax.slice(
-            xb,
-            (0, ty, tx, 0),
-            (bn, ty + stride * (ho - 1) + 1, tx + stride * (wo - 1) + 1, ci),
-            (1, stride, stride, 1),
+            phases[(ty % stride) * stride + tx % stride],
+            (0, oy, ox, 0), (bn, oy + ho, ox + wo, ci),
         )                                           # (bn, Ho, Wo, Ci)
         patch[:, t * ci:(t + 1) * ci] = tap.reshape(bn * ho * wo, ci)
     acc = jax.lax.dot_general(
@@ -111,8 +119,8 @@ def _conv_kernel(x_ref, w_ref, mul_ref, add_ref, *out_refs, stride: int,
         # exactly the values train-mode BatchNorm reduces over, so the
         # fp32 stats match the baseline's astype(float32) reduction
         yf = yc.astype(jnp.float32)
-        sum_ref[:] = jnp.sum(yf, axis=0, keepdims=True)
-        sq_ref[:] = jnp.sum(yf * yf, axis=0, keepdims=True)
+        sum_ref[0] = jnp.sum(yf, axis=0, keepdims=True)
+        sq_ref[0] = jnp.sum(yf * yf, axis=0, keepdims=True)
 
 
 def conv3x3_mxu(x, w, *, stride: int = 1, mul=None, add=None,
@@ -147,6 +155,11 @@ def conv3x3_mxu(x, w, *, stride: int = 1, mul=None, add=None,
     m = block_n * ho * wo
 
     x_pad = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+    # (N, H+2, W+2, Ci) → (N, s², (H+2)/s, (W+2)/s, Ci): phase py·s+px
+    # holds rows py, py+s, … and columns px, px+s, … (see _conv_kernel)
+    hp, wp = (h + 2) // stride, (wdim + 2) // stride
+    x_ph = x_pad.reshape(n, hp, stride, wp, stride, ci).transpose(
+        0, 2, 4, 1, 3, 5).reshape(n, stride * stride, hp, wp, ci)
     # (3, 3, Ci, Co) → (9·Ci, Co): row t·Ci+c is tap (ty, tx)=divmod(t,3),
     # input channel c — the exact column order the tap gather writes
     w2 = w.astype(x.dtype).reshape(9 * ci, co)
@@ -163,8 +176,11 @@ def conv3x3_mxu(x, w, *, stride: int = 1, mul=None, add=None,
     out_specs = [pl.BlockSpec((block_n, ho, wo, co),
                               lambda g: (g, 0, 0, 0))]
     if moments:
-        out_shape += [jax.ShapeDtypeStruct((grid[0], co), jnp.float32)] * 2
-        out_specs += [pl.BlockSpec((1, co), lambda g: (g, 0))] * 2
+        # one (1, Co) row per grid step, as a (G, 1, Co) array: a block's
+        # last two dims must be whole tiles or the array's own, and
+        # (1, Co) of a (G, Co) array is neither
+        out_shape += [jax.ShapeDtypeStruct((grid[0], 1, co), jnp.float32)] * 2
+        out_specs += [pl.BlockSpec((1, 1, co), lambda g: (g, 0, 0))] * 2
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
@@ -174,8 +190,8 @@ def conv3x3_mxu(x, w, *, stride: int = 1, mul=None, add=None,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_n, h + 2, wdim + 2, ci),
-                         lambda g: (g, 0, 0, 0)),
+            pl.BlockSpec((block_n, stride * stride, hp, wp, ci),
+                         lambda g: (g, 0, 0, 0, 0)),
             pl.BlockSpec((9 * ci, co), lambda g: (0, 0)),
             pl.BlockSpec((1, co), lambda g: (0, 0)),
             pl.BlockSpec((1, co), lambda g: (0, 0)),
@@ -185,10 +201,10 @@ def conv3x3_mxu(x, w, *, stride: int = 1, mul=None, add=None,
         scratch_shapes=[pltpu.VMEM((m, 9 * ci), x.dtype)],
         interpret=interpret,
         **kwargs,
-    )(x_pad, w2, mul_arr, add_arr)
+    )(x_ph, w2, mul_arr, add_arr)
     if moments:
         y, s, sq = out
-        return y, s.sum(axis=0), sq.sum(axis=0)
+        return y, s.sum(axis=(0, 1)), sq.sum(axis=(0, 1))
     return out[0]
 
 

@@ -40,15 +40,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from fedml_tpu.parallel.compat import shard_map
 from fedml_tpu.algorithms.fedavg import ServerState, make_round_fn
 from fedml_tpu.core.client import make_local_update
 from fedml_tpu.core.losses import LossFn, masked_softmax_ce
 from fedml_tpu.models.base import ModelBundle
 from fedml_tpu.models.transformer import TransformerLM
+from fedml_tpu.parallel.mesh import device_grid
 
 PyTree = Any
 
@@ -56,15 +56,8 @@ PyTree = Any
 def make_dp_sp_mesh(
     n_clients_axis: int, n_sp: int, *, devices=None
 ) -> Mesh:
-    devices = devices if devices is not None else jax.devices()
-    n = n_clients_axis * n_sp
-    if n > len(devices):
-        raise ValueError(
-            f"mesh {n_clients_axis}x{n_sp} needs {n} devices, "
-            f"have {len(devices)}"
-        )
-    arr = np.array(devices[:n]).reshape(n_clients_axis, n_sp)
-    return Mesh(arr, axis_names=("clients", "sp"))
+    return Mesh(device_grid((n_clients_axis, n_sp), devices),
+                axis_names=("clients", "sp"))
 
 
 def pmean_gradients(axis: str) -> optax.GradientTransformation:

@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fedml_tpu.algorithms.fedavg import ServerState, make_round_fn
 from fedml_tpu.core.client import LocalUpdateFn
+from fedml_tpu.parallel.mesh import device_grid
 from fedml_tpu.parallel.tensor import tp_param_spec
 
 PyTree = Any
@@ -34,15 +35,8 @@ PyTree = Any
 def make_dp_tp_mesh(
     n_clients_axis: int, n_model_axis: int, *, devices=None
 ) -> Mesh:
-    devices = devices if devices is not None else jax.devices()
-    n = n_clients_axis * n_model_axis
-    if n > len(devices):
-        raise ValueError(
-            f"mesh {n_clients_axis}x{n_model_axis} needs {n} devices, "
-            f"have {len(devices)}"
-        )
-    arr = np.array(devices[:n]).reshape(n_clients_axis, n_model_axis)
-    return Mesh(arr, axis_names=("clients", "model"))
+    return Mesh(device_grid((n_clients_axis, n_model_axis), devices),
+                axis_names=("clients", "model"))
 
 
 def opt_state_sharding_like(

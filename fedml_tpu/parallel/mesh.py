@@ -97,22 +97,36 @@ def parse_mesh_spec(
     return dp, mp
 
 
-def make_dp_mp_mesh(dp: int, mp: int, *, devices: Optional[Sequence] = None):
-    """A ``Mesh`` with axes ``("dp", "mp")`` over the first dp*mp
-    devices.  Raises loud — with the host-mesh hint — when the
-    platform doesn't have enough."""
+def device_grid(shape: Sequence[int], devices: Optional[Sequence] = None):
+    """The first ``prod(shape)`` devices arranged as ``shape`` — the one
+    place every mesh constructor in ``parallel/`` gets its device array.
+
+    On a TPU the arrangement follows the host's physical topology
+    (``mesh_utils.create_device_mesh``: on a 2x2 v5e host a 1-D axis
+    becomes the ring 0-1-3-2, so neighbours on the axis are neighbours
+    on the interconnect); ``jax.devices()`` list order does not.  On
+    other platforms it is list order.  Raises — with the host-mesh
+    hint — when the platform doesn't have enough devices."""
     import jax
-    from jax.sharding import Mesh
+    from jax.experimental import mesh_utils
 
     devices = list(devices) if devices is not None else jax.devices()
-    n = dp * mp
+    n = int(np.prod(shape))
     if n > len(devices):
         raise ValueError(
-            f"mesh {dp}x{mp} needs {n} devices, have {len(devices)} "
-            f"({HOST_MESH_HINT})"
+            f"mesh {'x'.join(map(str, shape))} needs {n} devices, have "
+            f"{len(devices)} ({HOST_MESH_HINT})"
         )
-    arr = np.array(devices[:n]).reshape(dp, mp)
-    return Mesh(arr, axis_names=(DP_AXIS, MP_AXIS))
+    return mesh_utils.create_device_mesh(tuple(shape), devices[:n])
+
+
+def make_dp_mp_mesh(dp: int, mp: int, *, devices: Optional[Sequence] = None):
+    """A ``Mesh`` with axes ``("dp", "mp")`` over the first dp*mp
+    devices (``device_grid``)."""
+    from jax.sharding import Mesh
+
+    return Mesh(device_grid((dp, mp), devices),
+                axis_names=(DP_AXIS, MP_AXIS))
 
 
 def mesh_from_spec(spec: str, *, devices: Optional[Sequence] = None):

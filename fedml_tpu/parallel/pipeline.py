@@ -25,10 +25,8 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from fedml_tpu.parallel.compat import shard_map
 
 PyTree = Any
 

@@ -18,10 +18,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from fedml_tpu.parallel.compat import shard_map
 
 from fedml_tpu.models.transformer import TransformerLM
 from fedml_tpu.parallel.ring_attention import (ring_attention,

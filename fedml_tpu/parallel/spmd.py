@@ -18,11 +18,12 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from fedml_tpu.parallel.compat import shard_map
 from fedml_tpu.algorithms.fedavg import make_round_fn
 from fedml_tpu.core.client import LocalUpdateFn
+from fedml_tpu.parallel.mesh import device_grid
 
 PyTree = Any
 
@@ -30,14 +31,8 @@ PyTree = Any
 def make_1d_mesh(n_devices: Optional[int] = None, axis: str = "x") -> Mesh:
     """1-D mesh over the first n devices (shared by the tp/pp/sp/ep
     constructors)."""
-    devices = jax.devices()
-    if n_devices is not None:
-        if n_devices > len(devices):
-            raise ValueError(
-                f"requested {n_devices} devices, only {len(devices)} present"
-            )
-        devices = devices[:n_devices]
-    return Mesh(np.array(devices), (axis,))
+    n = n_devices if n_devices is not None else jax.device_count()
+    return Mesh(device_grid((n,)), (axis,))
 
 
 def make_client_mesh(
@@ -45,12 +40,11 @@ def make_client_mesh(
 ) -> Mesh:
     """Mesh with a ``clients`` data axis and a reserved ``model`` axis."""
     devices = devices if devices is not None else jax.devices()
-    if num_devices is not None:
-        devices = devices[:num_devices]
-    n = len(devices)
-    assert n % model_axis == 0
-    arr = np.array(devices).reshape(n // model_axis, model_axis)
-    return Mesh(arr, axis_names=("clients", "model"))
+    n = num_devices if num_devices is not None else len(devices)
+    if n % model_axis:
+        raise ValueError(f"{n} devices not divisible by model axis {model_axis}")
+    return Mesh(device_grid((n // model_axis, model_axis), devices),
+                axis_names=("clients", "model"))
 
 
 def make_spmd_round_fn(
@@ -101,9 +95,17 @@ def make_spmd_round_fn(
 
 
 def shard_client_block(mesh: Mesh, pack_arrays):
-    """device_put packed [C, ...] arrays sharded over the clients axis."""
+    """device_put packed [C, ...] arrays sharded over the clients axis.
+
+    Host arrays go from host memory straight to their shards:
+    ``jnp.asarray`` first would commit the whole block to device 0 and
+    re-shard from there, which a cohort sized for the mesh may not fit."""
     sharding = NamedSharding(mesh, P("clients"))
-    return tuple(jax.device_put(jnp.asarray(a), sharding) for a in pack_arrays)
+    return tuple(
+        jax.device_put(a if isinstance(a, jax.Array) else np.asarray(a),
+                       sharding)
+        for a in pack_arrays
+    )
 
 
 def _devices_by_clients_index(mesh: Mesh):
@@ -213,7 +215,8 @@ def shard_client_block_local(
             if entry is None:
                 continue  # another host's range (its process supplies it)
             arrays, off = entry
-            piece = jnp.asarray(np.asarray(arrays[j])[off : off + block])
+            # a host array: device_put copies it to each owner directly
+            piece = np.asarray(arrays[j])[off : off + block]
             sample = piece
             for d in dev_rows[i]:
                 buffers.append(jax.device_put(piece, d))
@@ -239,14 +242,11 @@ def replicate(mesh: Mesh, tree: PyTree) -> PyTree:
 def make_group_mesh(num_groups: int, n_devices: Optional[int] = None) -> Mesh:
     """Nested mesh for two-tier FL: ``group`` (slow axis — slices/DCN)
     × ``clients`` (fast axis — chips within a slice/ICI)."""
-    devices = jax.devices()
-    if n_devices is not None:
-        devices = devices[:n_devices]
-    n = len(devices)
+    n = n_devices if n_devices is not None else jax.device_count()
     if n % num_groups:
         raise ValueError(f"{n} devices not divisible into {num_groups} groups")
-    arr = np.array(devices).reshape(num_groups, n // num_groups)
-    return Mesh(arr, axis_names=("group", "clients"))
+    return Mesh(device_grid((num_groups, n // num_groups)),
+                axis_names=("group", "clients"))
 
 
 def hierarchical_pack(dataset, groups, batch_size, steps_per_epoch, seed):

@@ -320,7 +320,6 @@ def test_forensics_two_fault_bundle_yields_both_verdicts(tmp_path):
 
 def _fed_env():
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     return env

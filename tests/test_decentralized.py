@@ -4,8 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
-from fedml_tpu.parallel.compat import shard_map
 from fedml_tpu.algorithms.decentralized import (
     DecentralizedSimulation,
     dense_mix,

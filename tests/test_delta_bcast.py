@@ -288,7 +288,6 @@ def test_resync_recovery_preserves_chain_byte_identity():
 
 def _fed_env():
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     return env

@@ -21,7 +21,6 @@ from fedml_tpu.experiments.distributed_fedavg import _build_problem, launch
 def test_multiprocess_federation_matches_simulation(tmp_path):
     out = str(tmp_path / "final.npz")
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     # keep the children lean: no faked multi-device mesh needed
     env["XLA_FLAGS"] = ""
@@ -62,7 +61,6 @@ def test_sampled_client_death_deadline_matches_masked_simulation(tmp_path):
     (server_manager.py:55-58)."""
     out = str(tmp_path / "final_straggler.npz")
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     # round_timeout bounds each round (the dead client never uploads, so
@@ -124,7 +122,6 @@ def test_hub_killed_and_restarted_federation_survives(tmp_path):
     model and at least one fully-participating round after recovery."""
     out = str(tmp_path / "final_hub_restart.npz")
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     info = {}
@@ -148,3 +145,56 @@ def test_hub_killed_and_restarted_federation_survives(tmp_path):
     assert info.get("rounds") == 3
     # the server's own reconnect is visible in its fault counters
     assert info.get("faults", {}).get("comm.reconnects", 0) >= 1
+
+
+def test_launch_pins_every_host_role_to_cpu_and_refuses_two_chip_muxers(
+        tmp_path, monkeypatch):
+    """One process for each chip, by construction: launch() starts hub,
+    edge hub, per-process client and server with JAX_PLATFORMS=cpu and
+    only the muxer inherits the caller's backend.  Inspects the
+    environments of the processes it WOULD start — nothing is spawned."""
+    import io
+
+    from fedml_tpu.experiments import distributed_fedavg as dfa
+
+    started = []
+
+    class FakeProc:
+        pid, returncode = 0, 0
+
+        def __init__(self, cmd, env=None, **_):
+            started.append((cmd[cmd.index("--role") + 1], dict(env)))
+            self.stdout = io.StringIO(
+                '{"hub_port": 1, "edge_port": 2}\n')
+
+        def wait(self, timeout=None):
+            return 0
+
+        def poll(self):
+            return 0
+
+        def communicate(self, timeout=None):
+            return "", None
+
+    monkeypatch.setattr(dfa.subprocess, "Popen", FakeProc)
+    caller = {"PATH": os.environ["PATH"], "JAX_PLATFORMS": "tpu,cpu"}
+    rc = dfa.launch(num_clients=3, rounds=1, out_path=str(tmp_path / "o.npz"),
+                    muxers=1, muxed_clients=2, topology="tree", edge_hubs=1,
+                    env=caller, info={})
+    assert rc == 0
+    roles = [r for r, _ in started]
+    assert sorted(roles) == ["client", "edge_hub", "hub", "muxer", "server"]
+    for role, env in started:
+        want = "tpu,cpu" if role == "muxer" else "cpu"
+        assert env["JAX_PLATFORMS"] == want, (role, env["JAX_PLATFORMS"])
+        assert dfa.role_env(role, caller)["JAX_PLATFORMS"] == want
+    # two muxers asking for the one accelerator: refused at launch,
+    # before anything starts; on the CPU any number may run
+    del started[:]
+    with pytest.raises(ValueError, match="a chip belongs to one process"):
+        dfa.launch(num_clients=4, rounds=1, out_path=str(tmp_path / "o.npz"),
+                   muxers=2, env=caller)
+    assert started == []
+    dfa.launch(num_clients=4, rounds=1, out_path=str(tmp_path / "o.npz"),
+               muxers=2, env={**caller, "JAX_PLATFORMS": "cpu"})
+    assert [r for r, _ in started].count("muxer") == 2

@@ -25,7 +25,6 @@ from fedml_tpu.core import tree as treelib
 
 def _fed_env():
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     return env

@@ -466,7 +466,6 @@ def test_crashed_client_leaves_parseable_bundle_ci_pin(tmp_path):
     from fedml_tpu.experiments.distributed_fedavg import launch
 
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     rc = launch(num_clients=3, rounds=3, seed=0, batch_size=16,

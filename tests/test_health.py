@@ -453,7 +453,6 @@ def test_multiprocess_federation_stats_plane(tmp_path):
 
     out = str(tmp_path / "final.npz")
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     info = {}

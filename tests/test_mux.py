@@ -336,7 +336,6 @@ def test_per_virtual_node_chaos_decisions_on_shared_conn():
 
 def _fed_env():
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     return env

@@ -99,3 +99,18 @@ def test_pack_clients_rejects_out_of_range_indices():
     ds.train_client_idx[1] = np.array([0, 5, 999])  # 999 >= 100
     with pytest.raises(IndexError):
         pack_clients(ds, [0, 1], batch_size=4)
+
+
+def test_library_is_tied_to_the_source_by_content(tmp_path):
+    """The library's name carries packer.cpp's hash, and a library under
+    any other name — an older source's, another tree's, the pre-hash
+    ``_libpacker.so`` — is refused before dlopen."""
+    import hashlib
+
+    want = packer_mod.lib_path()
+    digest = hashlib.sha256(packer_mod._SRC.read_bytes()).hexdigest()[:16]
+    assert want.name == f"_libpacker-{digest}.so"
+    for stale in ("_libpacker.so", "_libpacker-0123456789abcdef.so"):
+        with pytest.raises(ValueError, match="not built from this checkout"):
+            packer_mod._open(tmp_path / stale)
+    assert packer_mod.native_status().startswith("native (")

@@ -223,6 +223,63 @@ def test_record_device_memory_none_guarded():
     record_device_memory(Telemetry())
 
 
+# --- where the compile cache goes, and which device a run is on --------------
+
+def test_compile_cache_env_wins_else_fixed_path_in_checkout(monkeypatch):
+    import jax
+
+    from fedml_tpu.utils import compile_cache as cc
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    # set from outside: the directory is left alone, no other is set in code
+    monkeypatch.setenv(cc.ENV_VAR, "/some/dir")
+    assert cc.configure_compile_cache(0.0) == "/some/dir"
+    assert updates == [("jax_persistent_cache_min_compile_time_secs", 0.0)]
+    # not set: one fixed path inside the checkout
+    del updates[:]
+    monkeypatch.delenv(cc.ENV_VAR)
+    fixed = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    assert cc.configure_compile_cache() == fixed
+    assert updates == [("jax_compilation_cache_dir", fixed),
+                       ("jax_persistent_cache_min_compile_time_secs", 2.0)]
+
+
+def test_require_tpu_raises_on_cpu_and_report_names_the_device():
+    from fedml_tpu.utils.device import device_report, require_tpu
+
+    with pytest.raises(RuntimeError, match="platform='cpu'"):
+        require_tpu()
+    report = device_report()
+    assert report["platform"] == "cpu" and report["device_count"] >= 8
+    assert set(report) == {"platform", "device_kind", "device_count"}
+
+
+def test_bench_peak_table_raises_on_unknown_device_kind():
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    try:
+        import bench
+    finally:
+        sys.path.pop(0)
+    assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
+    with pytest.raises(KeyError, match="no bf16 peak recorded"):
+        bench.peak_bf16_flops("cpu")
+
+
+def test_chip_smoke_without_a_chip_fails_and_names_the_platform():
+    """No chip, no rehearsal argument: non-zero exit, the platform it
+    found in the message, and no result line."""
+    repo = Path(__file__).resolve().parents[1]
+    out = subprocess.run(
+        [sys.executable, str(repo / "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0
+    assert "found platform=cpu" in out.stderr + out.stdout
+    assert '"ok"' not in out.stdout and "leg resnet56" not in out.stdout
+
+
 # --- end-to-end: simulation emits, trace_summary reads -----------------------
 
 def _tiny_sim(tmp_path, telemetry):

@@ -6,9 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from fedml_tpu.parallel.compat import shard_map
 
 from fedml_tpu.parallel.ring_attention import (blockwise_attention,
                                                dense_attention,

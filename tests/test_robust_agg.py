@@ -578,7 +578,6 @@ def test_defended_federation_muxed_vs_per_process_identical(tmp_path):
     from fedml_tpu.experiments.distributed_fedavg import launch
 
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     digests = {}
     for name, muxers in (("proc", 0), ("mux", 2)):

@@ -3,9 +3,8 @@ weak-scaling ladder must run end-to-end on the faked CPU mesh and emit
 well-formed efficiency points, and the clients-mode fused driver must
 report throughput per point.
 
-The conftest already forces the 8-device CPU mesh, so the harness's own
---platform cpu env mutation is a no-op here and its jax.config update is
-idempotent.
+The conftest already forces the 8-device CPU mesh (jax is started), so
+the harness's own --platform cpu env mutation is a no-op here.
 """
 
 import json

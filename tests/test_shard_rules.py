@@ -167,7 +167,6 @@ def test_parse_mesh_spec_forms():
 
 def _spawn_child(child, devices, **kw):
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}"
@@ -231,7 +230,6 @@ def test_muxed_host_mesh_federation_byte_identical_to_per_process(tmp_path):
 
     def env(devices):
         e = dict(os.environ)
-        e["FEDML_TPU_FORCE_CPU"] = "1"
         e["JAX_PLATFORMS"] = "cpu"
         e["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={devices}"
@@ -247,7 +245,7 @@ def test_muxed_host_mesh_federation_byte_identical_to_per_process(tmp_path):
         out = str(tmp_path / f"{tag}.npz")
         info = {}
         rc = launch(num_clients=8, rounds=2, seed=0, batch_size=16,
-                    out_path=out, env=env(devices), server_env=env(1),
+                    out_path=out, env=env(devices),
                     info=info, timeout=300.0, **kw)
         assert rc == 0, tag
         z = np.load(out)

@@ -52,7 +52,7 @@ def main():
     from fedml_tpu.experiments.distributed_fedavg import launch
 
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
 
     def run_one(tag, codec, wire):
@@ -64,7 +64,7 @@ def main():
             out_path=f"/tmp/compress_fed_{tag}.npz",
             round_timeout=args.round_timeout,
             codec=codec, wire=wire, input_dim=args.input_dim,
-            info=info, env=env, server_env=env,
+            info=info, env=env,
             timeout=300.0 + args.rounds * args.round_timeout,
         )
         if rc != 0:

@@ -59,7 +59,6 @@ ENV_HUB_MODE = "FEDML_TPU_HUB_MODE"
 
 def _env(mode: str):
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     env[ENV_HUB_MODE] = mode
@@ -110,7 +109,7 @@ def _one(tag, mode, *, clients, rounds, seed, input_dim, train_samples,
     t0 = time.time()
     rc = launch(
         num_clients=clients, rounds=rounds, seed=seed, batch_size=16,
-        out_path=out, env=_env(mode), server_env=_env(mode), info=info,
+        out_path=out, env=_env(mode), info=info,
         timeout=timeout, round_timeout=round_timeout,
         input_dim=input_dim, train_samples=train_samples,
         lane=lane, bcast=bcast, codec=codec, muxers=muxers,

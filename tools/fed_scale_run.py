@@ -53,7 +53,6 @@ from tools.trace_summary import percentile  # noqa: E402
 
 def _env():
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     return env
@@ -420,7 +419,7 @@ def run_ab(args) -> dict:
             round_timeout=args.round_timeout,
             codec="none", wire=2, input_dim=args.input_dim,
             hotpath=hotpath, train_samples=args.train_samples,
-            muxers=muxers, env=env, server_env=env,
+            muxers=muxers, env=env,
             timeout=600.0 + args.ab_rounds * args.round_timeout,
         )
         if rc != 0:

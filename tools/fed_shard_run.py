@@ -58,7 +58,6 @@ _SEQ = 16
 
 def _child_env(devices: int) -> dict:
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}"
@@ -400,7 +399,7 @@ def run_mux_pin(args) -> dict:
             rc = launch(
                 num_clients=args.mux_clients, rounds=args.mux_rounds,
                 seed=args.seed, batch_size=16, out_path=out,
-                env=_child_env(devices), server_env=_child_env(1),
+                env=_child_env(devices),
                 info=info, timeout=args.timeout, **kw,
             )
             z = np.load(out)

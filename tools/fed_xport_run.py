@@ -47,7 +47,6 @@ from tools.trace_summary import percentile  # noqa: E402
 
 def _env():
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     return env
@@ -97,7 +96,7 @@ def _one(tag, *, clients, rounds, seed, input_dim, train_samples,
     t0 = time.time()
     rc = launch(
         num_clients=clients, rounds=rounds, seed=seed, batch_size=16,
-        out_path=out, env=_env(), server_env=_env(),
+        out_path=out, env=_env(),
         info=info if collect_info else None,
         timeout=timeout, round_timeout=round_timeout,
         input_dim=input_dim, train_samples=train_samples,

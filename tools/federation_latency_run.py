@@ -82,7 +82,7 @@ def main():
     from fedml_tpu.experiments.distributed_fedavg import launch
 
     env = dict(os.environ)
-    env["FEDML_TPU_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ""
     log_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)), "logs")
     os.makedirs(log_dir, exist_ok=True)
@@ -97,7 +97,7 @@ def main():
             round_timeout=args.round_timeout,
             codec=codec, wire=2, input_dim=args.input_dim,
             hotpath=hotpath, train_samples=args.train_samples,
-            info=info, env=env, server_env=env,
+            info=info, env=env,
             timeout=600.0 + args.rounds * args.round_timeout,
         )
         if rc != 0:

@@ -40,6 +40,7 @@ from fedml_tpu.core.types import (
     pack_clients,
 )
 from fedml_tpu.models.base import ModelBundle
+from fedml_tpu.obs import scopes
 
 PyTree = Any
 
@@ -135,154 +136,165 @@ def make_round_fn(
         from fedml_tpu.compress import COMPRESS_STREAM, roundtrip_tree
 
     def round_fn(state: ServerState, x, y, mask, num_samples, participation, slot_ids):
-        # slot_ids are GLOBAL client slot indices — under shard_map each
-        # device sees only its local block, so a local arange would collide
-        # RNG streams across devices.  Two independent sub-streams per
-        # round (training vs aggregation noise) so per-client keys never
-        # collide across uses.
-        k_round = jax.random.fold_in(state.key, state.round_idx)
-        k_train = jax.random.fold_in(k_round, 0)
-        k_agg = jax.random.fold_in(k_round, 1)
-        client_rngs = jax.vmap(lambda i: jax.random.fold_in(k_train, i))(slot_ids)
-        # Model sync = SPMD replication (no explicit send).  Client-axis
-        # mapping: sequential lax.map keeps each client's convs at full
-        # MXU tile sizes (measured ~7x faster than vmap for ResNet-56 on
-        # one v5e chip); vmap remains available for many tiny clients.
-        run_one = lambda cx, cy, cm, ck: local_update(state.variables, cx, cy, cm, ck)
-        if client_axis_impl == "vmap":
-            client_vars, client_metrics = jax.vmap(run_one)(x, y, mask, client_rngs)
-        elif client_unroll > 1:
-            # lax.map is scan-without-carry; express it as such to get
-            # scan's unroll knob (lax.map grew batch_size, not unroll)
-            client_vars, client_metrics = jax.lax.scan(
-                lambda c, args: (c, run_one(*args)),
-                (), (x, y, mask, client_rngs), unroll=client_unroll,
-            )[1]
-        else:
-            client_vars, client_metrics = jax.lax.map(
-                lambda args: run_one(*args), (x, y, mask, client_rngs)
+        with jax.named_scope(scopes.ROUND):
+            # slot_ids are GLOBAL client slot indices — under shard_map each
+            # device sees only its local block, so a local arange would collide
+            # RNG streams across devices.  Two independent sub-streams per
+            # round (training vs aggregation noise) so per-client keys never
+            # collide across uses.
+            k_round = jax.random.fold_in(state.key, state.round_idx)
+            k_train = jax.random.fold_in(k_round, 0)
+            k_agg = jax.random.fold_in(k_round, 1)
+            client_rngs = jax.vmap(lambda i: jax.random.fold_in(k_train, i))(slot_ids)
+            # Model sync = SPMD replication (no explicit send).  Client-axis
+            # mapping: sequential lax.map keeps each client's convs at full
+            # MXU tile sizes (measured ~7x faster than vmap for ResNet-56 on
+            # one v5e chip); vmap remains available for many tiny clients.
+            def run_one(cx, cy, cm, ck):
+                with jax.named_scope(scopes.LOCAL_UPDATE):
+                    return local_update(state.variables, cx, cy, cm, ck)
+
+            with jax.named_scope(scopes.CLIENTS):
+                if client_axis_impl == "vmap":
+                    client_vars, client_metrics = jax.vmap(run_one)(x, y, mask, client_rngs)
+                elif client_unroll > 1:
+                    # lax.map is scan-without-carry; express it as such to get
+                    # scan's unroll knob (lax.map grew batch_size, not unroll)
+                    client_vars, client_metrics = jax.lax.scan(
+                        lambda c, args: (c, run_one(*args)),
+                        (), (x, y, mask, client_rngs), unroll=client_unroll,
+                    )[1]
+                else:
+                    client_vars, client_metrics = jax.lax.map(
+                        lambda args: run_one(*args), (x, y, mask, client_rngs)
+                    )
+
+            residuals = state.residuals
+            if codec is not None:
+                with jax.named_scope(scopes.CODEC):
+                    # lossy uplink: what the server aggregates is the DECODED
+                    # update, exactly what the wire form reconstructs.  EF folds
+                    # the per-client residual in before encoding and keeps the
+                    # new quantization error for the next round (participation-
+                    # masked: a client that did not report keeps its residual).
+                    k_comp = jax.random.fold_in(k_round, COMPRESS_STREAM)
+                    comp_rngs = jax.vmap(
+                        lambda i: jax.random.fold_in(k_comp, i)
+                    )(slot_ids)
+                    f32 = jnp.float32
+
+                    def lossy_one(cvars, rng, res_row):
+                        delta = jax.tree_util.tree_map(
+                            lambda c, g: c.astype(f32) - g.astype(f32),
+                            cvars, state.variables,
+                        )
+                        if error_feedback:
+                            delta = jax.tree_util.tree_map(jnp.add, delta, res_row)
+                        dec = roundtrip_tree(codec, delta, rng)
+                        new_cvars = jax.tree_util.tree_map(
+                            lambda g, d: (g.astype(f32) + d).astype(g.dtype),
+                            state.variables, dec,
+                        )
+                        new_res = (
+                            jax.tree_util.tree_map(jnp.subtract, delta, dec)
+                            if error_feedback else ()
+                        )
+                        return new_cvars, new_res
+
+                    if error_feedback:
+                        res_rows = jax.tree_util.tree_map(
+                            lambda r: r[slot_ids], state.residuals
+                        )
+                        client_vars, res_new = jax.vmap(lossy_one)(
+                            client_vars, comp_rngs, res_rows
+                        )
+                        keep = lambda new, old: jnp.where(
+                            participation.reshape(
+                                (-1,) + (1,) * (new.ndim - 1)
+                            ) > 0,
+                            new, old,
+                        )
+                        res_rows = jax.tree_util.tree_map(keep, res_new, res_rows)
+                        residuals = jax.tree_util.tree_map(
+                            lambda store, rows: store.at[slot_ids].set(rows),
+                            state.residuals, res_rows,
+                        )
+                    else:
+                        client_vars, _ = jax.vmap(
+                            lambda c, r: lossy_one(c, r, None)
+                        )(client_vars, comp_rngs)
+
+            with jax.named_scope(scopes.AGGREGATE):
+                weights = participation * num_samples  # sample-weighted, masked
+            if aggregate_transform is not None:
+                with jax.named_scope(scopes.AGG_TRANSFORM):
+                    # per-client keys from GLOBAL slot ids: independent noise per
+                    # client even under shard_map (a single replicated key would
+                    # stamp identical noise on every device's local block)
+                    agg_rngs = jax.vmap(lambda i: jax.random.fold_in(k_agg, i))(slot_ids)
+                    client_vars = aggregate_transform(
+                        state.variables, client_vars, weights, agg_rngs
+                    )
+
+            with jax.named_scope(scopes.AGGREGATE):
+                if aggregate_impl is not None:
+                    # pluggable weighted-sum kernel: the partition-rule engine
+                    # (parallel/partition.py) substitutes a sequential lax.scan
+                    # here — on a dp-sharded mesh the GSPMD partitioner may
+                    # partial-sum the einsum's K axis per device, which
+                    # reassociates the fp32 reduction and breaks the
+                    # sharded-vs-replicated sha256 parity pins
+                    num = aggregate_impl(weights, client_vars)
+                else:
+                    num = jax.tree_util.tree_map(
+                        lambda leaf: jnp.einsum(
+                            "k,k...->...", weights, leaf.astype(jnp.float32)
+                        ),
+                        client_vars,
+                    )
+                den = weights.sum()
+                n_participants = participation.sum()
+                if axis_name is not None:
+                    num = jax.lax.psum(num, axis_name)
+                    den = jax.lax.psum(den, axis_name)
+                    n_participants = jax.lax.psum(n_participants, axis_name)
+                # zero-participation guard: with den == 0 (every client dropped
+                # or deadline-missed this round) the weighted average is
+                # undefined — 0/eps would ZERO the global model and the next
+                # round's gradients would NaN-poison it.  A participant-less
+                # round is a no-op update (the cross-device server's
+                # dropped_all semantics), and the driver counts it as degraded.
+                agg = jax.tree_util.tree_map(
+                    lambda s, ref: jnp.where(
+                        den > 0,
+                        (s / jnp.maximum(den, 1e-12)).astype(ref.dtype),
+                        ref,
+                    ),
+                    num,
+                    state.variables,
+                )
+            with jax.named_scope(scopes.SERVER_UPDATE):
+                new_vars, new_opt = server_update(state.variables, agg, state.opt_state)
+
+            with jax.named_scope(scopes.METRICS):
+                train_metrics = {
+                    k: (
+                        jax.lax.psum((participation * v).sum(), axis_name)
+                        if axis_name
+                        else (participation * v).sum()
+                    )
+                    for k, v in client_metrics.items()
+                }
+                # realized cohort size: the drivers' degraded-round detector
+                # (participants == 0 -> rounds.degraded counter, model unchanged)
+                train_metrics["participants"] = n_participants
+            new_state = ServerState(
+                variables=new_vars,
+                opt_state=new_opt,
+                round_idx=state.round_idx + 1,
+                key=state.key,
+                residuals=residuals,
             )
-
-        residuals = state.residuals
-        if codec is not None:
-            # lossy uplink: what the server aggregates is the DECODED
-            # update, exactly what the wire form reconstructs.  EF folds
-            # the per-client residual in before encoding and keeps the
-            # new quantization error for the next round (participation-
-            # masked: a client that did not report keeps its residual).
-            k_comp = jax.random.fold_in(k_round, COMPRESS_STREAM)
-            comp_rngs = jax.vmap(
-                lambda i: jax.random.fold_in(k_comp, i)
-            )(slot_ids)
-            f32 = jnp.float32
-
-            def lossy_one(cvars, rng, res_row):
-                delta = jax.tree_util.tree_map(
-                    lambda c, g: c.astype(f32) - g.astype(f32),
-                    cvars, state.variables,
-                )
-                if error_feedback:
-                    delta = jax.tree_util.tree_map(jnp.add, delta, res_row)
-                dec = roundtrip_tree(codec, delta, rng)
-                new_cvars = jax.tree_util.tree_map(
-                    lambda g, d: (g.astype(f32) + d).astype(g.dtype),
-                    state.variables, dec,
-                )
-                new_res = (
-                    jax.tree_util.tree_map(jnp.subtract, delta, dec)
-                    if error_feedback else ()
-                )
-                return new_cvars, new_res
-
-            if error_feedback:
-                res_rows = jax.tree_util.tree_map(
-                    lambda r: r[slot_ids], state.residuals
-                )
-                client_vars, res_new = jax.vmap(lossy_one)(
-                    client_vars, comp_rngs, res_rows
-                )
-                keep = lambda new, old: jnp.where(
-                    participation.reshape(
-                        (-1,) + (1,) * (new.ndim - 1)
-                    ) > 0,
-                    new, old,
-                )
-                res_rows = jax.tree_util.tree_map(keep, res_new, res_rows)
-                residuals = jax.tree_util.tree_map(
-                    lambda store, rows: store.at[slot_ids].set(rows),
-                    state.residuals, res_rows,
-                )
-            else:
-                client_vars, _ = jax.vmap(
-                    lambda c, r: lossy_one(c, r, None)
-                )(client_vars, comp_rngs)
-
-        weights = participation * num_samples  # sample-weighted, masked
-        if aggregate_transform is not None:
-            # per-client keys from GLOBAL slot ids: independent noise per
-            # client even under shard_map (a single replicated key would
-            # stamp identical noise on every device's local block)
-            agg_rngs = jax.vmap(lambda i: jax.random.fold_in(k_agg, i))(slot_ids)
-            client_vars = aggregate_transform(
-                state.variables, client_vars, weights, agg_rngs
-            )
-
-        if aggregate_impl is not None:
-            # pluggable weighted-sum kernel: the partition-rule engine
-            # (parallel/partition.py) substitutes a sequential lax.scan
-            # here — on a dp-sharded mesh the GSPMD partitioner may
-            # partial-sum the einsum's K axis per device, which
-            # reassociates the fp32 reduction and breaks the
-            # sharded-vs-replicated sha256 parity pins
-            num = aggregate_impl(weights, client_vars)
-        else:
-            num = jax.tree_util.tree_map(
-                lambda leaf: jnp.einsum(
-                    "k,k...->...", weights, leaf.astype(jnp.float32)
-                ),
-                client_vars,
-            )
-        den = weights.sum()
-        n_participants = participation.sum()
-        if axis_name is not None:
-            num = jax.lax.psum(num, axis_name)
-            den = jax.lax.psum(den, axis_name)
-            n_participants = jax.lax.psum(n_participants, axis_name)
-        # zero-participation guard: with den == 0 (every client dropped
-        # or deadline-missed this round) the weighted average is
-        # undefined — 0/eps would ZERO the global model and the next
-        # round's gradients would NaN-poison it.  A participant-less
-        # round is a no-op update (the cross-device server's
-        # dropped_all semantics), and the driver counts it as degraded.
-        agg = jax.tree_util.tree_map(
-            lambda s, ref: jnp.where(
-                den > 0,
-                (s / jnp.maximum(den, 1e-12)).astype(ref.dtype),
-                ref,
-            ),
-            num,
-            state.variables,
-        )
-        new_vars, new_opt = server_update(state.variables, agg, state.opt_state)
-
-        train_metrics = {
-            k: (
-                jax.lax.psum((participation * v).sum(), axis_name)
-                if axis_name
-                else (participation * v).sum()
-            )
-            for k, v in client_metrics.items()
-        }
-        # realized cohort size: the drivers' degraded-round detector
-        # (participants == 0 -> rounds.degraded counter, model unchanged)
-        train_metrics["participants"] = n_participants
-        new_state = ServerState(
-            variables=new_vars,
-            opt_state=new_opt,
-            round_idx=state.round_idx + 1,
-            key=state.key,
-            residuals=residuals,
-        )
         return new_state, train_metrics
 
     # the baked-in mesh axis travels WITH the kernel: a pre-built
@@ -402,7 +414,8 @@ def make_multi_round_fn(
                 part = inject_dropout(st.key, st.round_idx, part, drop_prob)
             return rf(st, x, y, mask, num_samples, part, slot_ids)
 
-        return jax.lax.scan(body, state, None, length=rounds_per_call)
+        with jax.named_scope(scopes.ROUNDS):
+            return jax.lax.scan(body, state, None, length=rounds_per_call)
 
     return multi_round_fn
 

@@ -28,6 +28,7 @@ import optax
 from fedml_tpu.core import tree as treelib
 from fedml_tpu.core.losses import LossFn, masked_softmax_ce
 from fedml_tpu.models.base import ModelBundle
+from fedml_tpu.obs import scopes
 
 PyTree = Any
 
@@ -143,20 +144,25 @@ def make_local_update(
     def loss_and_logits(params, other_vars, global_params, x, y, m, rng):
         variables = {**other_vars, "params": params}
         if compute_dtype is not None:
-            cvars = treelib.tree_cast_floats(variables, compute_dtype)
-            cx = (
-                x.astype(compute_dtype)
-                if jnp.issubdtype(x.dtype, jnp.floating)
-                else x
-            )
-            logits, new_vars = bundle.apply_train(cvars, cx, rng)
-            new_vars = treelib.tree_cast_like(new_vars, variables)
+            with jax.named_scope(scopes.CAST):
+                cvars = treelib.tree_cast_floats(variables, compute_dtype)
+                cx = (
+                    x.astype(compute_dtype)
+                    if jnp.issubdtype(x.dtype, jnp.floating)
+                    else x
+                )
+            with jax.named_scope(scopes.MODEL):
+                logits, new_vars = bundle.apply_train(cvars, cx, rng)
+            with jax.named_scope(scopes.CAST):
+                new_vars = treelib.tree_cast_like(new_vars, variables)
         else:
-            logits, new_vars = bundle.apply_train(variables, x, rng)
-        loss, aux = loss_fn(logits, y, m)
-        if prox_mu:
-            sq = treelib.tree_sq_norm(treelib.tree_sub(params, global_params))
-            loss = loss + 0.5 * prox_mu * sq
+            with jax.named_scope(scopes.MODEL):
+                logits, new_vars = bundle.apply_train(variables, x, rng)
+        with jax.named_scope(scopes.LOSS):
+            loss, aux = loss_fn(logits, y, m)
+            if prox_mu:
+                sq = treelib.tree_sq_norm(treelib.tree_sub(params, global_params))
+                loss = loss + 0.5 * prox_mu * sq
         return loss, (new_vars, aux)
 
     grad_fn = jax.value_and_grad(loss_and_logits, has_aux=True)
@@ -170,52 +176,55 @@ def make_local_update(
         def epoch_body(carry, ep):
             variables, opt_state = carry
             ek = jax.random.fold_in(rng, ep)
-            if shuffle:
-                perm = jax.random.permutation(jax.random.fold_in(ek, 0), n)
-                xs = x.reshape(n, *x.shape[2:])[perm].reshape(x.shape)
-                ys = y.reshape(n, *y.shape[2:])[perm].reshape(y.shape)
-                ms = mask.reshape(n)[perm].reshape(mask.shape)
-            else:
-                xs, ys, ms = x, y, mask
-            if augment_fn is not None:
-                # fresh augmentation for every sample once per EPOCH —
-                # exactly the reference's torchvision semantics (each
-                # sample is transformed once per pass) — applied to the
-                # whole epoch tensor in ONE call.  Per-STEP augmentation
-                # is semantically identical but ~15x slower end-to-end:
-                # the augment's ~6 threefry/elementwise kernels cost
-                # ~1.5 ms per scan step on v5e (latency-, not
-                # bandwidth-bound), which at north-star scale (15,600
-                # steps/round) added ~25 s/round and pushed the round
-                # over the ~70 s device-execution deadline (measured;
-                # one whole-epoch call costs ~0.1 ms for 5,000 images)
-                flat = augment_fn(
-                    jax.random.fold_in(ek, n + 1),
-                    xs.reshape(n, *x.shape[2:]),
-                )
-                xs = flat.reshape(x.shape)
+            with jax.named_scope(scopes.SHUFFLE):
+                if shuffle:
+                    perm = jax.random.permutation(jax.random.fold_in(ek, 0), n)
+                    xs = x.reshape(n, *x.shape[2:])[perm].reshape(x.shape)
+                    ys = y.reshape(n, *y.shape[2:])[perm].reshape(y.shape)
+                    ms = mask.reshape(n)[perm].reshape(mask.shape)
+                else:
+                    xs, ys, ms = x, y, mask
+                if augment_fn is not None:
+                    # fresh augmentation for every sample once per EPOCH —
+                    # exactly the reference's torchvision semantics (each
+                    # sample is transformed once per pass) — applied to the
+                    # whole epoch tensor in ONE call.  Per-STEP augmentation
+                    # is semantically identical but ~15x slower end-to-end:
+                    # the augment's ~6 threefry/elementwise kernels cost
+                    # ~1.5 ms per scan step on v5e (latency-, not
+                    # bandwidth-bound), which at north-star scale (15,600
+                    # steps/round) added ~25 s/round and pushed the round
+                    # over the ~70 s device-execution deadline (measured;
+                    # one whole-epoch call costs ~0.1 ms for 5,000 images)
+                    flat = augment_fn(
+                        jax.random.fold_in(ek, n + 1),
+                        xs.reshape(n, *x.shape[2:]),
+                    )
+                    xs = flat.reshape(x.shape)
 
             def step_body(carry, batch):
-                variables, opt_state = carry
-                bx, by, bm, bi = batch
-                sk = jax.random.fold_in(ek, bi + 1)
-                others = {k: v for k, v in variables.items() if k != "params"}
-                (loss, (new_vars, aux)), grads = grad_fn(
-                    variables["params"], others, global_params, bx, by, bm, sk
-                )
-                updates, new_opt = optimizer.update(
-                    grads, opt_state, variables["params"]
-                )
-                params = optax.apply_updates(variables["params"], updates)
-                # batches that are entirely padding must be true no-ops
-                has_real = (bm.sum() > 0).astype(jnp.float32)
-                params = jax.tree_util.tree_map(
-                    lambda new, old: has_real * new + (1 - has_real) * old,
-                    params,
-                    variables["params"],
-                )
-                new_vars = {**new_vars, "params": params}
-                aux = {**aux, "step": has_real}
+                with jax.named_scope(scopes.STEP):
+                    variables, opt_state = carry
+                    bx, by, bm, bi = batch
+                    sk = jax.random.fold_in(ek, bi + 1)
+                    others = {k: v for k, v in variables.items() if k != "params"}
+                    (loss, (new_vars, aux)), grads = grad_fn(
+                        variables["params"], others, global_params, bx, by, bm, sk
+                    )
+                    with jax.named_scope(scopes.OPTIMIZER):
+                        updates, new_opt = optimizer.update(
+                            grads, opt_state, variables["params"]
+                        )
+                        params = optax.apply_updates(variables["params"], updates)
+                        # batches that are entirely padding must be true no-ops
+                        has_real = (bm.sum() > 0).astype(jnp.float32)
+                        params = jax.tree_util.tree_map(
+                            lambda new, old: has_real * new + (1 - has_real) * old,
+                            params,
+                            variables["params"],
+                        )
+                    new_vars = {**new_vars, "params": params}
+                    aux = {**aux, "step": has_real}
                 return (new_vars, new_opt), aux
 
             # unroll>1 trades compiled-code size for fewer while-loop

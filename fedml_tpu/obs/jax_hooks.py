@@ -157,6 +157,8 @@ def trace_rounds(
     ``run_dir``; the trace path and per-round seconds are logged into
     the metrics stream.  Returns ``(final_state, per_round_seconds)``.
     """
+    import jax
+
     from fedml_tpu.core.metrics import trace
     from fedml_tpu.utils.timing import sync_round
 
@@ -165,8 +167,11 @@ def trace_rounds(
     with trace(log_dir, logger=logger) as tdir:
         for _ in range(rounds):
             t0 = time.perf_counter()
-            state, metrics = round_fn(state, *args)
-            sync_round(state, metrics)
+            # marks the round on the trace's own clock: what
+            # benchmark/tools/scope_table.py takes for the window
+            with jax.profiler.TraceAnnotation("fed.traced_round"):
+                state, metrics = round_fn(state, *args)
+                sync_round(state, metrics)
             dt = time.perf_counter() - t0
             times.append(dt)
             t.observe("span.traced_round_s", dt)

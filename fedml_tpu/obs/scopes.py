@@ -1,0 +1,29 @@
+"""The ``jax.named_scope`` names of a federated round's stages.
+
+A scope is a path segment in an op's ``op_name`` (the profiler's ``tf_op``):
+debug info, not part of the program, so it costs nothing at run time and
+the compile cache's key does not hold it.  Inside ``value_and_grad`` JAX
+wraps the name: ``jvp(fed.model)`` forward, ``transpose(jvp(fed.model))``
+backward.  Read by ``benchmark/fed_scopes.py`` and
+``benchmark/tools/scope_table.py``.
+"""
+
+ROUNDS = "fed.rounds"  # make_multi_round_fn: the scan over fused rounds
+ROUND = "fed.round"  # round_fn: the whole round, key folding included
+CLIENTS = "fed.clients"  # the client map: the [K, ...] stack around the updates
+LOCAL_UPDATE = "fed.local_update"  # one client's local_update call
+CODEC = "fed.codec"  # the lossy-uplink round trip and its error feedback
+AGG_TRANSFORM = "fed.agg_transform"  # aggregate_transform with its keys
+AGGREGATE = "fed.aggregate"  # weights, weighted sum, psums, guarded divide
+SERVER_UPDATE = "fed.server_update"  # server_update(old, agg, opt_state)
+METRICS = "fed.metrics"  # the train_metrics sums and their psum
+SHUFFLE = "fed.shuffle"  # an epoch's permutation gather and augment_fn
+STEP = "fed.step"  # one optimizer step: the whole step_body
+CAST = "fed.cast"  # masters and inputs to compute_dtype, new state back
+MODEL = "fed.model"  # bundle.apply_train
+LOSS = "fed.loss"  # loss_fn and the proximal term
+OPTIMIZER = "fed.optimizer"  # optimizer.update, apply_updates, has_real blend
+
+SCOPES = (ROUNDS, ROUND, CLIENTS, LOCAL_UPDATE, CODEC, AGG_TRANSFORM,
+          AGGREGATE, SERVER_UPDATE, METRICS, SHUFFLE, STEP, CAST, MODEL,
+          LOSS, OPTIMIZER)

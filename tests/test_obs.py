@@ -223,6 +223,74 @@ def test_record_device_memory_none_guarded():
     record_device_memory(Telemetry())
 
 
+# --- the round's stages as named scopes (ISSUE 24) ---------------------------
+
+def _lower_round(case):
+    """A toy round lowered, never compiled or run: the scopes are debug
+    info of the lowered module."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.algorithms.fedavg import ServerState, make_multi_round_fn
+    from fedml_tpu.compress import get_codec
+    from fedml_tpu.core.client import make_client_optimizer, make_local_update
+    from fedml_tpu.models.linear import logistic_regression
+    from fedml_tpu.parallel.spmd import make_client_mesh, make_spmd_round_fn
+
+    bundle = logistic_regression(8, 2)
+    lu = make_local_update(bundle, make_client_optimizer("sgd", 0.1),
+                           epochs=1, compute_dtype=jnp.bfloat16)
+    scale = lambda tree: jax.tree_util.tree_map(lambda l: l * 0.5, tree)
+    if case == "spmd":
+        fn = make_spmd_round_fn(make_client_mesh(1), lu)
+    elif case == "codec_transform":
+        fn = jax.jit(make_multi_round_fn(
+            lu, 1, codec=get_codec("int8"),
+            aggregate_transform=lambda old, stacked, w, rngs: scale(stacked),
+            server_update=lambda old, agg, opt: (scale(agg), opt)))
+    else:
+        kw = {"vmap": {"client_axis_impl": "vmap"},
+              "unroll": {"client_unroll": 2}}.get(case, {})
+        fn = jax.jit(make_multi_round_fn(lu, 2, **kw))
+    key = jax.random.PRNGKey(0)
+    state = ServerState(bundle.init(key), (), jnp.zeros((), jnp.int32), key)
+    k, steps, b = 2, 2, 4
+    shape = jax.ShapeDtypeStruct
+    return fn.lower(
+        state, shape((k, steps, b, 8), jnp.float32),
+        shape((k, steps, b), jnp.int32), shape((k, steps, b), jnp.float32),
+        shape((k,), jnp.float32), shape((k,), jnp.float32),
+        shape((k,), jnp.int32))
+
+
+# no codec, no transform; the default server update passes the mean on and
+# has no op to name
+_PLAIN_ROUND_LACKS = {"fed.codec", "fed.agg_transform", "fed.server_update"}
+
+
+@pytest.mark.parametrize("case,absent", [
+    ("fused", _PLAIN_ROUND_LACKS),
+    ("vmap", _PLAIN_ROUND_LACKS),
+    ("unroll", _PLAIN_ROUND_LACKS),
+    ("spmd", _PLAIN_ROUND_LACKS | {"fed.rounds"}),
+    ("codec_transform", set()),
+])
+def test_round_stages_are_named_scopes_in_debug_info_only(case, absent):
+    import re
+
+    from fedml_tpu.obs.scopes import SCOPES
+
+    lowered = _lower_round(case)
+    text = lowered.as_text(debug_info=True)
+    assert set(re.findall(r"fed\.[a-z_]+", text)) == set(SCOPES) - absent
+    # inside value_and_grad JAX wraps the scope: forward, then backward
+    assert "jvp(fed.model)" in text and "transpose(jvp(fed.model))" in text
+    assert "transpose(jvp(fed.loss))" in text
+    # a scope is debug info, which the compile cache's key strips: the
+    # program is the one the cache held before the stages had names
+    assert "fed." not in lowered.as_text()
+
+
 # --- where the compile cache goes, and which device a run is on --------------
 
 def test_compile_cache_env_wins_else_fixed_path_in_checkout(monkeypatch):

@@ -21,9 +21,11 @@ chip):
   fedllm_fused     the transformer at width 1280, 12 layers, 10 heads,
                    L = 1024, vocab 8192, 4 clients x 4 steps x batch 8
                    (bench.py --workload fedllm): warm-up + 1 call
-  flash_attention  ops/flash_attention.py compiled, forward and custom
-                   backward, L = 2048 and 8192 at D = 128, against
-                   blockwise_attention at "highest" matmul precision
+  flash_attention  ops/flash_attention.py compiled through the model's
+                   policy, forward, dQ and dK/dV kernels: L = 1024 at
+                   H = 20, D = 64 (the benchmark cells' shape), L = 2048
+                   and 8192 at D = 128, against blockwise_attention at
+                   "highest" matmul precision
   conv_mxu         ops/conv_mxu.py compiled, forward with and without
                    moments at ResNet-56's 3x3 shapes, against the XLA conv
   federation_mux   launch(num_clients=8, muxers=1, muxed_clients=8,
@@ -353,11 +355,14 @@ def leg_fedllm_fused(rehearsal: bool) -> dict:
         "warmup_call_s": secs[0], "step_s": secs[1:],
         "peak_bytes_in_use": peak_device_bytes(rehearsal),
         "pallas_kernels": watch.kernel_summary(),
-        "attention": "lax blockwise_attention (models/transformer.py "
-                     "policy: L < 2048)",
+        "attention": "lax blockwise_attention (the CPU backend)" if rehearsal
+                     else "Pallas flash kernels, forward and backward "
+                          "(models/transformer.py policy: bf16 on a TPU)",
         "conv": "none",
     }
-    check(not watch.kernels, f"unexpected Pallas kernels: {watch.kernels}")
+    names = {name for name, interpreted in watch.kernels if not interpreted}
+    check(names == (set() if rehearsal else set(FLASH_KERNELS)),
+          f"Pallas kernels traced: {watch.kernels}")
     if not rehearsal:
         res["observed_tokens_per_s"] = [
             round(tokens_per_call / s) for s in secs[1:]]
@@ -368,9 +373,11 @@ def leg_fedllm_fused(rehearsal: bool) -> dict:
 # takes bf16 q/k/v, accumulates scores in fp32, rounds the probabilities to
 # bf16 for the second matmul and the output to bf16: each rounding is a
 # relative 2^-8 = 0.4 %, they do not compound beyond a small multiple, and
-# 2 % leaves room for that multiple.  The backward runs its fp32 matmuls at
-# the TPU's default precision (one bf16 pass), the same 2^-8 per product.
+# 2 % leaves room for that multiple.  The backward kernels do the same:
+# bf16 operands (p and ds rounded to bf16), fp32 scores and accumulators.
 FLASH_TOL = 0.02
+# the kernels of ops/flash_attention.py, as Watch names them
+FLASH_KERNELS = ("_fwd_kernel", "_dq_kernel", "_dkv_kernel")
 
 
 def leg_flash_attention(rehearsal: bool) -> dict:
@@ -383,17 +390,21 @@ def leg_flash_attention(rehearsal: bool) -> dict:
     from fedml_tpu.ops.flash_attention import flash_attention, pick_block
     from fedml_tpu.parallel.ring_attention import blockwise_attention
 
-    heads, dim = (2, 128) if rehearsal else (10, 128)  # width 1280 / 10
+    # (L, heads, head size): the benchmark cells' own shape (gpt2-large,
+    # two heads to a 128-lane block), then width 1280 / 10 at the lengths
+    # where the lax path no longer fits
+    shapes = ([(256, 2, 64), (256, 2, 128)] if rehearsal else
+              [(1024, 20, 64), (2048, 10, 128), (8192, 10, 128)])
     res = {"tolerance": FLASH_TOL, "shapes": {}}
-    for L in ((256,) if rehearsal else (2048, 8192)):
-        block = pick_block(L)
+    for L, heads, dim in shapes:
+        block = pick_block(L, dim)
         if rehearsal:
-            # the CPU can only interpret the kernel
+            # the CPU can only interpret the kernels
             attn = lambda q, k, v: flash_attention(  # noqa: E731
                 q, k, v, causal=True, block_q=block, block_k=block,
                 interpret=True)
         else:
-            # the policy the model uses: flash from L = 2048 on a TPU
+            # the policy the model uses: the kernels for bf16 on a TPU
             attn = lambda q, k, v: _default_attn(q, k, v, True)  # noqa: E731
         ks = jax.random.split(jax.random.PRNGKey(L), 4)
         q, k, v, do = (
@@ -407,7 +418,11 @@ def leg_flash_attention(rehearsal: bool) -> dict:
             argnums=(0, 1, 2)))(q, k, v)
         jax.block_until_ready((out, grads))
         traced = watch.kernels[first:]
-        check(traced, f"L={L}: no Pallas kernel was traced")
+        # the forward call traces the forward kernel, the gradient call
+        # the forward again and both backward kernels
+        check([name for name, _ in traced]
+              == [FLASH_KERNELS[0], *FLASH_KERNELS],
+              f"L={L}: Pallas kernels traced: {traced}")
         check(rehearsal or not any(i for _, i in traced),
               f"L={L}: the kernel ran interpreted: {traced}")
         # reference on the same values in fp32, two heads at a time (its
@@ -438,7 +453,7 @@ def leg_flash_attention(rehearsal: bool) -> dict:
         res["shapes"][f"L={L},H={heads},D={dim},block={block}"] = errs
     res.update(compile_s=watch.compile_seconds(), cache=watch.cache,
                pallas_kernels=watch.kernel_summary(),
-               attention="Pallas flash kernel (ops/flash_attention.py) "
+               attention="Pallas flash kernels (ops/flash_attention.py) "
                          + ("interpreted" if rehearsal else "compiled"),
                conv="none",
                peak_bytes_in_use=peak_device_bytes(rehearsal))

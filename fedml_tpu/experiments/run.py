@@ -179,13 +179,18 @@ def _run_fedllm(cfg: ExperimentConfig, ds, t0, log_fn, metrics=None) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from fedml_tpu.models.transformer import transformer_lm
+    from fedml_tpu.models.transformer import lax_attention, transformer_lm
 
     seq_len = int(ds.train_x.shape[1])
     vocab = max(int(ds.num_classes), int(ds.train_x.max()) + 1)
+    # the rule engine and DP x TP shard the model (and the cohort) by
+    # GSPMD, where a pallas_call has no partitioning rule: those meshes
+    # keep the lax attention, every other driver the model's own policy
+    gspmd = bool(cfg.mesh) or cfg.tp_degree > 1
     bundle = transformer_lm(
         vocab_size=vocab, embed_dim=cfg.embed_dim, num_heads=cfg.num_heads,
         num_layers=cfg.num_layers, seq_len=seq_len,
+        attn_fn=lax_attention if gspmd else None,
     )
 
     if cfg.mesh and (cfg.tp_degree > 1 or cfg.sp_degree > 1):

@@ -29,29 +29,51 @@ from fedml_tpu.parallel.ring_attention import blockwise_attention
 AttnFn = Callable
 
 
+def lax_attention(q, k, v, causal):
+    """The lax blockwise scan under the ``AttnFn`` signature: the policy's
+    fallback, and what a caller whose heads are sharded by GSPMD passes
+    as ``attn_fn`` (a ``pallas_call`` has no partitioning rule, so XLA
+    would gather q, k, v and run every head on every chip)."""
+    return blockwise_attention(q, k, v, causal=causal, block_size=512)
+
+
 def _default_attn(q, k, v, causal):
-    """Single-device attention policy:
+    """Single-device attention policy, from what the call can see:
 
-    - L >= 2048 on an accelerator: the pallas flash kernel with its
-      custom O(L)-memory backward.  Measured on one v5e chip: >= parity
-      with the lax blockwise scan at 4k and ~2.4x on the fwd at 8k — and
-      at 8k the blockwise TRAINING path does not fit at all (its scan
-      vjp stacks per-block residuals; observed 17.6 GB > 15.75 GB HBM
-      for a 4x8192 batch, while flash trains the same batch in ~365 ms).
-    - shorter sequences, non-TPU backends (the kernel is Mosaic/TPU;
-      GPU would fail to compile it, CPU runs it only in interpret mode),
-      lengths that no >=512 block divides (smaller pallas blocks measured
-      4-8x SLOWER than the blockwise scan): the lax blockwise path.
+    - on a TPU, for a shape the fused kernels take (``pick_block`` finds
+      a block that divides L, ``head_group`` a lane layout for the head
+      size) and inputs in a 16-bit compute dtype: the Pallas flash
+      kernels (``ops/flash_attention.py``), forward and backward, which
+      keep scores and probabilities in VMEM and save only ``o`` and
+      ``lse``.  At the benchmark cells' shape (bf16 [8, 1024, 20, 64])
+      they run forward + backward 2.7x faster than the lax scan below
+      (PERF.md §6, PR 26);
+    - float32 inputs take the kernels from L = 2048 on, in 512-blocks,
+      where the lax path's saved probability blocks no longer fit (17.6
+      GB observed for a 4 x 8192 batch); below that they keep the lax
+      path, which is also the benchmark reference's attention;
+    - other backends (the kernels are Mosaic/TPU; the CPU runs them only
+      in interpret mode) and ragged lengths: the lax blockwise scan.
+
+    The model cannot see a sharding: a caller whose heads GSPMD shards
+    passes ``lax_attention`` as ``attn_fn`` (``experiments/run.py``,
+    ``parallel/tensor.py``).
     """
-    from fedml_tpu.ops.flash_attention import flash_attention, pick_block
+    from fedml_tpu.ops.flash_attention import (
+        flash_attention, head_group, pick_block,
+    )
 
-    L = q.shape[0]
-    block = pick_block(L)
-    if block >= 512 and L >= 2048 and jax.default_backend() == "tpu":
+    L, H, D = q.shape
+    block = pick_block(L, D)
+    if q.dtype.itemsize == 2:
+        fits = block > 0
+    else:
+        fits = block == 512 and L >= 2048
+    if fits and head_group(H, D) and jax.default_backend() == "tpu":
         return flash_attention(
             q, k, v, causal=causal, block_q=block, block_k=block
         )
-    return blockwise_attention(q, k, v, causal=causal, block_size=512)
+    return lax_attention(q, k, v, causal)
 
 
 class MultiHeadAttention(nn.Module):

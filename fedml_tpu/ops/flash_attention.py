@@ -1,21 +1,36 @@
-"""Pallas TPU flash-attention kernel.
+"""Pallas TPU flash attention: forward, dQ and dK/dV kernels.
 
 The hot op of the transformer family (``models/transformer.py``):
 softmax(QKᵀ/√d)V computed blockwise in VMEM with online-softmax
-accumulation — no [L, L] score matrix ever hits HBM.  This is the
-single-device attention path; the ring path
-(``parallel/ring_attention.py``) keeps its own lax blockwise inner loop
-because merging shards needs raw (m, l, o) online-softmax partials and
-global position offsets, which this kernel does not expose.
+accumulation.  No [L, L] score or probability block ever reaches HBM, in
+the forward or in the backward: the forward saves only ``o`` and the
+per-row log-sum-exp ``lse`` ([H, L] float32), and the backward recomputes
+``p = exp(s - lse)`` per block pair.  This is the single-device attention
+path (``_default_attn``) and the per-step local attention of
+``parallel.ring_attention.ring_flash_attention``; the lax ring keeps its
+own blockwise inner loop.
 
-Layout per pallas core: one (batch·head) slice [L, D]; the caller vmaps
-over batch and heads.  Grid = (q_blocks, kv_blocks) with the kv axis
-iterated innermost ("arbitrary" semantics) so the VMEM scratch (m, l,
-acc) carries across kv steps of one q block — the standard TPU flash
-pattern from the pallas guide (grid/scratch/`pl.when` sections).
+Layout: the kernels read the model's own [L, H, D] arrays as [L, H·D]
+(a free reshape: no head transpose in HBM) in column blocks of
+``group`` heads, 128 lanes wide where the head size divides 128.  A head
+inside a block is picked with lane masks, never with lane slices: a
+masked ``q`` contracted over all 128 lanes against ``k`` is that head's
+``q kᵀ`` (the MXU pads a 64-deep contraction to 128 anyway), and
+``p @ v`` over the block's 128 columns is kept on that head's lanes only.
+Scores, probabilities and every running sum are float32; ``p`` and ``ds``
+are rounded to the input dtype only as matmul operands.
 
-``flash_attention(..., interpret=True)`` runs the same kernel on CPU
-(tests); ``blockwise_attention`` remains the lax fallback.
+Grids (a ``vmap`` prepends its batch axis): forward and dQ run (head
+blocks, q blocks) with that head block's k and v whole in VMEM, and walk
+the kv blocks in the kernel's own loop; dK/dV runs (head blocks, kv
+blocks) with q and dO whole in VMEM, walks the q blocks, and works on the
+transposed block ``sᵀ = k qᵀ``, so that ``lse`` and ``delta`` broadcast
+as lane-dense rows and no operand is transposed.  Under a causal mask the
+loop's bounds leave out the blocks above the diagonal, and only the
+blocks the diagonal crosses apply the mask.
+
+``interpret=True`` runs the same kernels on the CPU (tests);
+``blockwise_attention`` remains the lax fallback.
 """
 
 from __future__ import annotations
@@ -30,186 +45,307 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
+# scoped VMEM the kernels may use (the v5e has 128 MiB): one side of the
+# attention is whole in VMEM, double-buffered
+VMEM_LIMIT = 96 * 1024 * 1024
+
+# contract the last dim of both operands: a @ b.T without a transpose
+_NT = (((1,), (1,)), ((), ()))
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *, sm_scale: float, causal: bool, block_q: int,
-                  block_k: int):
-    qi = pl.program_id(0)
-    ki = pl.program_id(1)
-    nk = pl.num_programs(1)
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    # 16-bit operands multiply exactly in one MXU pass whatever precision
+    # the caller's config asks for (Mosaic refuses "highest" on bf16);
+    # float32 operands follow the config
+    precision = jax.lax.Precision.DEFAULT if a.dtype.itemsize == 2 else None
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: a KV block strictly above the diagonal contributes nothing;
-    # skip its matmuls entirely (half the work for long sequences)
-    visible = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+def head_group(num_heads: int, head_dim: int) -> int:
+    """Heads per column block of the [L, H·D] view, 0 if the kernels do
+    not take the shape on a TPU: a block is ``head_dim`` lanes wide when
+    that is whole 128-lane tiles, else 128 lanes of ``128 // head_dim``
+    heads."""
+    if head_dim % LANES == 0:
+        return 1
+    group = LANES // head_dim
+    if LANES % head_dim == 0 and num_heads % group == 0:
+        return group
+    return 0
 
-    @pl.when(visible)
-    def _compute():
-        q = q_ref[:]            # [BQ, D]
-        k = k_ref[:]            # [BK, D]
-        v = v_ref[:]            # [BK, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale            # [BQ, BK]
 
-        if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0
+def _head_lanes(shape, a: int, head_dim: int, group: int):
+    """Mask of head ``a``'s lanes in a [rows, group·head_dim] block."""
+    if group == 1:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= a * head_dim) & (lane < (a + 1) * head_dim)
+
+
+def _only(x, lanes):
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _keep(row0, col0, shape, kv_axis: int):
+    """Causal mask of a score block whose kv positions run along
+    ``kv_axis`` (1: s = q kᵀ, 0: sᵀ = k qᵀ)."""
+    qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - kv_axis)
+    kpos = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, kv_axis)
+    return kpos <= qpos
+
+
+def _row_to_col(row):
+    """[1, n] -> [n, 1] through a tile-aligned transpose."""
+    return jnp.broadcast_to(row, (LANES, row.shape[1])).T[:, :1]
+
+
+def _col_to_row(col):
+    """[n, 1] -> [1, n]."""
+    return jnp.broadcast_to(col, (col.shape[0], LANES)).T[:1]
+
+
+def _loop_blocks(bounds, masked, body, carry):
+    """Run ``body(j, carry, masked=m)`` over the consecutive block ranges
+    [bounds[i], bounds[i + 1]) with ``m = masked[i]``: the blocks the
+    causal diagonal crosses apply the mask, wholly visible ones skip it,
+    wholly masked ones are in no range."""
+    for lo, hi, m in zip(bounds, bounds[1:], masked):
+        if isinstance(lo, int) and isinstance(hi, int) and lo == hi:
+            continue  # no causal mask: the masked range is empty
+        carry = jax.lax.fori_loop(
+            lo, hi, functools.partial(body, masked=m), carry)
+    return carry
+
+
+def _kv_range(qi, block_q, block_k, nk, causal):
+    """(first masked, end) kv blocks of q block ``qi``: blocks before the
+    first are wholly visible, blocks from ``end`` on wholly masked."""
+    if not causal:
+        return nk, nk
+    return (qi * block_q + 1) // block_k, \
+        jnp.minimum(nk, (qi * block_q + block_q - 1) // block_k + 1)
+
+
+def _q_range(ki, block_q, block_k, nq, causal):
+    """(first visible, first wholly visible) q blocks of kv block ``ki``."""
+    if not causal:
+        return 0, 0
+    return (ki * block_k) // block_q, jnp.minimum(
+        nq, (ki * block_k + block_k + block_q - 2) // block_q)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
+                causal: bool, block_k: int, head_dim: int, group: int):
+    block_q, width = q_ref.shape
+    qi, nk = pl.program_id(1), k_ref.shape[0] // block_k
+    diag, end = _kv_range(qi, block_q, block_k, nk, causal)
+    q = q_ref[...]
+    out = jnp.zeros(q.shape, jnp.float32)
+    for a in range(group):
+        lanes = _head_lanes(q.shape, a, head_dim, group)
+        qa = _only(q, lanes)
+
+        def body(j, carry, masked):
+            m_prev, l_prev, acc = carry
+            k = k_ref[pl.ds(j * block_k, block_k), :]
+            v = v_ref[pl.ds(j * block_k, block_k), :]
+            s = _dot(qa, k, _NT) * sm_scale                  # [BQ, BK]
+            if masked:
+                s = jnp.where(_keep(qi * block_q, j * block_k, s.shape, 1),
+                              s, NEG_INF)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)                  # [BQ, 1]
+            l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
+            return m_new, l_new, acc * alpha + _dot(p.astype(v.dtype), v)
+
+        m, l, acc = _loop_blocks((0, diag, end), (False, True), body, (
+            jnp.full((block_q, 1), NEG_INF, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32),
+            jnp.zeros((block_q, width), jnp.float32)))
+        l = jnp.maximum(l, 1e-30)
+        o_a = acc / l
+        out = o_a if lanes is None else jnp.where(lanes, o_a, out)
+        # log-sum-exp per query row: all the backward needs to recompute
+        # p.  Stored as a lane-dense row.
+        lse_ref[a:a + 1, :] = _col_to_row(m + jnp.log(l))
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
+               sm_scale: float, causal: bool, block_k: int, head_dim: int,
+               group: int):
+    block_q, width = q_ref.shape
+    qi, nk = pl.program_id(1), k_ref.shape[0] // block_k
+    diag, end = _kv_range(qi, block_q, block_k, nk, causal)
+    q, do = q_ref[...], do_ref[...]
+    dq = jnp.zeros((block_q, width), jnp.float32)
+    for a in range(group):
+        lanes = _head_lanes(q.shape, a, head_dim, group)
+        qa, doa = _only(q, lanes), _only(do, lanes)
+        lse = _row_to_col(lse_ref[a:a + 1, :])               # [BQ, 1]
+        delta = _row_to_col(delta_ref[a:a + 1, :])
+
+        def body(j, acc, masked):
+            k = k_ref[pl.ds(j * block_k, block_k), :]
+            v = v_ref[pl.ds(j * block_k, block_k), :]
+            s = _dot(qa, k, _NT) * sm_scale                  # [BQ, BK]
+            if masked:
+                s = jnp.where(_keep(qi * block_q, j * block_k, s.shape, 1),
+                              s, NEG_INF)
+            p = jnp.exp(s - lse)
+            ds = p * (_dot(doa, v, _NT) - delta)
+            return acc + _dot(ds.astype(k.dtype), k)
+
+        dq_a = _loop_blocks((0, diag, end), (False, True), body,
+                            jnp.zeros((block_q, width), jnp.float32))
+        dq = dq_a if lanes is None else jnp.where(lanes, dq_a, dq)
+    dq_ref[...] = (dq * sm_scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                dv_ref, *, sm_scale: float, causal: bool, block_q: int,
+                head_dim: int, group: int):
+    block_k, width = k_ref.shape
+    ki, nq = pl.program_id(1), q_ref.shape[0] // block_q
+    first, whole = _q_range(ki, block_q, block_k, nq, causal)
+    k, v = k_ref[...], v_ref[...]
+    dk = dv = jnp.zeros((block_k, width), jnp.float32)
+    for a in range(group):
+        lanes = _head_lanes(k.shape, a, head_dim, group)
+        ka, va = _only(k, lanes), _only(v, lanes)
+
+        def body(i, carry, masked):
+            dk_a, dv_a = carry
+            q = q_ref[pl.ds(i * block_q, block_q), :]
+            do = do_ref[pl.ds(i * block_q, block_q), :]
+            st = _dot(ka, q, _NT) * sm_scale                 # [BK, BQ]
+            if masked:
+                st = jnp.where(_keep(i * block_q, ki * block_k, st.shape, 0),
+                               st, NEG_INF)
+            pt = jnp.exp(st - lse_ref[a, pl.ds(i, 1), :])
+            dv_a = dv_a + _dot(pt.astype(do.dtype), do)
+            dst = pt * (_dot(va, do, _NT) - delta_ref[a, pl.ds(i, 1), :])
+            return dk_a + _dot(dst.astype(q.dtype), q), dv_a
+
+        zero = jnp.zeros((block_k, width), jnp.float32)
+        dk_a, dv_a = _loop_blocks((first, whole, nq), (True, False), body,
+                                  (zero, zero))
+        if lanes is None:
+            dk, dv = dk_a, dv_a
+        else:
+            dk, dv = jnp.where(lanes, dk_a, dk), jnp.where(lanes, dv_a, dv)
+    dk_ref[...] = (dk * sm_scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+
+class _Plan:
+    """Shapes, grids and block specs shared by the three kernels: the
+    [L, H·D] view in column blocks of ``group`` heads; one side of the
+    score block is a grid axis, the other is whole in VMEM and walked by
+    the kernel's own loop."""
+
+    def __init__(self, q, k, block_q, block_k, causal, interpret):
+        self.Lq, self.H, self.D = q.shape
+        self.Lk = k.shape[0]
+        self.bq, self.bk = min(block_q, self.Lq), min(block_k, self.Lk)
+        if self.Lq % self.bq or self.Lk % self.bk:
+            raise ValueError(
+                f"sequence ({self.Lq},{self.Lk}) must divide blocks "
+                f"({self.bq},{self.bk})"
             )
-            kpos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1
+        # a shape head_group refuses runs as one block of every head:
+        # right for the interpreter, not sent to a chip by the policy
+        self.group = head_group(self.H, self.D) or self.H
+        self.W = self.group * self.D
+        self.nh = self.H // self.group
+        self.nq, self.nk = self.Lq // self.bq, self.Lk // self.bk
+        self.interpret = interpret
+        self.consts = dict(sm_scale=1.0 / (self.D ** 0.5), causal=causal,
+                           head_dim=self.D, group=self.group)
+
+    def block(self, rows):
+        return pl.BlockSpec((rows, self.W), lambda h, i: (i, h))
+
+    def whole(self, rows):
+        return pl.BlockSpec((rows, self.W), lambda h, i: (0, h))
+
+    def call(self, name, kernel, grid, in_specs, out_specs, out_shape,
+             **consts):
+        """``name`` is the custom call's in a device trace."""
+        kwargs = {}
+        if not self.interpret:
+            kwargs["compiler_params"] = pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel"),
+                vmem_limit_bytes=VMEM_LIMIT,
             )
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-
-        m_prev = m_scr[:, :1]                       # [BQ, 1]
-        l_prev = l_scr[:, :1]
-        m_cur = s.max(axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                      # [BQ, BK]
-        alpha = jnp.exp(m_prev - m_new)             # [BQ, 1]
-        l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        return pl.pallas_call(
+            functools.partial(kernel, **self.consts, **consts), grid=grid,
+            in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+            interpret=self.interpret, name=name, **kwargs,
         )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        o_ref[:] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(
-            o_ref.dtype
-        )
-        # log-sum-exp per query row — the softmax statistic the custom
-        # backward needs to recompute p without re-running the online max.
-        # Single-lane output: the m/l scratch is lane-replicated, but
-        # writing all 128 lanes to HBM costs 512B/row of pure waste
-        # (ADVICE r2) — Mosaic takes a (block_q, 1) block fine.
-        lse_ref[:] = m_scr[:, :1] + jnp.log(jnp.maximum(l_scr[:, :1], 1e-30))
-
-
-def _flash_single(q, k, v, *, causal, block_q, block_k, interpret):
-    """Flash attention for one [L, D] head slice."""
-    Lq, D = q.shape
-    Lk = k.shape[0]
-    block_q = min(block_q, Lq)
-    block_k = min(block_k, Lk)
-    if Lq % block_q or Lk % block_k:
-        raise ValueError(
-            f"sequence ({Lq},{Lk}) must divide blocks ({block_q},{block_k})"
-        )
-    grid = (Lq // block_q, Lk // block_k)
-    sm_scale = 1.0 / (D ** 0.5)
-
-    scratch_shapes = [
-        pltpu.VMEM((block_q, 128), jnp.float32),   # running max m
-        pltpu.VMEM((block_q, 128), jnp.float32),   # running sum l
-        pltpu.VMEM((block_q, D), jnp.float32),     # output accumulator
-    ]
-
-    kernel = functools.partial(
-        _flash_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k,
-    )
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q, D), lambda qi, ki: (qi, 0)),
-            pl.BlockSpec((block_k, D), lambda qi, ki: (ki, 0)),
-            pl.BlockSpec((block_k, D), lambda qi, ki: (ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_q, D), lambda qi, ki: (qi, 0)),
-            pl.BlockSpec((block_q, 1), lambda qi, ki: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Lq, D), q.dtype),
-            jax.ShapeDtypeStruct((Lq, 1), jnp.float32),
-        ],
-        scratch_shapes=scratch_shapes,
-        interpret=interpret,
-        **kwargs,
-    )(q, k, v)
-    return out, lse[:, 0]
-
-
-def _flash_bwd_single(q, k, v, o, lse, do, dlse, *, causal, block_k,
-                      sm_scale):
-    """Exact flash backward for one [L, D] head slice in KV blocks —
-    O(L) memory (no [L, L] residuals; p is recomputed per block
-    pair from the forward's saved log-sum-exp).  Standard formulas:
-
-        p_ij  = exp(s_ij - lse_i)
-        dv_j  = pᵀ dO           dp_ij = dO_i · v_j
-        ds_ij = p_ij (dp_ij - D_i),   D_i = dO_i · O_i
-        dq_i  = scale · Σ_j ds_ij k_j
-        dk_j  = scale · Σ_i ds_ij q_i
-
-    Causal blocks above the diagonal DO run their (zero-producing)
-    matmuls here, unlike the forward kernel's block skip — a version
-    that bounded a fori_loop to each q block's visible KV prefix was
-    tried and measured ~6x SLOWER (313 ms vs 52 ms at L=4096): the
-    per-iteration dynamic_update_slice of the full [Lk, D] dk/dv
-    accumulators inside a while carry costs far more than the skipped
-    matmuls save.  The straight KV scan below emits dk/dv as stacked
-    scan outputs instead, which XLA handles well.
-    """
-    L, Dm = q.shape
-    Lk = k.shape[0]
-    bs = min(block_k, Lk)
-    n_blocks = Lk // bs
-    qf = q.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    Drow = (dof * o.astype(jnp.float32)).sum(-1)        # [L]
-    qpos = jnp.arange(L)
-
-    def body(dq, j):
-        kb = jax.lax.dynamic_slice_in_dim(k, j * bs, bs).astype(jnp.float32)
-        vb = jax.lax.dynamic_slice_in_dim(v, j * bs, bs).astype(jnp.float32)
-        s = (qf @ kb.T) * sm_scale                      # [L, bs]
-        if causal:
-            kpos = j * bs + jnp.arange(bs)
-            s = jnp.where(kpos[None, :] <= qpos[:, None], s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                   # [L, bs]
-        dv_j = p.T @ dof                                # [bs, D]
-        dp = dof @ vb.T                                 # [L, bs]
-        # dlse: the lse OUTPUT's cotangent (nonzero when the caller uses
-        # lse, e.g. the ring merge weights) — d lse_i / d s_ij = p_ij
-        ds = p * (dp - Drow[:, None] + dlse[:, None])
-        dq = dq + (ds @ kb) * sm_scale
-        dk_j = (ds.T @ qf) * sm_scale                   # [bs, D]
-        return dq, (dk_j, dv_j)
-
-    dq0 = jnp.zeros((L, Dm), jnp.float32)
-    dq, (dks, dvs) = jax.lax.scan(body, dq0, jnp.arange(n_blocks))
-    dk = dks.reshape(Lk, Dm)
-    dv = dvs.reshape(Lk, Dm)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    def flat(self, t):
+        return t.reshape(t.shape[0], self.H * self.D)
 
 
 def _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret):
-    run = functools.partial(
-        _flash_single, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
-    )
-    # vmap over a LEADING head axis: pallas prepends the batch dim to the
-    # grid, keeping each block's trailing dims tile-aligned ([L, D])
-    qh, kh, vh = (t.swapaxes(0, 1) for t in (q, k, v))
-    out, lse = jax.vmap(run)(qh, kh, vh)
-    return out.swapaxes(0, 1), lse  # out [L, H, D], lse [H, L]
+    """(o [L, H, D], lse [H, L]) of [L, H, D] inputs."""
+    pn = _Plan(q, k, block_q, block_k, causal, interpret)
+    out, lse = pn.call(
+        "flash_fwd", _fwd_kernel, (pn.nh, pn.nq),
+        [pn.block(pn.bq), pn.whole(pn.Lk), pn.whole(pn.Lk)],
+        [pn.block(pn.bq),
+         pl.BlockSpec((None, pn.group, pn.bq), lambda h, i: (h, 0, i))],
+        [jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.D), q.dtype),
+         jax.ShapeDtypeStruct((pn.nh, pn.group, pn.Lq), jnp.float32)],
+        block_k=pn.bk,
+    )(pn.flat(q), pn.flat(k), pn.flat(v))
+    return out.reshape(q.shape), lse.reshape(pn.H, pn.Lq)
+
+
+def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
+                    interpret):
+    """Exact flash backward as two kernels.  Standard formulas:
+
+        p_ij  = exp(s_ij - lse_i)
+        dv_j  = pᵀ dO           dp_ij = dO_i · v_j
+        ds_ij = p_ij (dp_ij - delta_i),  delta_i = dO_i · O_i - dlse_i
+        dq_i  = scale · Σ_j ds_ij k_j
+        dk_j  = scale · Σ_i ds_ij q_i
+
+    ``dlse`` is the cotangent of the lse OUTPUT (nonzero when the caller
+    uses lse, e.g. the ring merge weights): d lse_i / d s_ij = p_ij.
+    """
+    pn = _Plan(q, k, block_q, block_k, causal, interpret)
+    delta = (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(-1).T \
+        - dlse.astype(jnp.float32)                           # [H, Lq]
+    ops = (pn.flat(q), pn.flat(k), pn.flat(v), pn.flat(do))
+    flat_q = jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.D), q.dtype)
+    flat_k = jax.ShapeDtypeStruct((pn.Lk, pn.H * pn.D), k.dtype)
+
+    # [H, L] statistics by head block; a q block's row is picked by the
+    # grid (dQ) or, on a leading dim, by the kernel's loop (dK/dV)
+    row = pl.BlockSpec((None, pn.group, pn.bq), lambda h, i: (h, 0, i))
+    dq = pn.call(
+        "flash_dq", _dq_kernel, (pn.nh, pn.nq),
+        [pn.block(pn.bq), pn.whole(pn.Lk), pn.whole(pn.Lk), pn.block(pn.bq),
+         row, row],
+        pn.block(pn.bq), flat_q, block_k=pn.bk,
+    )(*ops, *(t.reshape(pn.nh, pn.group, pn.Lq) for t in (lse, delta)))
+
+    rows = pl.BlockSpec((None, pn.group, pn.nq, pn.bq),
+                        lambda h, j: (h, 0, 0, 0))
+    dk, dv = pn.call(
+        "flash_dkv", _dkv_kernel, (pn.nh, pn.nk),
+        [pn.whole(pn.Lq), pn.block(pn.bk), pn.block(pn.bk), pn.whole(pn.Lq),
+         rows, rows],
+        [pn.block(pn.bk), pn.block(pn.bk)], [flat_k, flat_k], block_q=pn.bq,
+    )(*ops, *(t.reshape(pn.nh, pn.group, pn.nq, pn.bq)
+              for t in (lse, delta)))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -226,20 +362,10 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
 
 
 def _flash_bwd(causal, block_q, block_k, interpret, res, g):
-    del block_q, interpret
     q, k, v, out, lse = res
     do, dlse = g
-    sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    run = functools.partial(
-        _flash_bwd_single, causal=causal, block_k=block_k,
-        sm_scale=sm_scale,
-    )
-    swap = lambda t: t.swapaxes(0, 1)  # noqa: E731
-    dq, dk, dv = jax.vmap(run)(
-        swap(q), swap(k), swap(v), swap(out), lse, swap(do),
-        dlse.astype(jnp.float32),
-    )
-    return swap(dq), swap(dk), swap(dv)
+    return _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal, block_q,
+                           block_k, interpret)
 
 
 flash_attention_with_lse.defvjp(_flash_fwd, _flash_bwd)
@@ -279,21 +405,27 @@ def flash_attn_fn(block_q: int = 128, block_k: int = 128,
     return attn
 
 
-def pick_block(length: int, preferred: int = 1024) -> int:
-    """Largest power-of-two block <= preferred that divides ``length``
-    (0 if none >= 128 divides it — caller should fall back to the lax
-    blockwise path).
+# longest side the kernels hold whole in VMEM (double-buffered, beside the
+# score blocks) under VMEM_LIMIT: 4 * L * 128 lanes * 4 bytes = 64 MiB
+MAX_LENGTH = 32768
 
-    Measured on one v5e chip (bf16, B=4 H=8 D=64, dispatch amortized by
-    a fused 50-iteration scan): 1024-blocks run 4.4/5.0/9.7 ms per call
-    at L=1k/4k/8k vs 4.4/9.0/23.1 ms for the XLA blockwise scan — parity
-    at 1k, 2.4x at 8k.  SMALL blocks are actively bad on TPU (256-blocks
-    measured 4-8x slower than 1024): the (q, kv) grid then has too many
-    tiny kernel invocations for the scalar core to schedule.
+
+def pick_block(length: int, head_dim: int = 128) -> int:
+    """Block size (q and kv alike, forward and backward) for a sequence
+    of ``length`` at ``head_dim``: the largest of 512, 256, 128 that
+    divides it; 0 if none does or the sequence is longer than MAX_LENGTH
+    (the caller falls back to the lax blockwise path).
+
+    Measured on one v5e chip (PERF.md §6, PR 26; bf16 [8, 1024, 20, 64],
+    forward + backward in a fused 50-iteration scan): 512-blocks 2.93 ms
+    a layer, 1024 (no causal block skipped) 2.96, 256 3.78, 128 6.89,
+    against 8.01 for the lax blockwise scan.  A block pair costs a fixed
+    ~400 cycles beside its elementwise passes, so small blocks lose more
+    than their finer causal skip wins.  Both head sizes the kernels take
+    on a chip (``head_group``) run 128-lane blocks, so the choice does
+    not depend on ``head_dim`` today.
     """
-    b = preferred
-    while b >= 128:
-        if length % b == 0:
-            return b
-        b //= 2
-    return 0
+    del head_dim
+    if length > MAX_LENGTH:
+        return 0
+    return next((b for b in (512, 256, 128) if length % b == 0), 0)

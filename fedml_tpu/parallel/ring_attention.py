@@ -212,11 +212,11 @@ def ring_flash_attention(
     axis_size = lax.psum(1, axis_name)
     my_idx = lax.axis_index(axis_name)
     L = q.shape[0]
-    b = block or pick_block(L)
+    b = block or pick_block(L, q.shape[-1])
     if not b:
         raise ValueError(
-            f"shard length {L} has no >=128 power-of-two block; use the "
-            "lax ring_attention"
+            f"shard length {L} has no block the flash kernels take "
+            "(pick_block); use the lax ring_attention"
         )
 
     def flash(qq, kk, vv, c):

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from fedml_tpu.models.transformer import transformer_lm
+from fedml_tpu.models.transformer import lax_attention, transformer_lm
 
 PyTree = Any
 
@@ -106,9 +106,11 @@ def tensor_parallel_lm(
     params KEEP the TP sharding — XLA inserts the psums for the
     row-parallel matmuls in both passes.
     """
+    # heads are sharded over ``axis`` by GSPMD: the lax attention, which
+    # XLA can partition (a pallas_call it cannot)
     bundle = transformer_lm(
         vocab_size=vocab_size, embed_dim=embed_dim, num_heads=num_heads,
-        num_layers=num_layers, seq_len=seq_len,
+        num_layers=num_layers, seq_len=seq_len, attn_fn=lax_attention,
     )
 
     def shard_params(variables: PyTree) -> PyTree:

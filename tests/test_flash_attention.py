@@ -15,12 +15,26 @@ def _qkv(L=64, H=2, D=16, seed=0):
     return mk(), mk(), mk()
 
 
+# (L, H, D, block_q, block_k).  Head size 64 runs two heads in one
+# 128-lane block; blocks smaller than L make the kernels' own loops walk
+# wholly visible, diagonal and (causal) skipped blocks, and block_q !=
+# block_k moves the diagonal off the block corners.
+SHAPES = [
+    pytest.param((64, 2, 16, 16, 16), id="d16"),
+    pytest.param((256, 2, 64, 128, 128), id="d64_two_heads_a_block"),
+    pytest.param((256, 4, 64, 64, 128), id="d64_wide_kv_blocks"),
+    pytest.param((256, 4, 64, 128, 64), id="d64_wide_q_blocks"),
+]
+
+
+@pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_dense(causal):
-    q, k, v = _qkv()
+def test_flash_matches_dense(causal, shape):
+    L, H, D, block_q, block_k = shape
+    q, k, v = _qkv(L, H, D)
     want = dense_attention(q, k, v, causal=causal)
-    got = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16,
-                          interpret=True)
+    got = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                          block_k=block_k, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -58,11 +72,15 @@ def test_flash_attn_fn_plugs_into_transformer():
                                rtol=3e-4, atol=3e-4)
 
 
+@pytest.mark.parametrize("shape", [
+    pytest.param((32, 2, 8, 8, 8), id="d8"),
+    *SHAPES[1:],
+])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_gradients_match_dense(causal):
-    """The custom O(L)-memory backward must produce the same dq/dk/dv as
+def test_flash_gradients_match_dense(causal, shape):
+    """The dQ and dK/dV kernels must produce the same dq/dk/dv as
     differentiating dense softmax attention."""
-    L, H, D = 32, 2, 8
+    L, H, D, block_q, block_k = shape
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(k1, (L, H, D), jnp.float32)
     k = jax.random.normal(k2, (L, H, D), jnp.float32)
@@ -70,8 +88,8 @@ def test_flash_gradients_match_dense(causal):
     cot = jax.random.normal(k4, (L, H, D), jnp.float32)
 
     def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, causal=causal, block_q=8, block_k=8,
-                              interpret=True)
+        out = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                              block_k=block_k, interpret=True)
         return (out * cot).sum()
 
     def loss_dense(q, k, v):
@@ -84,6 +102,50 @@ def test_flash_gradients_match_dense(causal):
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
             err_msg=f"{name} diverged from dense-attention gradient",
         )
+
+
+def test_default_attn_policy(monkeypatch):
+    """What ``_default_attn`` sends to the kernels: nothing on the CPU;
+    on a TPU 16-bit inputs at any length a block divides, float32 only
+    from L = 2048 (below it the lax path, which is the benchmark
+    reference's attention), ragged lengths never."""
+    from jax.experimental import pallas as pl
+
+    from fedml_tpu.models.transformer import _default_attn
+    from fedml_tpu.ops.flash_attention import head_group, pick_block
+
+    traced = []  # as chip_smoke.Watch records them
+    real = pl.pallas_call
+
+    def recording_pallas_call(kernel, *args, **kwargs):
+        traced.append(kernel.func.__name__)
+        return real(kernel, *args, **kwargs)
+
+    monkeypatch.setattr(pl, "pallas_call", recording_pallas_call)
+
+    def kernels(L, H, D, dtype):
+        del traced[:]
+        x = jax.ShapeDtypeStruct((L, H, D), dtype)
+        jax.eval_shape(lambda q, k, v: jax.grad(
+            lambda q: _default_attn(q, k, v, True).astype(jnp.float32).sum()
+        )(q), x, x, x)
+        return list(traced)
+
+    assert kernels(1024, 20, 64, jnp.bfloat16) == []      # the CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fused = ["_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
+    assert kernels(1024, 20, 64, jnp.bfloat16) == fused
+    assert kernels(1024, 20, 64, jnp.float32) == []
+    assert kernels(2048, 10, 128, jnp.float32) == fused
+    assert kernels(1000, 20, 64, jnp.bfloat16) == []      # ragged
+    assert kernels(1024, 25, 64, jnp.bfloat16) == []      # 1600 lanes: no
+    # whole number of 128-lane blocks of two heads
+
+    for L, D in ((1024, 64), (2048, 128), (8192, 128)):
+        assert pick_block(L, D) and L % pick_block(L, D) == 0
+    assert pick_block(1000, 64) == 0
+    assert head_group(20, 64) == 2 and head_group(10, 128) == 1
+    assert head_group(25, 64) == 0 and head_group(12, 96) == 0
 
 
 def test_flash_trains_through_local_update():

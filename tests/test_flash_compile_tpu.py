@@ -1,0 +1,75 @@
+"""The flash kernels compiled for a v5e that is described, not attached:
+what Mosaic refuses (a block off the tiling, a transpose it cannot lower,
+more VMEM than a kernel may use) shows here and not on the chip.
+Interpret mode accepts all of that, so the other flash tests cannot.
+
+The topology is described inside a fixture, never at import (one process
+at a time may load the TPU's library; see the on-chip-measurement guide),
+and every test of the kind lives in this one file."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from fedml_tpu.ops.flash_attention import (
+    MAX_LENGTH, flash_attention, pick_block,
+)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a TPU executable written to the persistent cache cannot be read back
+    # without a chip: keep these compiles out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+# (shape, dtype): the benchmark cells' own call (gpt2-large: a batch of 8
+# under the model's vmap, two heads to a 128-lane block); width 1280 / 10
+# at the lengths of chip_smoke.py's flash leg; float32, which the policy
+# sends here from 2048 on; the longest sequence pick_block accepts
+CASES = [
+    pytest.param((8, 1024, 20, 64), jnp.bfloat16, id="cells_b8_L1024_h20_d64"),
+    pytest.param((2048, 10, 128), jnp.bfloat16, id="L2048_h10_d128"),
+    pytest.param((8192, 10, 128), jnp.bfloat16, id="L8192_h10_d128"),
+    pytest.param((2048, 10, 128), jnp.float32, id="L2048_float32"),
+    pytest.param((MAX_LENGTH, 2, 128), jnp.float32, id="max_length_float32"),
+]
+
+
+@pytest.mark.parametrize("shape, dtype", CASES)
+def test_forward_and_backward_compile_for_v5e(one_chip, shape, dtype):
+    block = pick_block(shape[-3], shape[-1])
+    assert block
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block,
+                               block_k=block)
+
+    if len(shape) == 4:
+        attn = jax.vmap(attn)
+
+    def grads(q, k, v, do):
+        return jax.grad(lambda q, k, v: (
+            attn(q, k, v).astype(jnp.float32) * do.astype(jnp.float32)
+        ).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = jax.jit(grads).lower(x, x, x, x).compile().as_text()
+    # forward, dQ and dK/dV, each a Mosaic kernel
+    assert text.count("tpu_custom_call") == 3

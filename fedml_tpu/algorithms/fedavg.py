@@ -7,10 +7,11 @@ messages arrive; clients run ``MyModelTrainer.train`` epoch loops.
 
 Here one round is ONE compiled program:
 
-    clients' local scans (vmap over a packed client axis, shard_map over
-    the ``clients`` mesh axis)  →  masked weighted tree-average
-    (einsum over the packed axis + ``lax.psum`` over the mesh axis)  →
-    server update hook.
+    clients' local scans (a sequential scan or a vmap over a packed
+    client axis, shard_map over the ``clients`` mesh axis)  →  masked
+    weighted tree-average (a running sum carried by the sequential scan,
+    or an einsum over the packed axis where the stack is kept, +
+    ``lax.psum`` over the mesh axis)  →  server update hook.
 
 Client subsampling is a participation mask folded into the weights, so
 unsampled clients cost zero gradient and no control-flow divergence —
@@ -106,11 +107,17 @@ def make_round_fn(
     aggregation plugs into (norm clipping / weak-DP noise run per-client
     before the sum, inside the same compiled program).
 
-    ``client_unroll`` unrolls the sequential client loop (``lax.map``
+    ``client_unroll`` unrolls the sequential client loop (a ``lax.scan``
     lowers to a while loop; its scalar-core bookkeeping is measurable
     next to small per-client bodies) — trades compiled-code size for
     fewer loop iterations, like the step-scan ``unroll`` inside
     ``make_local_update``.
+
+    The sequential loop carries the fp32 weighted sum (clients 0..K-1 in
+    order) and never holds more than one trained client model.  The
+    ``[K, ...]`` stack of all of them exists only for what reads it
+    whole: ``client_axis_impl="vmap"``, a ``codec``, an
+    ``aggregate_transform``, an ``aggregate_impl``.
 
     ``codec`` (a ``fedml_tpu.compress`` LeafCodec) simulates the lossy
     uplink INSIDE the compiled round: each client's update
@@ -134,6 +141,12 @@ def make_round_fn(
         )
     if codec is not None:
         from fedml_tpu.compress import COMPRESS_STREAM, roundtrip_tree
+    # what reads every client's trained model at once keeps the [K, ...]
+    # stack; without any of it the weighted sum is the client loop's carry
+    needs_stack = (
+        client_axis_impl == "vmap" or codec is not None
+        or aggregate_transform is not None or aggregate_impl is not None
+    )
 
     def round_fn(state: ServerState, x, y, mask, num_samples, participation, slot_ids):
         with jax.named_scope(scopes.ROUND):
@@ -147,26 +160,48 @@ def make_round_fn(
             k_agg = jax.random.fold_in(k_round, 1)
             client_rngs = jax.vmap(lambda i: jax.random.fold_in(k_train, i))(slot_ids)
             # Model sync = SPMD replication (no explicit send).  Client-axis
-            # mapping: sequential lax.map keeps each client's convs at full
+            # mapping: a sequential scan keeps each client's convs at full
             # MXU tile sizes (measured ~7x faster than vmap for ResNet-56 on
             # one v5e chip); vmap remains available for many tiny clients.
             def run_one(cx, cy, cm, ck):
                 with jax.named_scope(scopes.LOCAL_UPDATE):
                     return local_update(state.variables, cx, cy, cm, ck)
 
+            with jax.named_scope(scopes.AGGREGATE):
+                weights = participation * num_samples  # sample-weighted, masked
+
             with jax.named_scope(scopes.CLIENTS):
                 if client_axis_impl == "vmap":
                     client_vars, client_metrics = jax.vmap(run_one)(x, y, mask, client_rngs)
-                elif client_unroll > 1:
-                    # lax.map is scan-without-carry; express it as such to get
+                elif needs_stack:
+                    # lax.map is scan-without-carry; written as such for
                     # scan's unroll knob (lax.map grew batch_size, not unroll)
                     client_vars, client_metrics = jax.lax.scan(
                         lambda c, args: (c, run_one(*args)),
                         (), (x, y, mask, client_rngs), unroll=client_unroll,
                     )[1]
                 else:
-                    client_vars, client_metrics = jax.lax.map(
-                        lambda args: run_one(*args), (x, y, mask, client_rngs)
+                    # nothing reads all clients' models at once: the carry
+                    # is the fp32 weighted sum, clients 0..K-1 in sequence,
+                    # so no [K, ...] stack of trained models is ever live
+                    def fold(acc, args):
+                        *client_args, w = args
+                        cvars, cmetrics = run_one(*client_args)
+                        with jax.named_scope(scopes.AGGREGATE):
+                            acc = jax.tree_util.tree_map(
+                                lambda a, l: a + w * l.astype(jnp.float32),
+                                acc, cvars,
+                            )
+                        return acc, cmetrics
+
+                    num, client_metrics = jax.lax.scan(
+                        fold,
+                        jax.tree_util.tree_map(
+                            lambda l: jnp.zeros(l.shape, jnp.float32),
+                            state.variables,
+                        ),
+                        (x, y, mask, client_rngs, weights),
+                        unroll=client_unroll,
                     )
 
             residuals = state.residuals
@@ -224,8 +259,6 @@ def make_round_fn(
                             lambda c, r: lossy_one(c, r, None)
                         )(client_vars, comp_rngs)
 
-            with jax.named_scope(scopes.AGGREGATE):
-                weights = participation * num_samples  # sample-weighted, masked
             if aggregate_transform is not None:
                 with jax.named_scope(scopes.AGG_TRANSFORM):
                     # per-client keys from GLOBAL slot ids: independent noise per
@@ -245,13 +278,14 @@ def make_round_fn(
                     # reassociates the fp32 reduction and breaks the
                     # sharded-vs-replicated sha256 parity pins
                     num = aggregate_impl(weights, client_vars)
-                else:
+                elif needs_stack:
                     num = jax.tree_util.tree_map(
                         lambda leaf: jnp.einsum(
                             "k,k...->...", weights, leaf.astype(jnp.float32)
                         ),
                         client_vars,
                     )
+                # else: num is the client loop's carry
                 den = weights.sum()
                 n_participants = participation.sum()
                 if axis_name is not None:

@@ -10,11 +10,11 @@ backward.  Read by ``benchmark/fed_scopes.py`` and
 
 ROUNDS = "fed.rounds"  # make_multi_round_fn: the scan over fused rounds
 ROUND = "fed.round"  # round_fn: the whole round, key folding included
-CLIENTS = "fed.clients"  # the client map: the [K, ...] stack around the updates
+CLIENTS = "fed.clients"  # the client loop around the updates (and the [K, ...] stack, where kept)
 LOCAL_UPDATE = "fed.local_update"  # one client's local_update call
 CODEC = "fed.codec"  # the lossy-uplink round trip and its error feedback
 AGG_TRANSFORM = "fed.agg_transform"  # aggregate_transform with its keys
-AGGREGATE = "fed.aggregate"  # weights, weighted sum, psums, guarded divide
+AGGREGATE = "fed.aggregate"  # weights, weighted sum (in the client loop: a multiply-add a client), psums, guarded divide
 SERVER_UPDATE = "fed.server_update"  # server_update(old, agg, opt_state)
 METRICS = "fed.metrics"  # the train_metrics sums and their psum
 SHUFFLE = "fed.shuffle"  # an epoch's permutation gather and augment_fn

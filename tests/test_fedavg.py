@@ -573,3 +573,128 @@ def test_prebuilt_shard_map_kernel_refuses_on_device_sampling():
     with pytest.raises(ValueError, match="shard_map"):
         make_scheduled_multi_round_fn(None, drop_prob=0.5, round_fn=sharded)
     make_scheduled_multi_round_fn(None, round_fn=sharded)
+
+
+# --- the weighted sum folded into the client loop (ISSUE 27) -----------------
+
+_FOLD_K = 3
+
+
+def _fold_setup():
+    """K = 3 clients of unequal size on a linear model, fp32."""
+    from fedml_tpu.algorithms.fedavg import ServerState
+    from fedml_tpu.core.client import make_client_optimizer, make_local_update
+
+    bundle = logistic_regression(16, 4)
+    lu = make_local_update(bundle, make_client_optimizer("sgd", 0.1), epochs=1)
+    key = jax.random.PRNGKey(5)
+    state = ServerState(bundle.init(key), (), jnp.zeros((), jnp.int32), key)
+    steps, b = 2, 5
+    kx, ky = jax.random.split(jax.random.PRNGKey(6))
+    args = (
+        jax.random.normal(kx, (_FOLD_K, steps, b, 16), jnp.float32),
+        jax.random.randint(ky, (_FOLD_K, steps, b), 0, 4),
+        jnp.ones((_FOLD_K, steps, b), jnp.float32),
+        jnp.asarray((30, 50, 20), jnp.float32),
+    )
+    return lu, state, args, jnp.arange(_FOLD_K, dtype=jnp.int32)
+
+
+@pytest.mark.parametrize("part", [(1.0, 0.0, 1.0), (1.0, 1.0, 1.0)],
+                         ids=["one_dropped", "all_report"])
+def test_folded_round_equals_stacked_einsum_and_sequential_sum(part):
+    """The client loop's carry is the weighted sum: equal to the stacked
+    einsum to rounding, and bit for bit to acc += w_k * v_k in client
+    order.  A dropped client weighs nothing."""
+    from fedml_tpu.algorithms.fedavg import make_round_fn
+
+    lu, state, args, slot_ids = _fold_setup()
+    part = jnp.asarray(part)
+    new_state, metrics = jax.jit(make_round_fn(lu))(
+        state, *args, part, slot_ids)
+    assert float(metrics["participants"]) == float(part.sum())
+
+    # each client's trained model, from local_update with the round's keys
+    k_train = jax.random.fold_in(jax.random.fold_in(state.key, 0), 0)
+    x, y, mask, num_samples = args
+    one = jax.jit(lambda *a: lu(*a)[0])
+    trained = [
+        one(state.variables, x[k], y[k], mask[k],
+            jax.random.fold_in(k_train, k))
+        for k in range(_FOLD_K)
+    ]
+    w = np.asarray(part, np.float32) * np.asarray(num_samples, np.float32)
+    den = np.float32(w.sum())
+    # compiled, as the round is: XLA's CPU backend contracts a + w * v into
+    # one fused multiply-add, which numpy's two roundings do not reproduce
+    step = jax.jit(lambda acc, w_k, v_k: acc + w_k * v_k)
+    leaves = [jax.tree_util.tree_leaves(t) for t in trained]
+    order_shows = False
+    for i, got in enumerate(jax.tree_util.tree_leaves(new_state.variables)):
+        rows = np.stack([np.asarray(l[i], np.float32) for l in leaves])
+        stacked = np.einsum("k,k...->...", w, rows) / den
+        np.testing.assert_allclose(np.asarray(got), stacked, rtol=1e-6)
+
+        def seq_sum(order):
+            acc = np.zeros(rows.shape[1:], np.float32)
+            for k in order:
+                acc = np.asarray(step(acc, w[k], rows[k]))
+            return acc
+
+        acc = seq_sum(range(_FOLD_K))
+        np.testing.assert_array_equal(np.asarray(got), acc / den)
+        order_shows |= bool((seq_sum(reversed(range(_FOLD_K))) != acc).any())
+    # the pin is sharp: clients taken in another order give another sum
+    assert order_shows
+
+
+def _lower_fold_case(case):
+    from fedml_tpu.algorithms.fedavg import make_round_fn
+    from fedml_tpu.compress import get_codec
+    from fedml_tpu.parallel.spmd import make_client_mesh, make_spmd_round_fn
+
+    lu, state, args, slot_ids = _fold_setup()
+    if case == "spmd_axis_name":
+        fn = make_spmd_round_fn(make_client_mesh(1), lu)
+    else:
+        fn = jax.jit(make_round_fn(lu, **{
+            "plain": {},
+            "client_unroll": {"client_unroll": 2},
+            "codec": {"codec": get_codec("int8")},
+            "aggregate_transform": {
+                "aggregate_transform": lambda old, stacked, w, rngs: stacked},
+            "vmap": {"client_axis_impl": "vmap"},
+        }[case]))
+    text = fn.lower(state, *args, jnp.ones(_FOLD_K), slot_ids).as_text()
+    # (leaves whose fp32 [K, ...] stack is a tensor of the program, all leaves)
+    shapes = [l.shape for l in jax.tree_util.tree_leaves(state.variables)]
+    return [
+        shape for shape in shapes
+        if "tensor<%sxf32>" % "x".join(map(str, (_FOLD_K,) + shape)) in text
+    ], shapes
+
+
+@pytest.mark.parametrize("case", ["plain", "client_unroll", "spmd_axis_name"])
+def test_folded_round_holds_no_client_stack(case):
+    stacked, _ = _lower_fold_case(case)
+    assert stacked == []
+
+
+@pytest.mark.parametrize("case", ["codec", "aggregate_transform", "vmap"])
+def test_stack_stays_for_what_reads_it_whole(case):
+    stacked, every_leaf = _lower_fold_case(case)
+    assert stacked == every_leaf
+
+
+def test_folded_round_all_clients_dropped_is_a_no_op():
+    from fedml_tpu.algorithms.fedavg import make_round_fn
+
+    lu, state, args, slot_ids = _fold_setup()
+    new_state, metrics = jax.jit(make_round_fn(lu))(
+        state, *args, jnp.zeros(_FOLD_K), slot_ids)
+    assert float(metrics["participants"]) == 0.0
+    assert int(new_state.round_idx) == 1
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+        new_state.variables, state.variables,
+    )

@@ -5,7 +5,8 @@ Interpret mode accepts all of that, so the other flash tests cannot.
 
 The topology is described inside a fixture, never at import (one process
 at a time may load the TPU's library; see the on-chip-measurement guide),
-and every test of the kind lives in this one file."""
+and every test of the kind lives in this one file: so also the memory pin
+of the round whose client loop carries the weighted sum (ISSUE 27)."""
 
 import os
 
@@ -73,3 +74,48 @@ def test_forward_and_backward_compile_for_v5e(one_chip, shape, dtype):
     text = jax.jit(grads).lower(x, x, x, x).compile().as_text()
     # forward, dQ and dK/dV, each a Mosaic kernel
     assert text.count("tpu_custom_call") == 3
+
+
+def test_folded_round_peak_is_below_the_stacked_rounds_by_two_models(one_chip):
+    """K = 4 clients on one chip: the fused round whose client loop carries
+    the weighted sum never holds the fp32 ``[K, ...]`` stack of trained
+    models that an (identity) ``aggregate_transform`` keeps.  The compiler's
+    own peak must show it, by two parameter trees at least (at the
+    benchmark cell's sizes it showed three)."""
+    from fedml_tpu.algorithms.fedavg import (
+        ServerState, make_multi_round_fn,
+    )
+    from fedml_tpu.core.client import make_client_optimizer, make_local_update
+    from fedml_tpu.models.transformer import transformer_lm
+
+    k, steps, batch, seq = 4, 2, 2, 128
+    bundle = transformer_lm(vocab_size=4096, embed_dim=256, num_heads=4,
+                            num_layers=2, seq_len=seq)
+    lu = make_local_update(bundle, make_client_optimizer("sgd", 3e-4),
+                           epochs=1, compute_dtype=jnp.bfloat16)
+    shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    key = jax.random.PRNGKey(0)
+    state = shaped(jax.eval_shape(
+        lambda: ServerState(bundle.init(key), (), jnp.zeros((), jnp.int32),
+                            key)))
+    tree_bytes = sum(4 * l.size
+                     for l in jax.tree_util.tree_leaves(state.variables))
+    spec = jax.ShapeDtypeStruct
+    block = shaped((
+        spec((k, steps, batch, seq), jnp.int32),
+        spec((k, steps, batch, seq), jnp.int32),
+        spec((k, steps, batch), jnp.float32),
+        spec((k,), jnp.float32), spec((k,), jnp.float32),
+        spec((k,), jnp.int32),
+    ))
+
+    def peak(**round_kw):
+        fn = jax.jit(make_multi_round_fn(lu, 1, **round_kw))
+        return fn.lower(state, *block).compile(
+        ).memory_analysis().peak_memory_in_bytes
+
+    stacked = peak(aggregate_transform=lambda old, stack, w, rngs: stack)
+    folded = peak()
+    assert stacked - folded >= 2 * tree_bytes, (stacked, folded, tree_bytes)

@@ -1,6 +1,6 @@
 """Entry shim — federated transformer fine-tuning (beyond-reference
-long-context family; ``--tp_degree N`` runs DP x TP on a (clients,
-model) device mesh)."""
+long-context family; ``--mesh dp,mp`` runs DP x TP on a (dp, mp)
+device mesh)."""
 
 import sys
 

@@ -84,13 +84,12 @@ class ExperimentConfig:
     embed_dim: int = 64
     num_heads: int = 4
     num_layers: int = 2
-    tp_degree: int = 1  # >1: DP x TP on a (clients, model) device mesh
     sp_degree: int = 1  # >1: DP x SP — long-context clients, ring attention
     # rule-driven sharding engine (fedml_tpu/parallel/partition.py):
     # --mesh "dp,mp" (also "dp=4,mp=2" / "auto,2") lays the cohort over
     # dp and the model over mp in ONE jit step; --partition_rules picks
     # the (regex -> PartitionSpec) table: a canonical name (fedllm,
-    # resnet) or a JSON rule file.  Exclusive with tp/sp_degree;
+    # resnet) or a JSON rule file.  Exclusive with sp_degree;
     # composes with compress/compress_ef (the residual store shards
     # client rows over dp).
     mesh: str = ""
@@ -167,10 +166,10 @@ def _run_fedllm(cfg: ExperimentConfig, ds, t0, log_fn, metrics=None) -> dict:
     """Federated transformer fine-tuning over token sequences (the
     long-context family the reference lacks).  Three drivers:
 
-    - ``tp_degree == sp_degree == 1``: the standard simulation driver;
-    - ``tp_degree > 1``: DP x TP on a (clients, model) mesh
-      (``parallel/gspmd.py``), transformer Megatron-sharded inside
-      every client;
+    - neither ``mesh`` nor ``sp_degree``: the standard simulation driver;
+    - ``mesh``: DP x TP on a (dp, mp) mesh, cohort over ``dp`` and the
+      transformer laid out over ``mp`` by the rule table
+      (``parallel/partition.py``) inside every client;
     - ``sp_degree > 1``: DP x SP on a (clients, sp) mesh
       (``parallel/dp_sp.py``), each client's sequences sharded with
       ring attention — federated long-context fine-tuning.
@@ -183,22 +182,22 @@ def _run_fedllm(cfg: ExperimentConfig, ds, t0, log_fn, metrics=None) -> dict:
 
     seq_len = int(ds.train_x.shape[1])
     vocab = max(int(ds.num_classes), int(ds.train_x.max()) + 1)
-    # the rule engine and DP x TP shard the model (and the cohort) by
-    # GSPMD, where a pallas_call has no partitioning rule: those meshes
-    # keep the lax attention, every other driver the model's own policy
-    gspmd = bool(cfg.mesh) or cfg.tp_degree > 1
+    # the rule engine shards the model (and the cohort) by GSPMD, where
+    # a pallas_call has no partitioning rule: its mesh keeps the lax
+    # attention, every other driver the model's own policy
     bundle = transformer_lm(
         vocab_size=vocab, embed_dim=cfg.embed_dim, num_heads=cfg.num_heads,
         num_layers=cfg.num_layers, seq_len=seq_len,
-        attn_fn=lax_attention if gspmd else None,
+        attn_fn=lax_attention if cfg.mesh else None,
     )
 
-    if cfg.mesh and (cfg.tp_degree > 1 or cfg.sp_degree > 1):
+    if cfg.mesh and cfg.sp_degree > 1:
         raise ValueError(
-            "--mesh is the rule-driven sharding engine and is exclusive "
-            "with tp_degree/sp_degree (those pick the heuristic meshes)"
+            "--mesh (dp x mp, the rule-driven sharding engine) and "
+            "sp_degree (dp x sp, ring attention) cannot both be set: a "
+            "3-D dp x mp x sp mesh is not wired up"
         )
-    if cfg.tp_degree <= 1 and cfg.sp_degree <= 1 and not cfg.mesh:
+    if cfg.sp_degree <= 1 and not cfg.mesh:
         from fedml_tpu.algorithms.fedavg import FedAvgConfig, FedAvgSimulation
 
         sim = FedAvgSimulation(bundle, ds, FedAvgConfig(
@@ -219,25 +218,19 @@ def _run_fedllm(cfg: ExperimentConfig, ds, t0, log_fn, metrics=None) -> dict:
     from fedml_tpu.core.client import make_client_optimizer, make_local_update
     from fedml_tpu.core.types import pack_clients
 
-    if cfg.tp_degree > 1 and cfg.sp_degree > 1:
-        raise ValueError(
-            "tp_degree and sp_degree cannot both exceed 1 (a 3-D "
-            "clients x model x sp mesh is not wired up)"
-        )
     K = min(cfg.client_num_per_round, ds.num_clients)
     if cfg.mesh:
         from fedml_tpu.parallel.mesh import mesh_from_spec
 
-        rule_mesh = mesh_from_spec(cfg.mesh)
-        dp = int(rule_mesh.shape["dp"])
+        mesh = mesh_from_spec(cfg.mesh)
+        dp = int(mesh.shape["dp"])
     else:
-        degree = cfg.tp_degree if cfg.tp_degree > 1 else cfg.sp_degree
-        if jax.device_count() % degree:
+        if jax.device_count() % cfg.sp_degree:
             raise ValueError(
-                f"parallel degree {degree} does not divide device count "
+                f"sp_degree {cfg.sp_degree} does not divide device count "
                 f"{jax.device_count()}"
             )
-        dp = jax.device_count() // degree
+        dp = jax.device_count() // cfg.sp_degree
     if K % dp:
         raise ValueError(f"cohort {K} not divisible by dp width {dp}")
     opt = make_client_optimizer(
@@ -280,31 +273,13 @@ def _run_fedllm(cfg: ExperimentConfig, ds, t0, log_fn, metrics=None) -> dict:
             residuals=residuals,
         )
         round_fn, shard_state, shard_data = make_rule_round_fn(
-            rule_mesh, lu, variables, table,
+            mesh, lu, variables, table,
             codec=codec, error_feedback=ef,
         )
         state = shard_state(state)
         # the unsharded tree init left on the first device would
         # otherwise stay there, whole, for the entire run
         del variables
-        mesh = rule_mesh
-    elif cfg.tp_degree > 1:
-        from fedml_tpu.parallel.gspmd import (
-            make_dp_tp_mesh, make_dp_tp_round_fn,
-        )
-
-        mesh = make_dp_tp_mesh(dp, cfg.tp_degree)
-        lu = make_local_update(
-            bundle, opt, epochs=cfg.epochs, compute_dtype=cdtype,
-        )
-        state = ServerState(
-            variables=bundle.init(key), opt_state=(),
-            round_idx=jnp.zeros((), jnp.int32), key=key,
-        )
-        round_fn, shard_state, shard_data = make_dp_tp_round_fn(
-            mesh, lu, state.variables
-        )
-        state = shard_state(state)
     else:
         # DP x SP: each client's sequences sharded over an sp axis with
         # ring attention — federated LONG-CONTEXT fine-tuning

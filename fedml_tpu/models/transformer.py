@@ -56,8 +56,8 @@ def _default_attn(q, k, v, causal):
       in interpret mode) and ragged lengths: the lax blockwise scan.
 
     The model cannot see a sharding: a caller whose heads GSPMD shards
-    passes ``lax_attention`` as ``attn_fn`` (``experiments/run.py``,
-    ``parallel/tensor.py``).
+    passes ``lax_attention`` as ``attn_fn`` (``experiments/run.py
+    --mesh``).
     """
     from fedml_tpu.ops.flash_attention import (
         flash_attention, head_group, pick_block,

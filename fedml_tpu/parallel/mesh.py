@@ -3,8 +3,8 @@ model tensors on ``mp``.
 
 Every other mesh in ``parallel/`` is special-cased to its consumer —
 ``spmd.make_1d_mesh`` (clients axis for shard_map rounds),
-``gspmd.make_dp_tp_mesh`` (clients×model for the cross-silo round
-engine).  This module is the user-facing knob: ONE ``--mesh dp,mp``
+``dp_sp.make_dp_sp_mesh`` (clients×sequence for ring attention).
+This module is the user-facing knob: ONE ``--mesh dp,mp``
 string parsed once and handed to the partition-rule engine
 (``parallel/partition.py``), which lays the fedllm model over ``mp``
 and the virtual-client cohort (the vmap axis of the PR-10 muxed

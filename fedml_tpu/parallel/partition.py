@@ -1,9 +1,8 @@
 """Partition-rule sharding engine: ordered ``(regex → PartitionSpec)``
 tables matched against param-tree path names.
 
-The per-consumer sharding heuristics in ``parallel/tensor.py``
-(``tp_param_spec``) and ``parallel/gspmd.py`` hard-code ONE layout for
-ONE model family.  This module replaces them with data: a rule table is
+This module is the one place that knows which leaf of a model goes on
+which mesh axis, and it knows it as data: a rule table is
 an ordered list of ``(pattern, spec)`` pairs; each leaf's '/'-joined
 path (``params/Block_0/Dense_1/kernel``) is matched with ``re.search``
 and the FIRST matching rule wins — the fmengine/EasyLM lineage of
@@ -21,9 +20,10 @@ JSON (``resolve_rules``).
 On top of the matcher sit the appliers: ``shard_by_rules`` lays a
 pytree out on a ``(dp, mp)`` mesh (``parallel/mesh.py``);
 ``server_state_sharding`` extends the plan to the full
-``ServerState`` — optimizer moments via the generalized
-``gspmd.opt_state_sharding_like`` and the EF residual store with its
-leading client axis on ``dp``; ``make_rule_round_fn`` jits the FedAvg
+``ServerState`` — optimizer moments via the shape-matching
+``opt_state_sharding_like`` and the EF residual store with its
+leading client axis on ``dp``; ``make_rule_round_fn``, the only round
+that shards a model by GSPMD, jits the FedAvg
 round with the packed client block over ``dp`` and the model over
 ``mp``; ``cohort_shardings`` produces the sharding tuple the muxed
 cohort engine (``algorithms/fedavg_mux.py``) feeds to
@@ -143,9 +143,11 @@ def resolve_rules(name_or_path: str) -> RuleTable:
 
 
 def _leaf_path(path) -> str:
-    from fedml_tpu.parallel.tensor import _path_names
-
-    return "/".join(_path_names(path))
+    """'/'-joined names of a ``tree_util`` key path: a dict key, a
+    sequence index, anything else as it prints."""
+    return "/".join(
+        str(getattr(k, "key", getattr(k, "idx", k))) for k in path
+    )
 
 
 def _spec_of(dims: Sequence):
@@ -310,27 +312,51 @@ def jit_sharded(fn, *, in_shardings=None, out_shardings=None, **jit_kwargs):
 
 # --- ServerState / round-engine integration ---------------------------------
 
+def opt_state_sharding_like(mesh, variables_template: PyTree,
+                            opt_state_template: PyTree,
+                            pspec: PyTree) -> PyTree:
+    """Sharding tree for server-optimizer state whose leaves mirror the
+    parameters (FedAdam/FedYogi moments): each opt leaf with the shape
+    of some param leaf inherits that param's spec from ``pspec`` (the
+    rule-derived spec tree of ``variables_template``); everything else
+    (counts, scalars) is replicated.  Shape-based matching is a
+    heuristic — two same-shaped params with different specs resolve to
+    whichever appears first, which only changes layout, not values."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    shape_to_spec = {}
+    for leaf, spec in zip(
+        jax.tree_util.tree_leaves(variables_template),
+        jax.tree_util.tree_leaves(pspec, is_leaf=lambda x: isinstance(x, P)),
+    ):
+        shape_to_spec.setdefault(np.shape(leaf), spec)
+    return jax.tree_util.tree_map(
+        lambda l: NamedSharding(mesh, shape_to_spec.get(np.shape(l), P())),
+        opt_state_template,
+    )
+
+
 def server_state_sharding(mesh, variables_template: PyTree,
                           table: RuleTable, *,
                           opt_state_template: Optional[PyTree] = None,
                           error_feedback: bool = False):
     """ServerState-shaped tree of shardings under ``table``: variables
     by rules, optimizer moments via the shape-matching
-    ``gspmd.opt_state_sharding_like`` reusing the SAME rule-derived
+    ``opt_state_sharding_like`` reusing the SAME rule-derived
     specs, EF residuals (leading ``[num_clients, ...]`` axis) with the
     client axis on ``dp`` and the param dims inheriting the param's
     spec.  Scalars (round_idx, key) replicate."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from fedml_tpu.algorithms.fedavg import ServerState
-    from fedml_tpu.parallel.gspmd import opt_state_sharding_like
 
     specs = match_partition_rules(table, variables_template)
     var_sharding = named_sharding_tree(mesh, specs)
     repl = NamedSharding(mesh, P())
     if opt_state_template is not None:
         opt_sharding = opt_state_sharding_like(
-            mesh, variables_template, opt_state_template, pspec=specs
+            mesh, variables_template, opt_state_template, specs
         )
     else:
         opt_sharding = repl
@@ -363,35 +389,38 @@ def make_rule_round_fn(
     opt_state_template: Optional[PyTree] = None,
     codec=None,
     error_feedback: bool = False,
-    exact_aggregation: bool = True,
 ):
     """jit the FedAvg round on a ``(dp, mp)`` mesh with the packed
-    client block over ``dp`` and the model laid out by ``table``.
+    client block over ``dp`` and the model laid out by ``table``: the
+    one round that shards a model by GSPMD.
 
-    The rule-driven sibling of ``gspmd.make_dp_tp_round_fn``: same
-    round function (``make_round_fn(client_axis_impl="vmap")``, no
-    axis_name — GSPMD derives the cross-client reduce from the
-    annotations), but the layout comes from the table instead of the
-    transformer-only heuristic, and the in-engine compression path
+    The round function is ``make_round_fn(client_axis_impl="vmap")``
+    with no axis_name: the partitioner derives every collective —
+    client-parallel local scans, tensor-sharded matmuls inside each
+    client's forward and backward — from the annotations alone.  The
+    layout comes from the table, and the in-engine compression path
     (``codec`` name or LeafCodec, plus ``error_feedback``) keeps its
     residual store sharded — client rows on ``dp``, param dims like
-    the params.
+    the params.  When ``server_update`` carries parameter-sized
+    optimizer state (FedAdam moments), pass ``opt_state_template``:
+    the moments then follow the params' layout; without it
+    ``opt_state`` is replicated.
 
-    ``exact_aggregation`` (default on) makes the dp-sharded round
-    BIT-identical to the single-device one: the per-client heavy
-    compute stays sharded, but the cross-client weighted sum runs as
-    a shard_map'd REPLICATED einsum (every device gathers the update
-    stack and computes the full reduction locally, same shape → same
-    kernel → same bits as one device) and the tiny ``[K]`` weight
+    The dp-sharded round is BIT-identical to the single-device one in
+    fp32: the per-client heavy compute stays sharded, but the
+    cross-client weighted sum is an ordered scan over the gathered
+    update stack (``exact_agg`` below) and the tiny ``[K]`` weight
     vectors stay replicated throughout.  Left to the GSPMD
-    partitioner, the einsum may partial-sum the K axis per device —
-    reassociating the fp32 reduction and breaking the sha256 parity
+    partitioner, an einsum over K may partial-sum the axis per device
+    — reassociating the fp32 reduction and breaking the sha256 parity
     pins (a with_sharding_constraint on the operand is NOT enough;
     the partitioner may still split the reduction).  Costs an
-    all-gather of the update stack per round; set False at scale
-    where allclose is enough.
+    all-gather of the update stack per round.
 
-    Returns ``(round_fn, shard_state, shard_data)``.
+    Returns ``(round_fn, shard_state, shard_data)``:
+    ``shard_state(state)`` lays server state out on the mesh;
+    ``shard_data(arrays)`` places the packed client block.  The state
+    ``round_fn`` returns keeps the same shardings (donated input).
     """
     import jax
     import jax.numpy as jnp
@@ -404,42 +433,39 @@ def make_rule_round_fn(
         codec = get_codec(codec)
 
     repl = NamedSharding(mesh, P())
-    kwargs = {}
-    if exact_aggregation:
 
-        def exact_agg(w, cv):
-            # sequential scan over the K axis, NOT einsum: a reduction's
-            # accumulation strategy (lane splits, partial sums per
-            # device, horizontal adds) is a partitioner/fusion decision,
-            # so the "same" einsum can reassociate between the 1-device
-            # and SPMD lowerings (measured on CPU host meshes).  The
-            # scan carry chain is explicitly ordered, its xs interface
-            # MATERIALIZES the weighted update stack (a while-loop
-            # operand is a real buffer — fusions cannot duplicate the
-            # decode chain past it with different contraction choices,
-            # another measured 1-ulp source), and a sequential loop is
-            # not partitionable, so GSPMD all-gathers the stack and
-            # every device runs the identical full-K reduction.  A
-            # shard_map(P() -> P()) wrapper is NOT equivalent: its
-            # boundary changes the producer fusions and was measured to
-            # break bit-parity where this form holds it.
-            weighted = jax.tree_util.tree_map(
-                lambda l: w.reshape((-1,) + (1,) * (l.ndim - 1))
-                * l.astype(jnp.float32),
-                cv,
-            )
+    def exact_agg(w, cv):
+        # sequential scan over the K axis, NOT einsum: a reduction's
+        # accumulation strategy (lane splits, partial sums per
+        # device, horizontal adds) is a partitioner/fusion decision,
+        # so the "same" einsum can reassociate between the 1-device
+        # and SPMD lowerings (measured on CPU host meshes).  The
+        # scan carry chain is explicitly ordered, its xs interface
+        # MATERIALIZES the weighted update stack (a while-loop
+        # operand is a real buffer — fusions cannot duplicate the
+        # decode chain past it with different contraction choices,
+        # another measured 1-ulp source), and a sequential loop is
+        # not partitionable, so GSPMD all-gathers the stack and
+        # every device runs the identical full-K reduction.  A
+        # shard_map(P() -> P()) wrapper is NOT equivalent: its
+        # boundary changes the producer fusions and was measured to
+        # break bit-parity where this form holds it.
+        weighted = jax.tree_util.tree_map(
+            lambda l: w.reshape((-1,) + (1,) * (l.ndim - 1))
+            * l.astype(jnp.float32),
+            cv,
+        )
 
-            def body(acc, row):
-                return jax.tree_util.tree_map(jnp.add, acc, row), None
+        def body(acc, row):
+            return jax.tree_util.tree_map(jnp.add, acc, row), None
 
-            zeros = jax.tree_util.tree_map(
-                lambda l: jnp.zeros(l.shape[1:], jnp.float32), cv
-            )
-            acc, _ = jax.lax.scan(body, zeros, weighted)
-            return acc
+        zeros = jax.tree_util.tree_map(
+            lambda l: jnp.zeros(l.shape[1:], jnp.float32), cv
+        )
+        acc, _ = jax.lax.scan(body, zeros, weighted)
+        return acc
 
-        kwargs["aggregate_impl"] = exact_agg
-
+    kwargs = {"aggregate_impl": exact_agg}
     if server_update is not None:
         kwargs["server_update"] = server_update
     if codec is not None:
@@ -462,11 +488,10 @@ def make_rule_round_fn(
     data_sharding = NamedSharding(mesh, P(DP_AXIS))
     # (x, y, mask) carry the client compute and shard over dp; the [K]
     # scalar vectors (num_samples, participation, slot_ids) stay
-    # replicated in exact mode so weight products and their sums keep
-    # single-device reduction order
-    scalar_sharding = repl if exact_aggregation else data_sharding
+    # replicated so weight products and their sums keep single-device
+    # reduction order
     arg_shardings = (data_sharding, data_sharding, data_sharding,
-                     scalar_sharding, scalar_sharding, scalar_sharding)
+                     repl, repl, repl)
 
     def shard_state(state):
         return jax.device_put(state, state_sharding)

@@ -261,17 +261,18 @@ def test_run_experiment_fedllm_and_dp_tp():
         embed_dim=32, num_heads=4, num_layers=1, lr=0.1, ci=0,
     ), log_fn=None)
     assert len(out["history"]) == 2
-    # DP x TP path: 2-way DP x 4-way TP over the faked 8-device mesh
+    # DP x TP path: 4-way DP x 2-way TP over the faked 8-device mesh
+    # (the fedllm table shards the vocabulary over mp: 90 % 2 == 0)
     out2 = run_experiment(ExperimentConfig(
         algorithm="fedllm", dataset="fed_shakespeare", comm_round=2,
         client_num_in_total=4, client_num_per_round=4, batch_size=4,
-        embed_dim=32, num_heads=4, num_layers=1, lr=0.1, tp_degree=4,
+        embed_dim=32, num_heads=4, num_layers=1, lr=0.1, mesh="4,2",
     ), log_fn=None)
     assert len(out2["history"]) == 2
-    assert "mesh" in out2
+    assert "'dp': 4" in out2["mesh"] and "'mp': 2" in out2["mesh"]
     import numpy as np
     assert np.isfinite(out2["history"][-1]["loss_sum"])
-    # the tp path evaluates like the tp_degree==1 driver: both finals
+    # the tp path evaluates like the simulation driver: both finals
     # carry comparable test metrics
     assert np.isfinite(out["final"]["test_acc"])
     assert np.isfinite(out2["final"]["test_acc"])
@@ -301,6 +302,53 @@ def test_run_experiment_fedllm_dp_sp():
         run_experiment(ExperimentConfig(
             algorithm="fedllm", dataset="fed_shakespeare", comm_round=1,
             client_num_in_total=4, client_num_per_round=4, batch_size=4,
-            embed_dim=32, num_heads=4, num_layers=1, tp_degree=2,
+            embed_dim=32, num_heads=4, num_layers=1, mesh="4,2",
             sp_degree=2,
         ), log_fn=None)
+
+
+def _fedllm_mesh_2x4(**kw):
+    from fedml_tpu.experiments.run import ExperimentConfig
+
+    return ExperimentConfig(
+        algorithm="fedllm", dataset="fed_shakespeare", comm_round=1,
+        client_num_in_total=4, client_num_per_round=4, batch_size=4,
+        embed_dim=32, num_heads=4, num_layers=1, lr=0.1, mesh="2,4", **kw,
+    )
+
+
+def test_run_experiment_fedllm_mesh_refuses_undivided_vocab():
+    """The fedllm table shards ``wte`` over mp: a vocabulary mp does not
+    divide (fed_shakespeare's 90 over mp=4) is refused by name, not
+    padded."""
+    import pytest
+
+    from fedml_tpu.experiments.run import run_experiment
+
+    with pytest.raises(ValueError, match="wte/embedding"):
+        run_experiment(_fedllm_mesh_2x4(), log_fn=None)
+
+
+def test_run_experiment_fedllm_mesh_custom_rules_replicate_wte(tmp_path):
+    """...and runs when ``--partition_rules`` names the fedllm table
+    with its ``wte/embedding`` rule set to replicate."""
+    import json
+
+    import numpy as np
+
+    from fedml_tpu.experiments.run import run_experiment
+    from fedml_tpu.parallel.partition import FEDLLM_RULES
+
+    rules = [
+        [pat, [None, None] if pat == "wte/embedding" else list(dims)]
+        for pat, dims in FEDLLM_RULES.rules
+    ]
+    assert rules[0] == ["wte/embedding", [None, None]]
+    path = tmp_path / "fedllm_wte_replicated.json"
+    path.write_text(json.dumps({"rules": rules}))
+    out = run_experiment(
+        _fedllm_mesh_2x4(partition_rules=str(path)), log_fn=None
+    )
+    assert "'dp': 2" in out["mesh"] and "'mp': 4" in out["mesh"]
+    assert np.isfinite(out["final"]["loss_sum"])
+    assert np.isfinite(out["final"]["test_acc"])

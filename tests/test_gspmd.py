@@ -1,4 +1,5 @@
-"""DP×TP federated round on a 2-D (clients, model) mesh.
+"""DP×TP federated round on a 2-D (dp, mp) mesh: the rule engine's
+``make_rule_round_fn`` under the ``fedllm`` table.
 
 Oracle: the GSPMD-partitioned round equals the same round function run
 unsharded on one device (the parallelism-equivalence strategy of
@@ -15,7 +16,8 @@ from fedml_tpu.core.client import make_client_optimizer, make_local_update
 from fedml_tpu.core.types import pack_clients
 from fedml_tpu.data.shakespeare import load_fed_shakespeare
 from fedml_tpu.models.transformer import transformer_lm
-from fedml_tpu.parallel.gspmd import make_dp_tp_mesh, make_dp_tp_round_fn
+from fedml_tpu.parallel.mesh import make_dp_mp_mesh
+from fedml_tpu.parallel.partition import FEDLLM_RULES, make_rule_round_fn
 
 
 def _setup(num_clients=4, seq_len=80):
@@ -49,9 +51,9 @@ def test_dp_tp_round_matches_single_device():
     ref_fn = jax.jit(make_round_fn(local_update, client_axis_impl="vmap"))
     ref_state, ref_metrics = ref_fn(state, *[jnp.asarray(a) for a in args])
 
-    mesh = make_dp_tp_mesh(2, 4)  # 2-way client DP x 4-way TP
-    round_fn, shard_state, shard_data = make_dp_tp_round_fn(
-        mesh, local_update, state.variables
+    mesh = make_dp_mp_mesh(2, 4)  # 2-way client DP x 4-way TP
+    round_fn, shard_state, shard_data = make_rule_round_fn(
+        mesh, local_update, state.variables, FEDLLM_RULES
     )
     new_state, metrics = round_fn(shard_state(state), *shard_data(args))
 
@@ -71,17 +73,17 @@ def test_dp_tp_round_matches_single_device():
 
 def test_dp_tp_params_sharded_over_model_axis():
     _, local_update, state, args = _setup()
-    mesh = make_dp_tp_mesh(2, 4)
-    round_fn, shard_state, shard_data = make_dp_tp_round_fn(
-        mesh, local_update, state.variables
+    mesh = make_dp_mp_mesh(2, 4)
+    round_fn, shard_state, shard_data = make_rule_round_fn(
+        mesh, local_update, state.variables, FEDLLM_RULES
     )
     st = shard_state(state)
     qkv = st.variables["params"]["Block_0"]["MultiHeadAttention_0"]["Dense_0"]["kernel"]
-    assert qkv.sharding.spec == P(None, "model")
+    assert qkv.sharding.spec == P(None, "mp")
     # round output preserves the TP layout (no silent re-replication)
     new_state, _ = round_fn(st, *shard_data(args))
     qkv2 = new_state.variables["params"]["Block_0"]["MultiHeadAttention_0"]["Dense_0"]["kernel"]
-    assert qkv2.sharding.spec == P(None, "model")
+    assert qkv2.sharding.spec == P(None, "mp")
 
 
 def test_dp_tp_fedadam_server_opt_state_sharded():
@@ -90,7 +92,6 @@ def test_dp_tp_fedadam_server_opt_state_sharded():
     state)."""
     from fedml_tpu.algorithms.fedopt import make_fedopt_server_update
     from fedml_tpu.core.optrepo import get_server_optimizer
-    from fedml_tpu.parallel.gspmd import opt_state_sharding_like
 
     _, local_update, state, args = _setup()
     server_opt = get_server_optimizer("adam", lr=0.01)
@@ -99,14 +100,11 @@ def test_dp_tp_fedadam_server_opt_state_sharded():
         variables=state.variables, opt_state=opt_state,
         round_idx=state.round_idx, key=state.key,
     )
-    mesh = make_dp_tp_mesh(2, 4)
-    opt_sharding = opt_state_sharding_like(
-        mesh, state.variables, opt_state, axis="model"
-    )
-    round_fn, shard_state, shard_data = make_dp_tp_round_fn(
-        mesh, local_update, state.variables,
+    mesh = make_dp_mp_mesh(2, 4)
+    round_fn, shard_state, shard_data = make_rule_round_fn(
+        mesh, local_update, state.variables, FEDLLM_RULES,
         server_update=make_fedopt_server_update(server_opt),
-        opt_state_sharding=opt_sharding,
+        opt_state_template=opt_state,
     )
     st = shard_state(state)
     # find the adam mu for a column-parallel kernel and check its layout
@@ -116,7 +114,7 @@ def test_dp_tp_fedadam_server_opt_state_sharded():
             mu = s
             break
     assert mu is not None
-    assert mu.sharding.spec == P(None, "model")
+    assert mu.sharding.spec == P(None, "mp")
     new_state, metrics = round_fn(st, *shard_data(args))
     assert np.isfinite(float(metrics["loss_sum"]))
     assert int(new_state.round_idx) == 1

@@ -10,6 +10,15 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
+from fedml_tpu.algorithms.fedavg import ServerState
+from fedml_tpu.core.client import make_client_optimizer, make_local_update
+from fedml_tpu.models.transformer import lax_attention, transformer_lm
+from fedml_tpu.parallel.mesh import make_dp_mp_mesh
+from fedml_tpu.parallel.partition import (
+    FEDLLM_RULES,
+    make_rule_round_fn,
+    shard_by_rules,
+)
 from fedml_tpu.parallel.pipeline import (
     make_gpipe,
     make_pp_mesh,
@@ -17,62 +26,84 @@ from fedml_tpu.parallel.pipeline import (
     shard_stage_params,
     stack_stage_params,
 )
-from fedml_tpu.parallel.tensor import (
-    make_tp_mesh,
-    tensor_parallel_lm,
-    tp_param_spec,
-)
+
+
+def _tp_lm(num_layers):
+    """A (1, 4) mesh — tensor parallelism alone is the rule engine with
+    no cohort axis — and the LM whose heads GSPMD shards over ``mp``
+    (the lax attention: a pallas_call has no partitioning rule)."""
+    bundle = transformer_lm(
+        vocab_size=64, embed_dim=32, num_heads=4, num_layers=num_layers,
+        seq_len=16, attn_fn=lax_attention,
+    )
+    return make_dp_mp_mesh(1, 4), bundle
 
 
 def test_tensor_parallel_forward_matches_single_device():
-    mesh = make_tp_mesh(4)
-    bundle, shard_params, apply, _ = tensor_parallel_lm(
-        mesh, vocab_size=64, embed_dim=32, num_heads=4, num_layers=2,
-        seq_len=16,
-    )
+    mesh, bundle = _tp_lm(num_layers=2)
     variables = bundle.init(jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 64)
     ref = bundle.apply_eval(variables, tokens)
-    sharded_vars = shard_params(variables)
-    out = apply(sharded_vars, tokens)
+    sharded_vars, _ = shard_by_rules(mesh, variables, FEDLLM_RULES)
+    out = jax.jit(bundle.apply_eval)(sharded_vars, tokens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 
 
 def test_tp_params_actually_sharded():
-    mesh = make_tp_mesh(4)
-    bundle, shard_params, _, _ = tensor_parallel_lm(
-        mesh, vocab_size=64, embed_dim=32, num_heads=4, num_layers=1,
-        seq_len=16,
+    mesh, bundle = _tp_lm(num_layers=1)
+    variables, _ = shard_by_rules(
+        mesh, bundle.init(jax.random.PRNGKey(0)), FEDLLM_RULES
     )
-    variables = shard_params(bundle.init(jax.random.PRNGKey(0)))
     qkv = variables["params"]["Block_0"]["MultiHeadAttention_0"]["Dense_0"]["kernel"]
     mlp_down = variables["params"]["Block_0"]["Dense_1"]["kernel"]
-    assert qkv.sharding.spec == P(None, "tp")
-    assert mlp_down.sharding.spec == P("tp", None)
+    wte = variables["params"]["wte"]["embedding"]
+    assert qkv.sharding.spec == P(None, "mp")
+    assert mlp_down.sharding.spec == P("mp", None)
+    # the table shards the vocabulary too (the tied head's matmul is
+    # row-parallel for free)
+    assert wte.sharding.spec == P("mp", None)
     assert len(qkv.sharding.device_set) == 4
     # each device holds a quarter of the column-parallel kernel
     shard_shapes = {s.data.shape for s in qkv.addressable_shards}
     assert shard_shapes == {(32, 96 // 4)}
+    assert {s.data.shape for s in wte.addressable_shards} == {(64 // 4, 32)}
 
 
 def test_tp_train_step_learns_and_keeps_sharding():
-    mesh = make_tp_mesh(4)
-    bundle, shard_params, _, train_step = tensor_parallel_lm(
-        mesh, vocab_size=64, embed_dim=32, num_heads=4, num_layers=1,
-        seq_len=16,
+    """A tensor-parallel training step is a round on a (1, n) mesh: one
+    client, one SGD step a round."""
+    mesh, bundle = _tp_lm(num_layers=1)
+    local_update = make_local_update(
+        bundle, make_client_optimizer("sgd", 0.5), epochs=1
     )
-    variables = shard_params(bundle.init(jax.random.PRNGKey(0)))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-    targets = jnp.roll(tokens, -1, axis=1)
+    key = jax.random.PRNGKey(0)
+    state = ServerState(
+        variables=bundle.init(key), opt_state=(),
+        round_idx=jnp.zeros((), jnp.int32), key=key,
+    )
+    round_fn, shard_state, shard_data = make_rule_round_fn(
+        mesh, local_update, state.variables, FEDLLM_RULES
+    )
+    tokens = np.asarray(
+        jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
+    )
+    # [K=1, steps=1, batch=4, L]: per-position targets and mask
+    x = tokens[None, None]
+    args = shard_data((
+        x, np.roll(x, -1, axis=-1), np.ones((1, 1, 4), np.float32),
+        np.full((1,), 4.0, np.float32), np.ones((1,), np.float32),
+        np.zeros((1,), np.int32),
+    ))
+    state = shard_state(state)
     losses = []
     for _ in range(5):
-        variables, loss = train_step(variables, tokens, targets, 0.5)
-        losses.append(float(loss))
+        state, m = round_fn(state, *args)
+        losses.append(float(m["loss_sum"]) / float(m["count"]))
     assert all(np.isfinite(losses))
     assert losses[-1] < losses[0]
-    qkv = variables["params"]["Block_0"]["MultiHeadAttention_0"]["Dense_0"]["kernel"]
-    assert qkv.sharding.spec == P(None, "tp")
+    qkv = state.variables["params"]["Block_0"]["MultiHeadAttention_0"]["Dense_0"]["kernel"]
+    assert qkv.sharding.spec == P(None, "mp")
 
 
 def _mlp_stage(params, x):

@@ -22,7 +22,7 @@ chip):
                    L = 1024, vocab 8192, 4 clients x 4 steps x batch 8
                    (bench.py --workload fedllm): warm-up + 1 call
   flash_attention  ops/flash_attention.py compiled through the model's
-                   policy, forward, dQ and dK/dV kernels: L = 1024 at
+                   policy, forward and backward kernel: L = 1024 at
                    H = 20, D = 64 (the benchmark cells' shape), L = 2048
                    and 8192 at D = 128, against blockwise_attention at
                    "highest" matmul precision
@@ -373,11 +373,11 @@ def leg_fedllm_fused(rehearsal: bool) -> dict:
 # takes bf16 q/k/v, accumulates scores in fp32, rounds the probabilities to
 # bf16 for the second matmul and the output to bf16: each rounding is a
 # relative 2^-8 = 0.4 %, they do not compound beyond a small multiple, and
-# 2 % leaves room for that multiple.  The backward kernels do the same:
+# 2 % leaves room for that multiple.  The backward kernel does the same:
 # bf16 operands (p and ds rounded to bf16), fp32 scores and accumulators.
 FLASH_TOL = 0.02
 # the kernels of ops/flash_attention.py, as Watch names them
-FLASH_KERNELS = ("_fwd_kernel", "_dq_kernel", "_dkv_kernel")
+FLASH_KERNELS = ("_fwd_kernel", "_bwd_kernel")
 
 
 def leg_flash_attention(rehearsal: bool) -> dict:
@@ -419,7 +419,7 @@ def leg_flash_attention(rehearsal: bool) -> dict:
         jax.block_until_ready((out, grads))
         traced = watch.kernels[first:]
         # the forward call traces the forward kernel, the gradient call
-        # the forward again and both backward kernels
+        # the forward again and the backward kernel
         check([name for name, _ in traced]
               == [FLASH_KERNELS[0], *FLASH_KERNELS],
               f"L={L}: Pallas kernels traced: {traced}")

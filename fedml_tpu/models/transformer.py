@@ -46,8 +46,9 @@ def _default_attn(q, k, v, causal):
       kernels (``ops/flash_attention.py``), forward and backward, which
       keep scores and probabilities in VMEM and save only ``o`` and
       ``lse``.  At the benchmark cells' shape (bf16 [8, 1024, 20, 64])
-      they run forward + backward 2.7x faster than the lax scan below
-      (PERF.md §6, PR 26);
+      they run forward + backward 3.1x faster than the lax scan below
+      (PERF.md §6: 8.01 ms a layer, PR 26, against 2.55 for one
+      gradient call, PR 30);
     - float32 inputs take the kernels from L = 2048 on, in 512-blocks,
       where the lax path's saved probability blocks no longer fit (17.6
       GB observed for a 4 x 8192 batch); below that they keep the lax

@@ -1,4 +1,4 @@
-"""Pallas TPU flash attention: forward, dQ and dK/dV kernels.
+"""Pallas TPU flash attention: a forward and a backward kernel.
 
 The hot op of the transformer family (``models/transformer.py``):
 softmax(QKᵀ/√d)V computed blockwise in VMEM with online-softmax
@@ -20,13 +20,23 @@ masked ``q`` contracted over all 128 lanes against ``k`` is that head's
 Scores, probabilities and every running sum are float32; ``p`` and ``ds``
 are rounded to the input dtype only as matmul operands.
 
-Grids (a ``vmap`` prepends its batch axis): forward and dQ run (head
-blocks, q blocks) with that head block's k and v whole in VMEM, and walk
-the kv blocks in the kernel's own loop; dK/dV runs (head blocks, kv
-blocks) with q and dO whole in VMEM, walks the q blocks, and works on the
-transposed block ``sᵀ = k qᵀ``, so that ``lse`` and ``delta`` broadcast
-as lane-dense rows and no operand is transposed.  Under a causal mask the
-loop's bounds leave out the blocks above the diagonal, and only the
+Grids (a ``vmap`` prepends its batch axis).  ``flash_fwd`` runs (head
+blocks, q blocks) with that head block's k and v whole in VMEM, and walks
+the kv blocks in the kernel's own loop.  ``flash_bwd`` runs (head blocks,
+kv blocks) with q and dO whole in VMEM (in one buffer each where two
+would not fit: their block changes only with the head block), walks the
+q blocks, and computes ``s``, ``p``, ``dp`` and ``ds`` once a block pair
+for all three gradients: five matmuls and one ``exp`` pass.  It works on
+the transposed block ``sᵀ = k qᵀ``, so that ``lse`` and ``delta``
+broadcast as lane-dense rows and ``dv += pᵀ dO``, ``dk += dsᵀ q`` take
+the block as computed; ``dq += ds k`` contracts the kv dim of ``dsᵀ``
+and ``k``, the one transposed operand.  The three sums are float32
+accumulators in VMEM that every matmul adds to in place (a loop carry
+costs a copy a block pair): ``dk`` and ``dv`` [block, 128], zeroed and
+written every grid step, ``dq`` [L, 128], which the kv axis of the grid
+(sequential) carries from step to step and the last step scales, casts
+and writes, so no float32 gradient reaches HBM.  Under a causal mask the
+loops' bounds leave out the blocks above the diagonal, and only the
 blocks the diagonal crosses apply the mask.
 
 ``interpret=True`` runs the same kernels on the CPU (tests);
@@ -52,6 +62,8 @@ VMEM_LIMIT = 96 * 1024 * 1024
 
 # contract the last dim of both operands: a @ b.T without a transpose
 _NT = (((1,), (1,)), ((), ()))
+# contract the first dim of both: a.T @ b, the one transposed operand
+_TN = (((0,), (0,)), ((), ()))
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
@@ -94,11 +106,6 @@ def _keep(row0, col0, shape, kv_axis: int):
     qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - kv_axis)
     kpos = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, kv_axis)
     return kpos <= qpos
-
-
-def _row_to_col(row):
-    """[1, n] -> [n, 1] through a tile-aligned transpose."""
-    return jnp.broadcast_to(row, (LANES, row.shape[1])).T[:, :1]
 
 
 def _col_to_row(col):
@@ -174,75 +181,56 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               sm_scale: float, causal: bool, block_k: int, head_dim: int,
-               group: int):
-    block_q, width = q_ref.shape
-    qi, nk = pl.program_id(1), k_ref.shape[0] // block_k
-    diag, end = _kv_range(qi, block_q, block_k, nk, causal)
-    q, do = q_ref[...], do_ref[...]
-    dq = jnp.zeros((block_q, width), jnp.float32)
-    for a in range(group):
-        lanes = _head_lanes(q.shape, a, head_dim, group)
-        qa, doa = _only(q, lanes), _only(do, lanes)
-        lse = _row_to_col(lse_ref[a:a + 1, :])               # [BQ, 1]
-        delta = _row_to_col(delta_ref[a:a + 1, :])
-
-        def body(j, acc, masked):
-            k = k_ref[pl.ds(j * block_k, block_k), :]
-            v = v_ref[pl.ds(j * block_k, block_k), :]
-            s = _dot(qa, k, _NT) * sm_scale                  # [BQ, BK]
-            if masked:
-                s = jnp.where(_keep(qi * block_q, j * block_k, s.shape, 1),
-                              s, NEG_INF)
-            p = jnp.exp(s - lse)
-            ds = p * (_dot(doa, v, _NT) - delta)
-            return acc + _dot(ds.astype(k.dtype), k)
-
-        dq_a = _loop_blocks((0, diag, end), (False, True), body,
-                            jnp.zeros((block_q, width), jnp.float32))
-        dq = dq_a if lanes is None else jnp.where(lanes, dq_a, dq)
-    dq_ref[...] = (dq * sm_scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, *, sm_scale: float, causal: bool, block_q: int,
-                head_dim: int, group: int):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, sm_scale: float,
+                causal: bool, block_q: int, head_dim: int, group: int):
     block_k, width = k_ref.shape
     ki, nq = pl.program_id(1), q_ref.shape[0] // block_q
     first, whole = _q_range(ki, block_q, block_k, nq, causal)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+    dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
     k, v = k_ref[...], v_ref[...]
-    dk = dv = jnp.zeros((block_k, width), jnp.float32)
+    heads = []
     for a in range(group):
         lanes = _head_lanes(k.shape, a, head_dim, group)
-        ka, va = _only(k, lanes), _only(v, lanes)
+        heads.append((_only(k, lanes), _only(v, lanes)))
 
-        def body(i, carry, masked):
-            dk_a, dv_a = carry
-            q = q_ref[pl.ds(i * block_q, block_q), :]
-            do = do_ref[pl.ds(i * block_q, block_q), :]
+    def body(i, carry, masked):
+        rows = pl.ds(i * block_q, block_q)
+        q, do = q_ref[rows, :], do_ref[rows, :]
+        if masked:
+            keep = _keep(i * block_q, ki * block_k, (block_k, block_q), 0)
+        # every right-hand operand is zero off head a's lanes, so the
+        # heads of a block add up in one accumulator a gradient
+        for a, (ka, va) in enumerate(heads):
+            lanes = _head_lanes(q.shape, a, head_dim, group)
             st = _dot(ka, q, _NT) * sm_scale                 # [BK, BQ]
             if masked:
-                st = jnp.where(_keep(i * block_q, ki * block_k, st.shape, 0),
-                               st, NEG_INF)
+                st = jnp.where(keep, st, NEG_INF)
             pt = jnp.exp(st - lse_ref[a, pl.ds(i, 1), :])
-            dv_a = dv_a + _dot(pt.astype(do.dtype), do)
-            dst = pt * (_dot(va, do, _NT) - delta_ref[a, pl.ds(i, 1), :])
-            return dk_a + _dot(dst.astype(q.dtype), q), dv_a
+            dv_acc[...] += _dot(pt.astype(do.dtype), _only(do, lanes))
+            dst = (pt * (_dot(va, do, _NT) - delta_ref[a, pl.ds(i, 1), :])
+                   ).astype(q.dtype)
+            dk_acc[...] += _dot(dst, _only(q, lanes))
+            dq_acc[rows, :] += _dot(dst, ka, _TN)            # [BQ, W]
+        return carry  # nothing: every sum lives in a scratch ref
 
-        zero = jnp.zeros((block_k, width), jnp.float32)
-        dk_a, dv_a = _loop_blocks((first, whole, nq), (True, False), body,
-                                  (zero, zero))
-        if lanes is None:
-            dk, dv = dk_a, dv_a
-        else:
-            dk, dv = jnp.where(lanes, dk_a, dk), jnp.where(lanes, dv_a, dv)
-    dk_ref[...] = (dk * sm_scale).astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    _loop_blocks((first, whole, nq), (True, False), body, None)
+    dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
 class _Plan:
-    """Shapes, grids and block specs shared by the three kernels: the
+    """Shapes, grids and block specs shared by the two kernels: the
     [L, H·D] view in column blocks of ``group`` heads; one side of the
     score block is a grid axis, the other is whole in VMEM and walked by
     the kernel's own loop."""
@@ -269,22 +257,26 @@ class _Plan:
     def block(self, rows):
         return pl.BlockSpec((rows, self.W), lambda h, i: (i, h))
 
-    def whole(self, rows):
-        return pl.BlockSpec((rows, self.W), lambda h, i: (0, h))
+    def whole(self, rows, **kw):
+        return pl.BlockSpec((rows, self.W), lambda h, i: (0, h), **kw)
 
     def call(self, name, kernel, grid, in_specs, out_specs, out_shape,
-             **consts):
-        """``name`` is the custom call's in a device trace."""
+             sequential=False, scratch_shapes=(), **consts):
+        """``name`` is the custom call's in a device trace.  The second
+        grid axis runs in order on one core where ``sequential``: a
+        scratch buffer then carries from one of its steps to the next."""
         kwargs = {}
         if not self.interpret:
             kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel"),
+                dimension_semantics=(
+                    "parallel", "arbitrary" if sequential else "parallel"),
                 vmem_limit_bytes=VMEM_LIMIT,
             )
         return pl.pallas_call(
             functools.partial(kernel, **self.consts, **consts), grid=grid,
             in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
-            interpret=self.interpret, name=name, **kwargs,
+            scratch_shapes=scratch_shapes, interpret=self.interpret,
+            name=name, **kwargs,
         )
 
     def flat(self, t):
@@ -308,7 +300,7 @@ def _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret):
 
 def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
                     interpret):
-    """Exact flash backward as two kernels.  Standard formulas:
+    """Exact flash backward as one kernel.  Standard formulas:
 
         p_ij  = exp(s_ij - lse_i)
         dv_j  = pᵀ dO           dp_ij = dO_i · v_j
@@ -326,23 +318,27 @@ def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
     flat_q = jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.D), q.dtype)
     flat_k = jax.ShapeDtypeStruct((pn.Lk, pn.H * pn.D), k.dtype)
 
-    # [H, L] statistics by head block; a q block's row is picked by the
-    # grid (dQ) or, on a leading dim, by the kernel's loop (dK/dV)
-    row = pl.BlockSpec((None, pn.group, pn.bq), lambda h, i: (h, 0, i))
-    dq = pn.call(
-        "flash_dq", _dq_kernel, (pn.nh, pn.nq),
-        [pn.block(pn.bq), pn.whole(pn.Lk), pn.whole(pn.Lk), pn.block(pn.bq),
-         row, row],
-        pn.block(pn.bq), flat_q, block_k=pn.bk,
-    )(*ops, *(t.reshape(pn.nh, pn.group, pn.Lq) for t in (lse, delta)))
-
+    # [H, L] statistics by head block, a q block's row on a leading dim
+    # for the kernel's loop to pick
     rows = pl.BlockSpec((None, pn.group, pn.nq, pn.bq),
                         lambda h, j: (h, 0, 0, 0))
-    dk, dv = pn.call(
-        "flash_dkv", _dkv_kernel, (pn.nh, pn.nk),
-        [pn.whole(pn.Lq), pn.block(pn.bk), pn.block(pn.bk), pn.whole(pn.Lq),
+    # whole in VMEM: q, dO and the dq block in two buffers each, and the
+    # float32 accumulator.  Where that sum would leave the score blocks
+    # no room under VMEM_LIMIT, q and dO get one buffer: their block
+    # changes only with the head block, and a long sequence hides the
+    # wait (at the cells' L = 1024 it cost 0.17 ms in 1.22: PERF.md §6)
+    resident = pn.Lq * pn.W * (6 * q.dtype.itemsize + 4)
+    whole = pn.whole if resident <= VMEM_LIMIT * 3 // 4 else \
+        functools.partial(pn.whole, pipeline_mode=pl.Buffered(1))
+    dq, dk, dv = pn.call(
+        "flash_bwd", _bwd_kernel, (pn.nh, pn.nk),
+        [whole(pn.Lq), pn.block(pn.bk), pn.block(pn.bk), whole(pn.Lq),
          rows, rows],
-        [pn.block(pn.bk), pn.block(pn.bk)], [flat_k, flat_k], block_q=pn.bq,
+        [pn.whole(pn.Lq), pn.block(pn.bk), pn.block(pn.bk)],
+        [flat_q, flat_k, flat_k], sequential=True,
+        scratch_shapes=[pltpu.VMEM((n, pn.W), jnp.float32)
+                        for n in (pn.Lq, pn.bk, pn.bk)],
+        block_q=pn.bq,
     )(*ops, *(t.reshape(pn.nh, pn.group, pn.nq, pn.bq)
               for t in (lse, delta)))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
@@ -405,8 +401,10 @@ def flash_attn_fn(block_q: int = 128, block_k: int = 128,
     return attn
 
 
-# longest side the kernels hold whole in VMEM (double-buffered, beside the
-# score blocks) under VMEM_LIMIT: 4 * L * 128 lanes * 4 bytes = 64 MiB
+# longest side the kernels hold whole in VMEM beside the score blocks under
+# VMEM_LIMIT, in float32 at 128 lanes (16 MiB an array).  Forward: k and v
+# in two buffers each, 64 MiB.  Backward: q and dO in one buffer each, the
+# dq block in two, its accumulator: 80 MiB (112 with q and dO in two)
 MAX_LENGTH = 32768
 
 
@@ -416,14 +414,16 @@ def pick_block(length: int, head_dim: int = 128) -> int:
     divides it; 0 if none does or the sequence is longer than MAX_LENGTH
     (the caller falls back to the lax blockwise path).
 
-    Measured on one v5e chip (PERF.md §6, PR 26; bf16 [8, 1024, 20, 64],
-    forward + backward in a fused 50-iteration scan): 512-blocks 2.93 ms
-    a layer, 1024 (no causal block skipped) 2.96, 256 3.78, 128 6.89,
-    against 8.01 for the lax blockwise scan.  A block pair costs a fixed
-    ~400 cycles beside its elementwise passes, so small blocks lose more
-    than their finer causal skip wins.  Both head sizes the kernels take
-    on a chip (``head_group``) run 128-lane blocks, so the choice does
-    not depend on ``head_dim`` today.
+    Measured on one v5e chip (PERF.md §6, PR 30; bf16 [8, 1024, 20, 64],
+    the kernels' device time in a trace, ms a layer, forward + backward):
+    512-blocks 0.82 + 0.95, 1024 (no causal block skipped) 0.75 + 1.16,
+    256 1.11 + 1.15; PR 26 had 128 at 6.89 for both against 2.93 in
+    512-blocks then, and 8.01 for the lax blockwise scan.  A block pair
+    costs a fixed ~400 cycles beside its elementwise passes, so small
+    blocks lose more than their finer causal skip wins.  The forward
+    alone would take 1024; one size serves both kernels.  Both head sizes
+    the kernels take on a chip (``head_group``) run 128-lane blocks, so
+    the choice does not depend on ``head_dim`` today.
     """
     del head_dim
     if length > MAX_LENGTH:

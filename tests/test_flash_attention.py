@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from fedml_tpu.ops.flash_attention import flash_attention, flash_attn_fn
+from fedml_tpu.ops.flash_attention import (
+    flash_attention, flash_attention_with_lse, flash_attn_fn,
+)
 from fedml_tpu.parallel.ring_attention import dense_attention
 
 
@@ -72,31 +74,47 @@ def test_flash_attn_fn_plugs_into_transformer():
                                rtol=3e-4, atol=3e-4)
 
 
+def _dense_with_lse(q, k, v, causal):
+    """(o, lse [H, L]) of dense softmax attention."""
+    s = jnp.einsum("qhd,khd->hqk", q, k) / q.shape[-1] ** 0.5
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[1:], bool)), s, -jnp.inf)
+    return dense_attention(q, k, v, causal=causal), \
+        jax.scipy.special.logsumexp(s, axis=-1)
+
+
 @pytest.mark.parametrize("shape", [
     pytest.param((32, 2, 8, 8, 8), id="d8"),
     *SHAPES[1:],
+    pytest.param((256, 2, 128, 64, 128), id="d128_one_head_a_block"),
 ])
+@pytest.mark.parametrize("lse_cotangent", [False, True],
+                         ids=["o", "o_and_lse"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_gradients_match_dense(causal, shape):
-    """The dQ and dK/dV kernels must produce the same dq/dk/dv as
-    differentiating dense softmax attention."""
+def test_flash_gradients_match_dense(causal, lse_cotangent, shape):
+    """The backward kernel must produce the same dq/dk/dv as
+    differentiating dense softmax attention: through ``o`` alone, and
+    through ``o`` and ``lse`` (what the ring merge does) with a cotangent
+    of ``lse`` that is not zero.  Several kv blocks a head block walk the
+    dq accumulator the kernel carries from one kv step to the next."""
     L, H, D, block_q, block_k = shape
-    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(3), 4)
+    k1, k2, k3, k4, k5 = jax.random.split(jax.random.PRNGKey(3), 5)
     q = jax.random.normal(k1, (L, H, D), jnp.float32)
     k = jax.random.normal(k2, (L, H, D), jnp.float32)
     v = jax.random.normal(k3, (L, H, D), jnp.float32)
     cot = jax.random.normal(k4, (L, H, D), jnp.float32)
+    cot_lse = jax.random.normal(k5, (H, L), jnp.float32) * lse_cotangent
 
-    def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, causal=causal, block_q=block_q,
-                              block_k=block_k, interpret=True)
-        return (out * cot).sum()
+    def loss(attn):
+        def f(q, k, v):
+            out, lse = attn(q, k, v)
+            return (out * cot).sum() + (lse * cot_lse).sum()
+        return f
 
-    def loss_dense(q, k, v):
-        return (dense_attention(q, k, v, causal=causal) * cot).sum()
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.grad(loss(lambda q, k, v: flash_attention_with_lse(
+        q, k, v, causal, block_q, block_k, True)), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss(lambda q, k, v: _dense_with_lse(q, k, v, causal)),
+                  argnums=(0, 1, 2))(q, k, v)
     for a, b, name in zip(gf, gd, ("dq", "dk", "dv")):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
@@ -133,7 +151,7 @@ def test_default_attn_policy(monkeypatch):
 
     assert kernels(1024, 20, 64, jnp.bfloat16) == []      # the CPU backend
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    fused = ["_fwd_kernel", "_dq_kernel", "_dkv_kernel"]
+    fused = ["_fwd_kernel", "_bwd_kernel"]
     assert kernels(1024, 20, 64, jnp.bfloat16) == fused
     assert kernels(1024, 20, 64, jnp.float32) == []
     assert kernels(2048, 10, 128, jnp.float32) == fused

@@ -27,7 +27,7 @@ import optax
 
 from fedml_tpu.core import tree as treelib
 from fedml_tpu.core.losses import LossFn, masked_softmax_ce
-from fedml_tpu.models.base import ModelBundle
+from fedml_tpu.models.base import COUNTERS, ModelBundle
 from fedml_tpu.obs import scopes
 
 PyTree = Any
@@ -114,6 +114,17 @@ class LocalUpdateFn:
         return self.fn(variables, x, y, mask, rng)
 
 
+def _split_counters(new_vars):
+    """(the variables a model came back with, less its ``COUNTERS``
+    collection; that collection as an entry for the step's aux), so the
+    counters never enter the scan carry.  A model without one (every model
+    but the expert decoder) passes through untouched."""
+    if COUNTERS not in new_vars:
+        return new_vars, {}
+    rest = {k: v for k, v in new_vars.items() if k != COUNTERS}
+    return rest, {COUNTERS: dict(new_vars[COUNTERS])}
+
+
 def make_local_update(
     bundle: ModelBundle,
     optimizer: optax.GradientTransformation,
@@ -153,13 +164,16 @@ def make_local_update(
                 )
             with jax.named_scope(scopes.MODEL):
                 logits, new_vars = bundle.apply_train(cvars, cx, rng)
+            new_vars, counters = _split_counters(new_vars)
             with jax.named_scope(scopes.CAST):
                 new_vars = treelib.tree_cast_like(new_vars, variables)
         else:
             with jax.named_scope(scopes.MODEL):
                 logits, new_vars = bundle.apply_train(variables, x, rng)
+            new_vars, counters = _split_counters(new_vars)
         with jax.named_scope(scopes.LOSS):
             loss, aux = loss_fn(logits, y, m)
+            aux = {**aux, **counters}
             if prox_mu:
                 sq = treelib.tree_sq_norm(treelib.tree_sub(params, global_params))
                 loss = loss + 0.5 * prox_mu * sq
@@ -257,6 +271,8 @@ def make_local_update(
             # exact optimizer steps executed across ALL epochs (pad-only
             # batches are no-ops and excluded) — FedNova's tau_i
             "steps": auxs["step"].sum(),
+            # a model's own scalar counters, summed like the loss
+            **{k: v[-1].sum() for k, v in auxs.get(COUNTERS, {}).items()},
         }
         return variables, metrics
 

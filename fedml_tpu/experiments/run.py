@@ -85,6 +85,10 @@ class ExperimentConfig:
     num_heads: int = 4
     num_layers: int = 2
     sp_degree: int = 1  # >1: DP x SP — long-context clients, ring attention
+    # --model decoder_lm: the configuration-driven decoder
+    # (models/decoder.py) at the sizes of this config.json-style file;
+    # vocabulary and length come from the dataset
+    model_config: str = ""
     # rule-driven sharding engine (fedml_tpu/parallel/partition.py):
     # --mesh "dp,mp" (also "dp=4,mp=2" / "auto,2") lays the cohort over
     # dp and the model over mp in ONE jit step; --partition_rules picks
@@ -185,11 +189,24 @@ def _run_fedllm(cfg: ExperimentConfig, ds, t0, log_fn, metrics=None) -> dict:
     # the rule engine shards the model (and the cohort) by GSPMD, where
     # a pallas_call has no partitioning rule: its mesh keeps the lax
     # attention, every other driver the model's own policy
-    bundle = transformer_lm(
-        vocab_size=vocab, embed_dim=cfg.embed_dim, num_heads=cfg.num_heads,
-        num_layers=cfg.num_layers, seq_len=seq_len,
-        attn_fn=lax_attention if cfg.mesh else None,
-    )
+    if cfg.model == "decoder_lm":
+        from fedml_tpu.models.decoder import decoder_lm
+
+        if cfg.mesh or cfg.sp_degree > 1:
+            raise ValueError(
+                "--model decoder_lm trains through the simulation driver "
+                "only: the rule table and the ring attention of --mesh / "
+                "sp_degree know the transformer_lm parameter tree"
+            )
+        with open(cfg.model_config) as f:
+            bundle = decoder_lm({**json.load(f), "vocab_size": vocab,
+                                 "n_positions": seq_len})
+    else:
+        bundle = transformer_lm(
+            vocab_size=vocab, embed_dim=cfg.embed_dim,
+            num_heads=cfg.num_heads, num_layers=cfg.num_layers,
+            seq_len=seq_len, attn_fn=lax_attention if cfg.mesh else None,
+        )
 
     if cfg.mesh and cfg.sp_degree > 1:
         raise ValueError(

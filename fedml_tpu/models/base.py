@@ -22,6 +22,14 @@ import flax.linen as nn
 
 PyTree = Any
 
+# Reserved collection for scalar counters a model hands out of its train-mode
+# forward (tokens an expert layer kept, the fullest expert's rows): sown by
+# the module, returned by ``apply_train`` beside the variables, taken out by
+# ``make_local_update`` before the carry and summed into its metrics.  Never
+# part of ``variables``: ``init`` does not create it, so aggregation, codecs
+# and checkpoints never see it.
+COUNTERS = "counters"
+
 
 @dataclasses.dataclass
 class ModelBundle:
@@ -31,6 +39,7 @@ class ModelBundle:
     input_shape: Sequence[int]  # one example's shape, no batch dim
     input_dtype: Any = jnp.float32
     needs_dropout_rng: bool = False
+    has_counters: bool = False  # the module sows into ``COUNTERS`` in train mode
 
     def init(self, rng: jax.Array) -> PyTree:
         dummy = jnp.zeros((1, *self.input_shape), self.input_dtype)
@@ -44,11 +53,14 @@ class ModelBundle:
     ) -> Tuple[jax.Array, PyTree]:
         """Forward in train mode; returns (logits, updated variables)."""
         rngs = {"dropout": rng} if (self.needs_dropout_rng and rng is not None) else None
-        if "batch_stats" in variables:
+        mutable = ["batch_stats"] if "batch_stats" in variables else []
+        if self.has_counters:
+            mutable.append(COUNTERS)
+        if mutable:
             logits, mutated = self.module.apply(
-                variables, x, train=True, mutable=["batch_stats"], rngs=rngs
+                variables, x, train=True, mutable=mutable, rngs=rngs
             )
-            return logits, {**variables, "batch_stats": mutated["batch_stats"]}
+            return logits, {**variables, **mutated}
         logits = self.module.apply(variables, x, train=True, rngs=rngs)
         return logits, variables
 

@@ -1,0 +1,393 @@
+"""A decoder-only language model built from a configuration.
+
+Where ``models/transformer.py`` is one fixed block (LayerNorm, learned
+positions, GELU MLP, tied head), this one reads what a published
+``config.json`` says: RMSNorm, rotary positions (plain or YaRN, chosen by
+the layer's type), q heads that share k/v heads, a head size of its own,
+sliding-window and full attention layers mixed by ``layer_types``, a sparse
+expert layer in place of the MLP, and an untied head.  It shares
+``MultiHeadAttention`` (and so the attention policy and the flash kernels)
+with the transformer, and trains through ``make_local_update`` like any
+``ModelBundle``.
+
+The expert layer (``ExpertLayer``) is told which experts it holds.  The
+router keeps its full width and picks ``top_k`` of all experts; this
+layer computes what its own experts add for the tokens routed to them and
+leaves out the rest, which is what expert parallelism asks of one chip's
+layer (without its exchange: nothing here stands in for the other chips).
+Holding every expert, it is the whole layer.  No token is dropped: the row
+buffer holds the worst case, ``tokens x min(top_k, experts held)``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Callable, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from fedml_tpu.models.base import COUNTERS, ModelBundle
+from fedml_tpu.models.transformer import (
+    AttnFn, MultiHeadAttention, _default_attn,
+)
+from fedml_tpu.obs import scopes
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+# the scalar counters ``DecoderLM`` sows into ``COUNTERS`` in train mode
+ASSIGNMENTS_HELD = "moe_assignments_held"  # token-expert pairs on held experts
+EXPERT_TOKENS_MAX = "moe_expert_tokens_max"  # the fullest held expert's rows
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    vocab_size: int
+    hidden_size: int
+    num_layers: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    layer_types: Tuple[str, ...]  # one a layer: SLIDING or FULL
+    sliding_window: int
+    rope: Tuple[Tuple[str, Tuple[Tuple[str, object], ...]], ...]  # by layer type
+    rms_norm_eps: float
+    moe_intermediate_size: int
+    num_experts_routed: int  # the router's width
+    experts_held: Tuple[int, ...]  # ids of the experts this layer computes
+    top_k: int
+    norm_topk_prob: bool
+    max_len: int
+    remat: bool = False
+
+    @classmethod
+    def from_dict(cls, c: dict) -> "DecoderConfig":
+        """From a ``config.json``'s keys.  Depth is ``n_layer`` or
+        ``num_hidden_layers`` and ``layer_types`` is cycled to it;
+        ``num_experts_routed`` defaults to ``num_experts`` and
+        ``experts_held`` to the first ``num_experts`` ids."""
+        depth = c.get("n_layer", c.get("num_hidden_layers"))
+        if set(c.get("mlp_layer_types", ["sparse"])) != {"sparse"}:
+            raise ValueError("only sparse (expert) MLP layers are built")
+        kinds = c.get("layer_types") or [FULL]
+        routed = c.get("num_experts_routed", c["num_experts"])
+        held = tuple(c.get("experts_held", range(c["num_experts"])))
+        if len(held) != c["num_experts"] or not all(
+                0 <= e < routed for e in held):
+            raise ValueError(f"experts_held {held} against num_experts "
+                             f"{c['num_experts']} of {routed} routed")
+        rope = c["rope_parameters"]
+        if "rope_type" in rope:  # one block for every layer type
+            rope = {SLIDING: rope, FULL: rope}
+        return cls(
+            vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+            num_layers=depth, num_heads=c["num_attention_heads"],
+            num_kv_heads=c.get("num_key_value_heads",
+                               c["num_attention_heads"]),
+            head_dim=c.get("head_dim",
+                           c["hidden_size"] // c["num_attention_heads"]),
+            layer_types=tuple(kinds[i % len(kinds)] for i in range(depth)),
+            sliding_window=c.get("sliding_window"),
+            rope=tuple(sorted((k, tuple(sorted(v.items())))
+                              for k, v in rope.items())),
+            rms_norm_eps=c.get("rms_norm_eps", 1e-6),
+            moe_intermediate_size=c["moe_intermediate_size"],
+            num_experts_routed=routed, experts_held=held,
+            top_k=c["num_experts_per_tok"],
+            norm_topk_prob=c.get("norm_topk_prob", True),
+            max_len=c.get("n_positions", c.get("max_position_embeddings")),
+            remat=c.get("remat", False),
+        )
+
+
+# -- rotary positions ---------------------------------------------------------
+
+def rope_inv_freq(head_dim: int, theta: float) -> np.ndarray:
+    """``theta ** (-2i / head_dim)``, i = 0 .. head_dim / 2 - 1."""
+    return theta ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+
+
+def yarn_correction_range(head_dim, theta, original_max, beta_fast,
+                          beta_slow) -> Tuple[int, int]:
+    """(low, high): the pair indices between which YaRN ramps from the
+    published frequencies to the interpolated ones."""
+    def dim_of(rotations):
+        return head_dim * math.log(original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    return (max(math.floor(dim_of(beta_fast)), 0),
+            min(math.ceil(dim_of(beta_slow)), head_dim - 1))
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float = 32,
+                  beta_slow: float = 1) -> np.ndarray:
+    """YaRN's frequencies: pairs that turn fast keep theirs, pairs that
+    turn slowly over the original context are divided by ``factor``, with
+    a linear ramp between.  Static: the same at every length."""
+    low, high = yarn_correction_range(head_dim, theta, original_max,
+                                      beta_fast, beta_slow)
+    keep = 1 - np.clip((np.arange(head_dim // 2) - low)
+                       / max(high - low, 1e-3), 0, 1)
+    base = rope_inv_freq(head_dim, theta)
+    return base * keep + base / factor * (1 - keep)
+
+
+def make_rope_fn(params: dict, head_dim: int) -> Callable:
+    """x [B, L, H, D] -> x rotated by its position, in the rotate-half form
+    (pairs ``(i, i + D/2)``), from a ``rope_parameters`` block."""
+    theta = params["rope_theta"]
+    if params.get("rope_type", "default") == "yarn":
+        inv_freq = yarn_inv_freq(
+            head_dim, theta, params["factor"],
+            params["original_max_position_embeddings"],
+            params.get("beta_fast", 32), params.get("beta_slow", 1))
+        scale = params.get("attention_factor",
+                           0.1 * math.log(params["factor"]) + 1)
+    elif params.get("rope_type", "default") == "default":
+        inv_freq, scale = rope_inv_freq(head_dim, theta), 1.0
+    else:
+        raise ValueError(f"unknown rope_type {params['rope_type']!r}")
+    inv_freq = jnp.asarray(inv_freq, jnp.float32)
+
+    def rope(x):
+        angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
+        cos = (jnp.cos(angle) * scale)[None, :, None, :]
+        sin = (jnp.sin(angle) * scale)[None, :, None, :]
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).astype(x.dtype)
+
+    return rope
+
+
+# -- layers -------------------------------------------------------------------
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * g``, computed and returned in float32
+    (the caller rounds it where it wants to)."""
+
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        g = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        var = jnp.mean(x * x, axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
+
+
+@jax.custom_vjp
+def _take(src, idx, readers, readers_valid):
+    """``src[idx]``, for an ``idx`` whose inverse is known: row ``n`` of
+    ``src`` is read by the rows ``readers[n, :]`` of the result (where
+    ``readers_valid``).  The backward is then a gather too, not a
+    scatter-add."""
+    return src[idx]
+
+
+def _take_fwd(src, idx, readers, readers_valid):
+    return src[idx], (readers, readers_valid)
+
+
+def _take_bwd(res, g):
+    readers, valid = res
+    rows = g[readers]  # [N, c, ...]
+    valid = valid.reshape(valid.shape + (1,) * (rows.ndim - valid.ndim))
+    d_src = jnp.where(valid, rows, jnp.zeros_like(rows)).astype(
+        jnp.float32).sum(axis=1).astype(g.dtype)
+    return d_src, None, None, None
+
+
+_take.defvjp(_take_fwd, _take_bwd)
+
+
+def gmm_tiling(m: int, k: int, n: int):
+    """(rows, contraction, columns) tile of the grouped-product kernel for
+    ``[m, k] @ [groups, k, n]``, or None where it does not take the shape:
+    the largest row tile of 512, 256, 128 that divides ``m`` and, along
+    ``k`` and ``n``, the widest multiple of 128 up to 1152 that divides the
+    dim.  At the published expert shapes (65536 x 2304 x 896) a
+    (512, 1152, 896) tile ran forward + backward 2.2x faster than
+    (128, 128, 128) tiles times nine (PERF.md §6, PR 32)."""
+    def tile(dim):
+        return next((t for t in range(1152, 0, -128) if dim % t == 0), 0)
+
+    tiling = (next((t for t in (512, 256, 128) if m % t == 0), 0),
+              tile(k), tile(n))
+    return tiling if all(tiling) else None
+
+
+def _grouped_dot(rows, weights, group_sizes):
+    """``rows[r] @ weights[e]`` for the rows of group ``e``: groups lie one
+    after another from row 0; rows past their total cost no matmul and
+    hold nothing a caller may read.
+
+    On a TPU this is the Pallas grouped matmul (``megablox.gmm``), whose
+    grid holds only the row tiles the groups cover, forward and in both
+    gradients.  ``lax.ragged_dot`` compiles there to a product of every
+    row with every group under a mask when the sizes are not constants
+    (38 ms a product at the published shapes against 0.5, PERF.md §6); it
+    is what other backends and shapes the kernel does not tile run."""
+    tiling = gmm_tiling(rows.shape[0], *weights.shape[1:])
+    if jax.default_backend() == "tpu" and tiling:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        return gmm(rows, weights, group_sizes, rows.dtype, tiling)
+    return jax.lax.ragged_dot(rows, weights, group_sizes)
+
+
+class ExpertLayer(nn.Module):
+    """Dropless top-k mixture of gated SiLU experts over the experts held.
+
+    ``x`` [B, L, h] float32 (the normed input).  Router and selection run in
+    float32; the expert products run in the dtype of the expert weights.
+    Returns (``sum over e in top-k and held of w_e f_e(x)`` in the experts'
+    dtype, the two counters as float32 scalars).  Not under a ``vmap`` over
+    clients (``client_axis_impl="vmap"``): ``lax.ragged_dot`` refuses
+    stacked expert weights ("ragged_dot vmap ... NYI"); the default client
+    loop is a scan."""
+
+    num_experts_routed: int
+    experts_held: Tuple[int, ...]
+    top_k: int
+    intermediate: int
+    norm_topk_prob: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        B, L, h = x.shape
+        T, k, held = B * L, self.top_k, len(self.experts_held)
+        A, R = T * k, T * min(k, held)  # assignments, rows of the buffer
+        init = nn.initializers.lecun_normal()
+        stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                               batch_axis=0)
+        router = self.param("router", init, (h, self.num_experts_routed))
+        w_gate = self.param("gate", stacked, (held, h, self.intermediate))
+        w_up = self.param("up", stacked, (held, h, self.intermediate))
+        w_down = self.param("down", stacked, (held, self.intermediate, h))
+        x = x.reshape(T, h)
+
+        with jax.named_scope(scopes.MOE_ROUTER):
+            logits = jnp.dot(x, router.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+            if self.norm_topk_prob:
+                top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+            # for a caller that asks for "intermediates": the selection
+            self.sow("intermediates", "top_e", top_e)
+
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            # local index of each assignment's expert; ``held`` = not here
+            local_of = np.full((self.num_experts_routed,), held, np.int32)
+            local_of[list(self.experts_held)] = np.arange(held)
+            local = jnp.asarray(local_of)[top_e]  # [T, k]
+            here = local < held
+            # a counting sort by expert: held assignments first, by expert
+            order = jnp.argsort(local.reshape(A), stable=True)
+            rank = jnp.argsort(order).reshape(T, k)  # the inverse
+            group_sizes = (local.reshape(A, 1) == jnp.arange(held)).sum(
+                axis=0, dtype=jnp.int32)
+            routed = group_sizes.sum()
+            order, rank = order[:R], jnp.minimum(rank, R - 1)
+            row_live = (jnp.arange(R) < routed)[:, None]
+            rows = _take(x.astype(w_gate.dtype), order // k, rank, here)
+            row_w = _take(top_p.reshape(A), order, rank.reshape(A, 1),
+                          here.reshape(A, 1))
+
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            gate = _grouped_dot(rows, w_gate, group_sizes)
+            up = _grouped_dot(rows, w_up, group_sizes)
+            act = (jax.nn.silu(gate.astype(jnp.float32))
+                   * up.astype(jnp.float32) * row_w[:, None])
+            out = _grouped_dot(act.astype(w_down.dtype), w_down, group_sizes)
+
+        with jax.named_scope(scopes.MOE_COMBINE):
+            back = _take(out, rank.reshape(A), order[:, None], row_live)
+            y = jnp.where(here[..., None], back.reshape(T, k, h), 0).astype(
+                jnp.float32).sum(axis=1)
+
+        counters = {
+            ASSIGNMENTS_HELD: routed.astype(jnp.float32),
+            EXPERT_TOKENS_MAX: group_sizes.max().astype(jnp.float32),
+        }
+        return y.astype(w_down.dtype).reshape(B, L, h), counters
+
+
+def _scoped(attn: AttnFn, name: str) -> AttnFn:
+    def fn(*args, **kwargs):
+        with jax.named_scope(name):
+            return attn(*args, **kwargs)
+
+    return fn
+
+
+class DecoderBlock(nn.Module):
+    cfg: DecoderConfig
+    kind: str
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.cfg
+        sliding = self.kind == SLIDING
+        attn = MultiHeadAttention(
+            c.num_heads,
+            attn_fn=_scoped(self.attn_fn or _default_attn,
+                            scopes.ATTN_SLIDING if sliding
+                            else scopes.ATTN_FULL),
+            num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
+            rope_fn=make_rope_fn(dict(dict(c.rope)[self.kind]), c.head_dim),
+            window=c.sliding_window if sliding else None,
+        )
+        x = x + attn(RMSNorm(c.rms_norm_eps)(x).astype(x.dtype))
+        y, counters = ExpertLayer(
+            c.num_experts_routed, c.experts_held, c.top_k,
+            c.moe_intermediate_size, c.norm_topk_prob,
+        )(RMSNorm(c.rms_norm_eps)(x))
+        return x + y.astype(x.dtype), counters
+
+
+class DecoderLM(nn.Module):
+    cfg: DecoderConfig
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        c = self.cfg
+        if x.shape[1] > c.max_len:
+            raise ValueError(
+                f"sequence length {x.shape[1]} exceeds max_len {c.max_len}")
+        # unit-variance embeddings (torch's default).  Under the 1/sqrt(h) of
+        # flax's, a fresh model's attention output, a running mean of v that
+        # neighbouring tokens share, outweighs the token's own embedding;
+        # every router then sees one common input and a few experts take
+        # nearly every token (measured: PERF.md §6, PR 32)
+        h = nn.Embed(c.vocab_size, c.hidden_size, name="wte",
+                     embedding_init=nn.initializers.normal(1.0))(
+            x.astype(jnp.int32))
+        block_cls = nn.remat(DecoderBlock) if c.remat else DecoderBlock
+        totals = {}
+        for i, kind in enumerate(c.layer_types):
+            h, counters = block_cls(c, kind, self.attn_fn,
+                                    name=f"Block_{i}")(h)
+            totals = {n: totals.get(n, 0.0) + v for n, v in counters.items()}
+        if train and not self.is_initializing():
+            for n, v in totals.items():
+                self.sow(COUNTERS, n, v, reduce_fn=lambda _, new: new,
+                         init_fn=lambda: jnp.zeros((), jnp.float32))
+        h = RMSNorm(c.rms_norm_eps, name="norm_f")(h).astype(h.dtype)
+        return nn.Dense(c.vocab_size, use_bias=False, name="lm_head")(h)
+
+
+def decoder_lm(config, attn_fn: Optional[AttnFn] = None) -> ModelBundle:
+    """``config``: a ``DecoderConfig`` or a ``config.json``-style dict."""
+    cfg = config if isinstance(config, DecoderConfig) \
+        else DecoderConfig.from_dict(config)
+    return ModelBundle(
+        module=DecoderLM(cfg, attn_fn), input_shape=(cfg.max_len,),
+        input_dtype=jnp.int32, has_counters=True,
+    )

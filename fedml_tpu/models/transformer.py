@@ -16,6 +16,7 @@ embeddings, weight-tied output head.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import flax.linen as nn
@@ -23,21 +24,24 @@ import jax
 import jax.numpy as jnp
 
 from fedml_tpu.models.base import ModelBundle
+from fedml_tpu.obs import scopes
 from fedml_tpu.parallel.ring_attention import blockwise_attention
 
-# (q, k, v, causal) over [L, H, D] per example
+# (q, k, v, causal) over [L, H, D] per example; a layer with a window also
+# passes ``window=``, and k, v may hold fewer heads than q
 AttnFn = Callable
 
 
-def lax_attention(q, k, v, causal):
+def lax_attention(q, k, v, causal, window=None):
     """The lax blockwise scan under the ``AttnFn`` signature: the policy's
     fallback, and what a caller whose heads are sharded by GSPMD passes
     as ``attn_fn`` (a ``pallas_call`` has no partitioning rule, so XLA
     would gather q, k, v and run every head on every chip)."""
-    return blockwise_attention(q, k, v, causal=causal, block_size=512)
+    return blockwise_attention(q, k, v, causal=causal, block_size=512,
+                               window=window)
 
 
-def _default_attn(q, k, v, causal):
+def _default_attn(q, k, v, causal, window=None):
     """Single-device attention policy, from what the call can see:
 
     - on a TPU, for a shape the fused kernels take (``pick_block`` finds
@@ -70,28 +74,49 @@ def _default_attn(q, k, v, causal):
         fits = block > 0
     else:
         fits = block == 512 and L >= 2048
-    if fits and head_group(H, D) and jax.default_backend() == "tpu":
+    # k/v heads shared among q heads: one head a column block
+    groups = head_group(H, D) if k.shape[1] == H else D % 128 == 0
+    if fits and groups and jax.default_backend() == "tpu":
         return flash_attention(
-            q, k, v, causal=causal, block_q=block, block_k=block
+            q, k, v, causal=causal, block_q=block, block_k=block,
+            window=window,
         )
-    return lax_attention(q, k, v, causal)
+    return lax_attention(q, k, v, causal, window)
 
 
 class MultiHeadAttention(nn.Module):
+    """One fused q/k/v projection, the attention function under ``vmap``
+    over the batch, the output projection.  By default every q head has its
+    own k/v head of size ``E // num_heads``; ``num_kv_heads`` shares each
+    k/v head among ``num_heads // num_kv_heads`` q heads, ``head_dim`` sets
+    a head size that is not ``E // num_heads``, ``rope_fn`` rotates q and k
+    ([B, L, H, D] -> the same) and ``window`` is handed to ``attn_fn``."""
+
     num_heads: int
     attn_fn: Optional[AttnFn] = None
     causal: bool = True
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    rope_fn: Optional[Callable] = None
+    window: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
         B, L, E = x.shape
         H = self.num_heads
-        D = E // H
-        qkv = nn.Dense(3 * E, use_bias=False)(x)
-        q, k, v = jnp.split(qkv.reshape(B, L, 3 * H, D), 3, axis=2)
+        G = self.num_kv_heads or H
+        D = self.head_dim or E // H
+        qkv = nn.Dense((H + 2 * G) * D, use_bias=False)(x)
+        q, k, v = jnp.split(qkv.reshape(B, L, H + 2 * G, D), [H, H + G],
+                            axis=2)
+        if self.rope_fn is not None:
+            with jax.named_scope(scopes.ROPE):
+                q, k = self.rope_fn(q), self.rope_fn(k)
         attn = self.attn_fn or _default_attn
+        if self.window is not None:
+            attn = functools.partial(attn, window=self.window)
         out = jax.vmap(lambda a, b, c: attn(a, b, c, self.causal))(q, k, v)
-        return nn.Dense(E, use_bias=False)(out.reshape(B, L, E))
+        return nn.Dense(E, use_bias=False)(out.reshape(B, L, H * D))
 
 
 class Block(nn.Module):

@@ -27,3 +27,18 @@ OPTIMIZER = "fed.optimizer"  # optimizer.update, apply_updates, has_real blend
 SCOPES = (ROUNDS, ROUND, CLIENTS, LOCAL_UPDATE, CODEC, AGG_TRANSFORM,
           AGGREGATE, SERVER_UPDATE, METRICS, SHUFFLE, STEP, CAST, MODEL,
           LOSS, OPTIMIZER)
+
+# Inside ``fed.model``: the parts of a decoder that differ from layer to
+# layer (``models/decoder.py``).  They must not match ``fed\.[a-z_]+``: a
+# stage reader takes an op's last such segment as its stage, and these ops
+# stay the model's.
+ROPE = "model.rope"  # rotary tables and the rotation of q and k
+ATTN_SLIDING = "model.attn_sliding"  # the attention function of a windowed layer
+ATTN_FULL = "model.attn_full"  # the attention function of a full layer
+MOE_ROUTER = "model.moe_router"  # router logits, softmax, top-k, weights
+MOE_DISPATCH = "model.moe_dispatch"  # grouping by expert and the gather of rows
+MOE_EXPERTS = "model.moe_experts"  # the grouped matrix products and the gate
+MOE_COMBINE = "model.moe_combine"  # rows back to tokens, summed over the k
+
+MODEL_SCOPES = (ROPE, ATTN_SLIDING, ATTN_FULL, MOE_ROUTER, MOE_DISPATCH,
+                MOE_EXPERTS, MOE_COMBINE)

@@ -100,12 +100,15 @@ def _only(x, lanes):
     return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
 
 
-def _keep(row0, col0, shape, kv_axis: int):
+def _keep(row0, col0, shape, kv_axis: int, window=None):
     """Causal mask of a score block whose kv positions run along
-    ``kv_axis`` (1: s = q kᵀ, 0: sᵀ = k qᵀ)."""
+    ``kv_axis`` (1: s = q kᵀ, 0: sᵀ = k qᵀ); with a ``window``, a query
+    also loses the keys ``window`` or more positions behind it."""
     qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - kv_axis)
     kpos = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, kv_axis)
-    return kpos <= qpos
+    if window is None:
+        return kpos <= qpos
+    return (kpos <= qpos) & (qpos - kpos < window)
 
 
 def _col_to_row(col):
@@ -116,8 +119,8 @@ def _col_to_row(col):
 def _loop_blocks(bounds, masked, body, carry):
     """Run ``body(j, carry, masked=m)`` over the consecutive block ranges
     [bounds[i], bounds[i + 1]) with ``m = masked[i]``: the blocks the
-    causal diagonal crosses apply the mask, wholly visible ones skip it,
-    wholly masked ones are in no range."""
+    causal diagonal or a window's edge crosses apply the mask, wholly
+    visible ones skip it, wholly masked ones are in no range."""
     for lo, hi, m in zip(bounds, bounds[1:], masked):
         if isinstance(lo, int) and isinstance(hi, int) and lo == hi:
             continue  # no causal mask: the masked range is empty
@@ -126,28 +129,57 @@ def _loop_blocks(bounds, masked, body, carry):
     return carry
 
 
-def _kv_range(qi, block_q, block_k, nk, causal):
-    """(first masked, end) kv blocks of q block ``qi``: blocks before the
-    first are wholly visible, blocks from ``end`` on wholly masked."""
-    if not causal:
-        return nk, nk
-    return (qi * block_q + 1) // block_k, \
-        jnp.minimum(nk, (qi * block_q + block_q - 1) // block_k + 1)
+# how ``_loop_blocks`` masks the three ranges a walk is cut into: the
+# kv blocks of a q block run (window's edge, wholly visible, diagonal),
+# the q blocks of a kv block (diagonal, wholly visible, window's edge)
+MASKED = (True, False, True)
 
 
-def _q_range(ki, block_q, block_k, nq, causal):
-    """(first visible, first wholly visible) q blocks of kv block ``ki``."""
+def _kv_range(qi, block_q, block_k, nk, causal, window=None):
+    """(first, first wholly visible, first on the diagonal, end) kv blocks
+    of q block ``qi``: blocks before the first and from ``end`` on are
+    wholly masked.  Without a window the first two are 0."""
     if not causal:
-        return 0, 0
-    return (ki * block_k) // block_q, jnp.minimum(
-        nq, (ki * block_k + block_k + block_q - 2) // block_q)
+        return 0, 0, nk, nk
+    if window is None:  # op for op what a causal kernel always lowered to
+        return 0, 0, (qi * block_q + 1) // block_k, \
+            jnp.minimum(nk, (qi * block_q + block_q - 1) // block_k + 1)
+    q0 = qi * block_q
+    diag = (q0 + 1) // block_k
+    end = jnp.minimum(nk, (q0 + block_q - 1) // block_k + 1)
+    # row q0 sees back to key q0 - window + 1; every row of the block
+    # sees all of a kv block that starts at q0 + block_q - window or later
+    first = jnp.maximum(q0 - window + 1, 0) // block_k
+    whole = (jnp.maximum(q0 + block_q - window, 0) + block_k - 1) // block_k
+    return first, jnp.clip(whole, first, diag), diag, end
+
+
+def _q_range(ki, block_q, block_k, nq, causal, window=None):
+    """(first visible, first wholly visible, first on the window's edge,
+    end) q blocks of kv block ``ki``.  Without a window the last two are
+    ``nq``."""
+    if not causal:
+        return 0, 0, nq, nq
+    if window is None:
+        return (ki * block_k) // block_q, jnp.minimum(
+            nq, (ki * block_k + block_k + block_q - 2) // block_q), nq, nq
+    k0 = ki * block_k
+    first = k0 // block_q
+    whole = jnp.minimum(nq, (k0 + block_k + block_q - 2) // block_q)
+    # key k0 + block_k - 1 is seen up to row k0 + block_k + window - 2;
+    # a q block is wholly inside the window of key k0 while it ends
+    # before row k0 + window
+    end = jnp.minimum(nq, (k0 + block_k + window - 2) // block_q + 1)
+    whole = jnp.minimum(whole, end)
+    return first, whole, jnp.clip((k0 + window) // block_q, whole, end), end
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
-                causal: bool, block_k: int, head_dim: int, group: int):
+                causal: bool, window, block_k: int, head_dim: int,
+                group: int):
     block_q, width = q_ref.shape
     qi, nk = pl.program_id(1), k_ref.shape[0] // block_k
-    diag, end = _kv_range(qi, block_q, block_k, nk, causal)
+    bounds = _kv_range(qi, block_q, block_k, nk, causal, window)
     q = q_ref[...]
     out = jnp.zeros(q.shape, jnp.float32)
     for a in range(group):
@@ -160,15 +192,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
             v = v_ref[pl.ds(j * block_k, block_k), :]
             s = _dot(qa, k, _NT) * sm_scale                  # [BQ, BK]
             if masked:
-                s = jnp.where(_keep(qi * block_q, j * block_k, s.shape, 1),
-                              s, NEG_INF)
+                s = jnp.where(_keep(qi * block_q, j * block_k, s.shape, 1,
+                                    window), s, NEG_INF)
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)                  # [BQ, 1]
             l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
             return m_new, l_new, acc * alpha + _dot(p.astype(v.dtype), v)
 
-        m, l, acc = _loop_blocks((0, diag, end), (False, True), body, (
+        m, l, acc = _loop_blocks(bounds, MASKED, body, (
             jnp.full((block_q, 1), NEG_INF, jnp.float32),
             jnp.zeros((block_q, 1), jnp.float32),
             jnp.zeros((block_q, width), jnp.float32)))
@@ -183,10 +215,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, sm_scale: float,
-                causal: bool, block_q: int, head_dim: int, group: int):
+                causal: bool, window, block_q: int, head_dim: int,
+                group: int):
     block_k, width = k_ref.shape
     ki, nq = pl.program_id(1), q_ref.shape[0] // block_q
-    first, whole = _q_range(ki, block_q, block_k, nq, causal)
+    bounds = _q_range(ki, block_q, block_k, nq, causal, window)
 
     @pl.when(ki == 0)
     def _():
@@ -204,7 +237,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         rows = pl.ds(i * block_q, block_q)
         q, do = q_ref[rows, :], do_ref[rows, :]
         if masked:
-            keep = _keep(i * block_q, ki * block_k, (block_k, block_q), 0)
+            keep = _keep(i * block_q, ki * block_k, (block_k, block_q), 0,
+                         window)
         # every right-hand operand is zero off head a's lanes, so the
         # heads of a block add up in one accumulator a gradient
         for a, (ka, va) in enumerate(heads):
@@ -220,7 +254,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             dq_acc[rows, :] += _dot(dst, ka, _TN)            # [BQ, W]
         return carry  # nothing: every sum lives in a scratch ref
 
-    _loop_blocks((first, whole, nq), (True, False), body, None)
+    _loop_blocks(bounds, MASKED, body, None)
     dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
     dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
@@ -235,9 +269,12 @@ class _Plan:
     score block is a grid axis, the other is whole in VMEM and walked by
     the kernel's own loop."""
 
-    def __init__(self, q, k, block_q, block_k, causal, interpret):
+    def __init__(self, q, k, block_q, block_k, causal, interpret,
+                 window=None):
         self.Lq, self.H, self.D = q.shape
         self.Lk = k.shape[0]
+        if window is not None and not causal:
+            raise ValueError("a window is defined under the causal mask only")
         self.bq, self.bk = min(block_q, self.Lq), min(block_k, self.Lk)
         if self.Lq % self.bq or self.Lk % self.bk:
             raise ValueError(
@@ -249,16 +286,31 @@ class _Plan:
         self.group = head_group(self.H, self.D) or self.H
         self.W = self.group * self.D
         self.nh = self.H // self.group
+        # q heads to a k/v head: a q head block reads the column block of
+        # its k/v head, so no repeated k or v is made in HBM
+        self.rep = self.H // k.shape[1]
+        if self.rep * k.shape[1] != self.H or (
+                self.rep > 1 and self.group > 1):
+            raise ValueError(
+                f"{self.H} q heads of {self.D} over {k.shape[1]} k/v heads: "
+                "shared k/v heads need one head a column block")
         self.nq, self.nk = self.Lq // self.bq, self.Lk // self.bk
         self.interpret = interpret
         self.consts = dict(sm_scale=1.0 / (self.D ** 0.5), causal=causal,
-                           head_dim=self.D, group=self.group)
+                           window=window, head_dim=self.D, group=self.group)
 
-    def block(self, rows):
-        return pl.BlockSpec((rows, self.W), lambda h, i: (i, h))
+    def column(self, kv):
+        """q head block -> column block: its own, or its k/v head's."""
+        rep = self.rep
+        return (lambda h: h // rep) if kv and rep > 1 else (lambda h: h)
 
-    def whole(self, rows, **kw):
-        return pl.BlockSpec((rows, self.W), lambda h, i: (0, h), **kw)
+    def block(self, rows, kv=False):
+        col = self.column(kv)
+        return pl.BlockSpec((rows, self.W), lambda h, i: (i, col(h)))
+
+    def whole(self, rows, kv=False, **kw):
+        col = self.column(kv)
+        return pl.BlockSpec((rows, self.W), lambda h, i: (0, col(h)), **kw)
 
     def call(self, name, kernel, grid, in_specs, out_specs, out_shape,
              sequential=False, scratch_shapes=(), **consts):
@@ -280,15 +332,17 @@ class _Plan:
         )
 
     def flat(self, t):
-        return t.reshape(t.shape[0], self.H * self.D)
+        return t.reshape(t.shape[0], t.shape[1] * self.D)
 
 
-def _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret):
+def _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret,
+                      window=None):
     """(o [L, H, D], lse [H, L]) of [L, H, D] inputs."""
-    pn = _Plan(q, k, block_q, block_k, causal, interpret)
+    pn = _Plan(q, k, block_q, block_k, causal, interpret, window)
     out, lse = pn.call(
         "flash_fwd", _fwd_kernel, (pn.nh, pn.nq),
-        [pn.block(pn.bq), pn.whole(pn.Lk), pn.whole(pn.Lk)],
+        [pn.block(pn.bq), pn.whole(pn.Lk, kv=True),
+         pn.whole(pn.Lk, kv=True)],
         [pn.block(pn.bq),
          pl.BlockSpec((None, pn.group, pn.bq), lambda h, i: (h, 0, i))],
         [jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.D), q.dtype),
@@ -299,7 +353,7 @@ def _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret):
 
 
 def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
-                    interpret):
+                    interpret, window=None):
     """Exact flash backward as one kernel.  Standard formulas:
 
         p_ij  = exp(s_ij - lse_i)
@@ -310,13 +364,16 @@ def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
 
     ``dlse`` is the cotangent of the lse OUTPUT (nonzero when the caller
     uses lse, e.g. the ring merge weights): d lse_i / d s_ij = p_ij.
+
+    ``dk`` and ``dv`` of a k/v head that several q heads share leave the
+    kernel a q head and are summed over the group here.
     """
-    pn = _Plan(q, k, block_q, block_k, causal, interpret)
+    pn = _Plan(q, k, block_q, block_k, causal, interpret, window)
     delta = (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(-1).T \
         - dlse.astype(jnp.float32)                           # [H, Lq]
     ops = (pn.flat(q), pn.flat(k), pn.flat(v), pn.flat(do))
     flat_q = jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.D), q.dtype)
-    flat_k = jax.ShapeDtypeStruct((pn.Lk, pn.H * pn.D), k.dtype)
+    flat_k = jax.ShapeDtypeStruct((pn.Lk, pn.H * pn.D), k.dtype)  # a q head
 
     # [H, L] statistics by head block, a q block's row on a leading dim
     # for the kernel's loop to pick
@@ -332,8 +389,8 @@ def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
         functools.partial(pn.whole, pipeline_mode=pl.Buffered(1))
     dq, dk, dv = pn.call(
         "flash_bwd", _bwd_kernel, (pn.nh, pn.nk),
-        [whole(pn.Lq), pn.block(pn.bk), pn.block(pn.bk), whole(pn.Lq),
-         rows, rows],
+        [whole(pn.Lq), pn.block(pn.bk, kv=True), pn.block(pn.bk, kv=True),
+         whole(pn.Lq), rows, rows],
         [pn.whole(pn.Lq), pn.block(pn.bk), pn.block(pn.bk)],
         [flat_q, flat_k, flat_k], sequential=True,
         scratch_shapes=[pltpu.VMEM((n, pn.W), jnp.float32)
@@ -341,27 +398,33 @@ def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
         block_q=pn.bq,
     )(*ops, *(t.reshape(pn.nh, pn.group, pn.nq, pn.bq)
               for t in (lse, delta)))
+    if pn.rep > 1:
+        dk, dv = (t.reshape(pn.Lk, pn.H // pn.rep, pn.rep, pn.D).astype(
+            jnp.float32).sum(axis=2).astype(k.dtype) for t in (dk, dv))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention_with_lse(q, k, v, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def flash_attention_with_lse(q, k, v, causal, block_q, block_k, interpret,
+                             window=None):
     """Differentiable (o, lse) pair — the ring path consumes BOTH (the
     merge weights are lse functions), so the backward carries the lse
     cotangent too (one extra ``p * dlse`` term in ds)."""
-    return _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret)
+    return _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret,
+                             window)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
+    out, lse = _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret,
+                                 window)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, block_q, block_k, interpret, window, res, g):
     q, k, v, out, lse = res
     do, dlse = g
     return _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal, block_q,
-                           block_k, interpret)
+                           block_k, interpret, window)
 
 
 flash_attention_with_lse.defvjp(_flash_fwd, _flash_bwd)
@@ -374,8 +437,15 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """Flash attention over [L, H, D] (no batch; vmap for batches).
+
+    ``window`` (causal only): a query sees the ``window`` latest keys,
+    itself included; block pairs wholly behind it are skipped like those
+    above the diagonal.  ``k`` and ``v`` may hold fewer heads than ``q``
+    (head size a multiple of 128): k/v head ``g`` serves q heads
+    ``g * rep .. (g + 1) * rep - 1``.
 
     Drop-in for ``parallel.ring_attention.blockwise_attention`` where
     shapes divide the block sizes.  DIFFERENTIABLE: the custom backward
@@ -384,8 +454,10 @@ def flash_attention(
     [L, L] (tests/test_flash_attention.py pins grads against dense
     attention).
     """
+    if window is not None and window >= k.shape[0]:
+        window = None  # every key a causal query sees is inside it
     out, _ = flash_attention_with_lse(
-        q, k, v, causal, block_q, block_k, interpret
+        q, k, v, causal, block_q, block_k, interpret, window
     )
     return out
 
@@ -394,9 +466,10 @@ def flash_attn_fn(block_q: int = 128, block_k: int = 128,
                   interpret: bool = False):
     """Adapter matching the TransformerLM ``attn_fn`` signature."""
 
-    def attn(q, k, v, causal):
+    def attn(q, k, v, causal, window=None):
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k, interpret=interpret)
+                               block_k=block_k, interpret=interpret,
+                               window=window)
 
     return attn
 

@@ -12,6 +12,13 @@ dropped token contributes its residual path only).
 
 All dispatch/combine math is einsum on one-hot masks — MXU-friendly,
 no gathers/scatters with data-dependent shapes.
+
+This is the capacity-dropping all-to-all demo, wired to the dry run only:
+top-1, ReLU, one expert a device, no model calls it.  The expert layer a
+model trains with is ``models/decoder.py``'s ``ExpertLayer``: softmax
+routing with top-k and renormalisation, no dropped token, grouped products
+over the experts it is told it holds (one chip's layer of an expert-parallel
+deployment, without the exchange this file demonstrates).
 """
 
 from __future__ import annotations

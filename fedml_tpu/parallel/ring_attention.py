@@ -57,14 +57,21 @@ def _merge(m1, l1, o1, m2, l2, o2):
     return m, l, o
 
 
-def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset):
+def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset,
+                       window=None, q_copies=1):
     """(m, l, o) partials of Q [Lq,H,D] against K/V [Lk,H,D], scanned in
     KV blocks.  Pads ragged K/V to a block multiple and masks the pad —
     the ONE shared inner loop for both the single-device and ring paths.
 
     ``q_offset``/``kv_offset`` are GLOBAL positions of the first
-    query/key; kv_offset may be a traced value (ring path).
+    query/key; kv_offset may be a traced value (ring path).  ``window``
+    (causal only) also hides keys ``window`` or more positions behind the
+    query.  ``q_copies`` says the rows of ``q`` are that many runs of the
+    same positions, one after another (the q heads that share a k/v head,
+    folded into rows by ``blockwise_attention``).
     """
+    if window is not None and not causal:
+        raise ValueError("a window is defined under the causal mask only")
     Lq, H, D = q.shape
     Lk = k.shape[0]
     bs = min(block_size, Lk)
@@ -75,6 +82,8 @@ def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset):
         v = jnp.pad(v, ((0, pad), (0, 0), (0, 0)))
 
     qpos = q_offset + jnp.arange(Lq)
+    if q_copies > 1:
+        qpos = q_offset + jnp.tile(jnp.arange(Lq // q_copies), q_copies)
 
     def body(carry, i):
         m, l, o = carry
@@ -85,9 +94,10 @@ def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset):
         bias = jnp.where(local_kpos[None, :] < Lk, 0.0, NEG_INF)
         if causal:
             kpos = kv_offset + local_kpos
-            bias = bias + jnp.where(
-                kpos[None, :] <= qpos[:, None], 0.0, NEG_INF
-            )
+            seen = kpos[None, :] <= qpos[:, None]
+            if window is not None:
+                seen &= qpos[:, None] - kpos[None, :] < window
+            bias = bias + jnp.where(seen, 0.0, NEG_INF)
         else:
             bias = jnp.broadcast_to(bias, (Lq, bs))
         mb, lb, ob = _block_attn(q, kb, vb, bias.astype(q.dtype))
@@ -116,16 +126,32 @@ def blockwise_attention(
     block_size: int = 512,
     q_offset: int = 0,
     kv_offset: int = 0,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Exact attention over [L, H, D] tensors in KV blocks (O(L) memory).
 
     ``q_offset``/``kv_offset`` are the global positions of the first
-    query/key — how ring shards express causal masks.
+    query/key — how ring shards express causal masks.  ``window``: a causal
+    query sees only the ``window`` latest keys, itself included.  ``k`` and
+    ``v`` may hold fewer heads than ``q``: k/v head ``g`` serves q heads
+    ``g * rep .. (g + 1) * rep - 1``, which are folded into rows so that no
+    repeated ``k`` or ``v`` is made.
     """
-    return _normalize(*_partial_attention(
+    Lq, H, D = q.shape
+    rep = H // k.shape[1]
+    if rep * k.shape[1] != H:
+        raise ValueError(f"{H} q heads over {k.shape[1]} k/v heads")
+    if rep > 1:
+        q = q.reshape(Lq, H // rep, rep, D).transpose(2, 0, 1, 3).reshape(
+            rep * Lq, H // rep, D)
+    out = _normalize(*_partial_attention(
         q, k, v, causal=causal, block_size=block_size,
-        q_offset=q_offset, kv_offset=kv_offset,
+        q_offset=q_offset, kv_offset=kv_offset, window=window, q_copies=rep,
     ))
+    if rep > 1:
+        out = out.reshape(rep, Lq, H // rep, D).transpose(1, 2, 0, 3).reshape(
+            Lq, H, D)
+    return out
 
 
 def ring_attention(

@@ -78,6 +78,68 @@ def test_forward_and_backward_compile_for_v5e(one_chip, shape, dtype):
     assert "flash_dq" not in text and "flash_dkv" not in text
 
 
+# (q heads, k/v heads, window) at [1, 8192, ., 128] bf16 under the model's
+# vmap: the decoder cell's sliding and full layers (4 q heads share one k/v
+# head, window 1024), and a window that is no multiple of the block
+WINDOWED = [
+    pytest.param(4, 1, 1024, id="decoder_cell_sliding"),
+    pytest.param(4, 1, None, id="decoder_cell_full"),
+    pytest.param(8, 2, 1000, id="window_off_the_blocks"),
+]
+
+
+@pytest.mark.parametrize("heads, kv_heads, window", WINDOWED)
+def test_window_and_shared_kv_heads_compile_for_v5e(one_chip, heads,
+                                                    kv_heads, window):
+    L, D = 8192, 128
+    block = pick_block(L, D)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block,
+                               block_k=block, window=window)
+
+    def grads(q, k, v, do):
+        return jax.grad(lambda q, k, v: (
+            jax.vmap(attn)(q, k, v).astype(jnp.float32)
+            * do.astype(jnp.float32)).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    spec = lambda h: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, L, h, D), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(grads).lower(
+        spec(heads), spec(kv_heads), spec(kv_heads), spec(heads)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
+
+
+def test_the_expert_layers_grouped_products_compile_for_v5e(one_chip):
+    """``megablox.gmm`` at the decoder cell's expert shapes and the tiling
+    ``gmm_tiling`` picks, forward and both gradients, with group sizes
+    that are no constants.  The library kernel's dots take the config's
+    precision, and Mosaic refuses "highest" (conftest's) on bf16 operands:
+    compiled under the default, which is what a chip run has."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from fedml_tpu.models.decoder import gmm_tiling
+
+    m, h, f, held = 65536, 2304, 896, 8
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    for k, n in ((h, f), (f, h)):
+        tiling = gmm_tiling(m, k, n)
+        assert tiling and tiling[0] == 512
+
+        def loss(x, w, sizes):
+            return gmm(x, w, sizes, jnp.bfloat16, tiling).astype(
+                jnp.float32).sum()
+
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+                spec((m, k)), spec((held, k, n)), spec((held,), jnp.int32)
+            ).compile().as_text()
+        assert text.count("tpu_custom_call") >= 2
+
+
 def test_folded_round_peak_is_below_the_stacked_rounds_by_two_models(one_chip):
     """K = 4 clients on one chip: the fused round whose client loop carries
     the weighted sum never holds the fp32 ``[K, ...]`` stack of trained

@@ -16,12 +16,18 @@ layer computes what its own experts add for the tokens routed to them and
 leaves out the rest, which is what expert parallelism asks of one chip's
 layer (without its exchange: nothing here stands in for the other chips).
 Holding every expert, it is the whole layer.  No token is dropped: the row
-buffer holds the worst case, ``tokens x min(top_k, experts held)``.
+buffer follows the rows routed.  A share of the experts sorts its rows into
+a buffer of twice what a level router sends it when the count of a call
+allows, and runs every held expert over every token, with no buffer, when it
+does not; where that buffer would be no shorter than the worst case,
+``tokens x min(top_k, experts held)``, the layer is one path through the
+worst case (``buffer_capacities``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Callable, Optional, Tuple
 
@@ -40,6 +46,7 @@ SLIDING, FULL = "sliding_attention", "full_attention"
 # the scalar counters ``DecoderLM`` sows into ``COUNTERS`` in train mode
 ASSIGNMENTS_HELD = "moe_assignments_held"  # token-expert pairs on held experts
 EXPERT_TOKENS_MAX = "moe_expert_tokens_max"  # the fullest held expert's rows
+ROWS_BUFFERED = "moe_rows_buffered"  # rows of the buffer the layer took
 
 
 @dataclasses.dataclass(frozen=True)
@@ -240,16 +247,171 @@ def _grouped_dot(rows, weights, group_sizes):
     return jax.lax.ragged_dot(rows, weights, group_sizes)
 
 
+# The short row buffer over the rows a level router sends to the experts
+# held.  PR 32 measured the held experts at 0.95-1.11 of their share under a
+# level router and at 0.3-1.6 under a collapsed one: twice the share holds
+# both, and a call that routes more runs without a buffer.
+BUFFER_HEADROOM = 2
+ROW_TILE = 512  # the grouped product's largest row tile (``gmm_tiling``)
+
+
+def buffer_capacities(tokens: int, top_k: int, held: int,
+                      routed: int) -> Tuple[int, int]:
+    """(short, worst case) rows of the expert layer's buffer, from the shapes
+    alone.  Worst case: every token's whole top-k on held experts.  Short:
+    ``BUFFER_HEADROOM`` x the ``tokens x top_k x held / routed`` rows of a
+    level router, rounded up to whole row tiles.  Where short >= worst case
+    the layer is one path through the worst case, else it takes the short
+    buffer when a call's count fits it and ``_every_expert`` when not."""
+    share = -(-tokens * top_k * held // routed)
+    short = -(-BUFFER_HEADROOM * share // ROW_TILE) * ROW_TILE
+    return short, tokens * min(top_k, held)
+
+
+@jax.custom_vjp
+def _kept_for(computed, kept):
+    """``kept``, a value of ``computed`` from an earlier pass, with
+    ``computed``'s gradient: what computed it runs no second time (nothing
+    reads it), its backward does."""
+    return kept
+
+
+_kept_for.defvjp(lambda computed, kept: (kept, None),
+                 lambda _, g: (g, None))
+
+
+def _expert_rows(capacity, kept, x, top_p, w_gate, w_up, w_down, local, order,
+                 rank, group_sizes):
+    """(the held experts' part of the layer's output [T, h] float32, the
+    buffers a backward reads again: the rows, their weights, their two grouped
+    products and the gated product) through a row buffer of ``capacity`` rows
+    (static; at least ``group_sizes.sum()``).
+
+    ``x`` [T, h], ``top_p`` [T, k]; ``local`` [T, k] is the local index of
+    each assignment's expert (the count of experts held: not here), ``order``
+    [T x k] sorts the assignments by it and ``rank`` [T, k] is the inverse.
+    ``kept``: those buffers from an earlier pass, to be read and not computed
+    again, or None."""
+    (T, k), h = top_p.shape, x.shape[-1]
+    A, here = T * k, local < w_gate.shape[0]
+    keep = iter(kept or ())
+
+    def buffer(computed):
+        return _kept_for(computed, next(keep)) if kept else computed
+
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        order, rank = order[:capacity], jnp.minimum(rank, capacity - 1)
+        row_live = (jnp.arange(capacity) < group_sizes.sum())[:, None]
+        rows = buffer(_take(x.astype(w_gate.dtype), order // k, rank, here))
+        row_w = buffer(_take(top_p.reshape(A), order, rank.reshape(A, 1),
+                             here.reshape(A, 1)))
+
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        gate = buffer(_grouped_dot(rows, w_gate, group_sizes))
+        up = buffer(_grouped_dot(rows, w_up, group_sizes))
+        act = buffer((jax.nn.silu(gate.astype(jnp.float32))
+                      * up.astype(jnp.float32) * row_w[:, None]
+                      ).astype(w_down.dtype))
+        out = _grouped_dot(act, w_down, group_sizes)
+
+    with jax.named_scope(scopes.MOE_COMBINE):
+        back = _take(out, rank.reshape(A), order[:, None], row_live)
+        y = jnp.where(here[..., None], back.reshape(T, k, h), 0).astype(
+            jnp.float32).sum(axis=1)
+    return y, (rows, row_w, gate, up, act)
+
+
+def _every_expert(x, top_p, w_gate, w_up, w_down, local):
+    """The same output with no row buffer: every held expert over every token,
+    weighted by the token's routing weight for it or 0 (``local`` [T, k]: the
+    local index of each assignment's expert).  The products of the worst case
+    (every token's whole top-k here) whatever was routed, as plain matmuls
+    an expert at a time: no sort, no gather, no kernel of its own to compile,
+    and each expert computes its forward again in the backward.  (As three
+    matmuls batched over the experts it held 0.22 GiB more and, never run,
+    cost the cell 3 % of its tokens/s: PERF.md §6, PR 33.)"""
+    rows = x.astype(w_gate.dtype)
+
+    def one(y, expert):
+        e, gate_w, up_w, down_w = expert
+        weight = jnp.where(local == e, top_p, 0).sum(axis=-1, keepdims=True)
+        act = (jax.nn.silu((rows @ gate_w).astype(jnp.float32))
+               * (rows @ up_w).astype(jnp.float32) * weight)
+        return y + (act.astype(down_w.dtype) @ down_w).astype(jnp.float32), None
+
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        y, _ = jax.lax.scan(
+            jax.checkpoint(one), jnp.zeros(x.shape, jnp.float32),
+            (jnp.arange(w_gate.shape[0]), w_gate, w_up, w_down))
+    return y
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _expert_rows_fitted(capacity, fits, *operands):
+    """``_expert_rows`` through ``capacity`` rows where ``fits`` (a traced
+    bool), else ``_every_expert``.
+
+    Differentiated by hand, a ``cond`` forward and a ``cond`` backward.  JAX's
+    own rule splits each branch into a part that makes residuals and a part
+    that reads them, hands the second ``cond`` the union of both branches'
+    residuals, and has each branch zero-fill the other's and copy the
+    operands it reads again.  Here the buffered branch keeps its own five
+    buffers, the other keeps nothing, and neither copies an operand."""
+    return _fitted_fwd(capacity, fits, *operands)[0]
+
+
+def _fitted_fwd(capacity, fits, *operands):
+    buffered = functools.partial(_expert_rows, capacity, None)
+    _, kept = jax.eval_shape(buffered, *operands)
+
+    def unbuffered(x, top_p, w_gate, w_up, w_down, local, *_):
+        return (_every_expert(x, top_p, w_gate, w_up, w_down, local),
+                tuple(jnp.zeros(b.shape, b.dtype) for b in kept))
+
+    y, kept = jax.lax.cond(fits, buffered, unbuffered, *operands)
+    return y, (fits, operands, kept)
+
+
+def _fitted_bwd(capacity, res, g):
+    fits, (*floats, local, order, rank, group_sizes), kept = res
+
+    def grads(path):
+        return jax.vjp(path, *floats)[1](g)
+
+    d_floats = jax.lax.cond(
+        fits,
+        lambda: grads(lambda *floats: _expert_rows(
+            capacity, kept, *floats, local, order, rank, group_sizes)[0]),
+        lambda: grads(lambda *floats: _every_expert(*floats, local)))
+    return (None, *d_floats, None, None, None, None)
+
+
+_expert_rows_fitted.defvjp(_fitted_fwd, _fitted_bwd)
+
+
 class ExpertLayer(nn.Module):
     """Dropless top-k mixture of gated SiLU experts over the experts held.
 
     ``x`` [B, L, h] float32 (the normed input).  Router and selection run in
     float32; the expert products run in the dtype of the expert weights.
     Returns (``sum over e in top-k and held of w_e f_e(x)`` in the experts'
-    dtype, the two counters as float32 scalars).  Not under a ``vmap`` over
-    clients (``client_axis_impl="vmap"``): ``lax.ragged_dot`` refuses
-    stacked expert weights ("ragged_dot vmap ... NYI"); the default client
-    loop is a scan."""
+    dtype, the three counters as float32 scalars).
+
+    The row buffer is as long as ``buffer_capacities`` says.  Where the short
+    one is shorter than the worst case, a ``lax.cond`` on this call's count of
+    routed rows (``_expert_rows_fitted``) takes it when the count fits, and
+    else runs every held expert over every token (``_every_expert``: the
+    worst case's products, no buffer, nothing kept for the backward, which
+    computes the forward again).  Both paths compute the same sums, and no
+    token is dropped on either; ``moe_rows_buffered`` says which ran: the
+    short buffer's rows, or ``tokens x experts held``.  Where the short
+    buffer is no shorter (every expert held) there is no branch and the
+    buffer is the worst case.
+
+    Not under a ``vmap`` over clients (``client_axis_impl="vmap"``):
+    ``lax.ragged_dot`` refuses stacked expert weights ("ragged_dot vmap ...
+    NYI"), and a batched ``cond`` is a select that runs both paths; the
+    default client loop is a scan."""
 
     num_experts_routed: int
     experts_held: Tuple[int, ...]
@@ -261,7 +423,8 @@ class ExpertLayer(nn.Module):
     def __call__(self, x):
         B, L, h = x.shape
         T, k, held = B * L, self.top_k, len(self.experts_held)
-        A, R = T * k, T * min(k, held)  # assignments, rows of the buffer
+        A = T * k  # assignments
+        short, worst = buffer_capacities(T, k, held, self.num_experts_routed)
         init = nn.initializers.lecun_normal()
         stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                                batch_axis=0)
@@ -285,34 +448,27 @@ class ExpertLayer(nn.Module):
             local_of = np.full((self.num_experts_routed,), held, np.int32)
             local_of[list(self.experts_held)] = np.arange(held)
             local = jnp.asarray(local_of)[top_e]  # [T, k]
-            here = local < held
             # a counting sort by expert: held assignments first, by expert
             order = jnp.argsort(local.reshape(A), stable=True)
             rank = jnp.argsort(order).reshape(T, k)  # the inverse
             group_sizes = (local.reshape(A, 1) == jnp.arange(held)).sum(
                 axis=0, dtype=jnp.int32)
             routed = group_sizes.sum()
-            order, rank = order[:R], jnp.minimum(rank, R - 1)
-            row_live = (jnp.arange(R) < routed)[:, None]
-            rows = _take(x.astype(w_gate.dtype), order // k, rank, here)
-            row_w = _take(top_p.reshape(A), order, rank.reshape(A, 1),
-                          here.reshape(A, 1))
 
-        with jax.named_scope(scopes.MOE_EXPERTS):
-            gate = _grouped_dot(rows, w_gate, group_sizes)
-            up = _grouped_dot(rows, w_up, group_sizes)
-            act = (jax.nn.silu(gate.astype(jnp.float32))
-                   * up.astype(jnp.float32) * row_w[:, None])
-            out = _grouped_dot(act.astype(w_down.dtype), w_down, group_sizes)
-
-        with jax.named_scope(scopes.MOE_COMBINE):
-            back = _take(out, rank.reshape(A), order[:, None], row_live)
-            y = jnp.where(here[..., None], back.reshape(T, k, h), 0).astype(
-                jnp.float32).sum(axis=1)
+        operands = (x, top_p, w_gate, w_up, w_down, local, order, rank,
+                    group_sizes)
+        if short >= worst:
+            y, _ = _expert_rows(worst, None, *operands)
+            buffered = float(worst)
+        else:
+            fits = routed <= short
+            y = _expert_rows_fitted(short, fits, *operands)
+            buffered = jnp.where(fits, float(short), float(T * held))
 
         counters = {
             ASSIGNMENTS_HELD: routed.astype(jnp.float32),
             EXPERT_TOKENS_MAX: group_sizes.max().astype(jnp.float32),
+            ROWS_BUFFERED: jnp.asarray(buffered, jnp.float32),
         }
         return y.astype(w_down.dtype).reshape(B, L, h), counters
 

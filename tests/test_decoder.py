@@ -17,7 +17,8 @@ from benchmark.families import mellum_moe_plain as plain  # noqa: E402
 from fedml_tpu.models import decoder  # noqa: E402
 from fedml_tpu.models.base import COUNTERS  # noqa: E402
 from fedml_tpu.models.decoder import (  # noqa: E402
-    ASSIGNMENTS_HELD, EXPERT_TOKENS_MAX, ExpertLayer, decoder_lm,
+    ASSIGNMENTS_HELD, EXPERT_TOKENS_MAX, ROWS_BUFFERED, ExpertLayer,
+    buffer_capacities, decoder_lm,
 )
 
 YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
@@ -231,6 +232,115 @@ def test_expert_layer_gradients_match_the_reference_on_a_share():
         assert rel(a, b) < 1e-5
 
 
+# -- the row buffer follows the rows routed ------------------------------------------
+
+BRANCH_HELD, BRANCH_T = [3, 9], 1024  # 2 of 16 held, top 4: short 1024, worst 2048
+
+
+def branch_case(kind):
+    """(params of the share, x [1, 1024, 32]) whose router is level, sends
+    every token's whole top-k share to the held experts, or puts expert 3 in
+    every token's top-k and expert 9 in none (``routed == short`` exactly)."""
+    params = expert_params(jax.random.PRNGKey(12))
+    x = jax.random.normal(jax.random.PRNGKey(13), (1, BRANCH_T, 32))
+    if kind != "level":  # |x . 1| dominates the logits of the columns set
+        x = x + 3.0
+        router = params["router"].at[:, 3].set(1.0)
+        params["router"] = router.at[:, 9].set(1.0 if kind == "all_held"
+                                               else -1.0)
+    return share_of(params, BRANCH_HELD), x
+
+
+@pytest.mark.parametrize("kind, capacity", [
+    ("level", 1024), ("all_held", 2048), ("exactly_short", 1024)])
+def test_the_row_buffer_follows_the_rows_routed(kind, capacity):
+    assert buffer_capacities(BRANCH_T, 4, 2, 16) == (1024, 2048)
+    params, x = branch_case(kind)
+    cfg = {"num_experts": 2, "num_experts_routed": 16,
+           "experts_held": BRANCH_HELD, "num_experts_per_tok": 4}
+    layer = ExpertLayer(16, tuple(BRANCH_HELD), 4, 24)
+    probe = jax.random.normal(jax.random.PRNGKey(14), (BRANCH_T, 32))
+
+    def ours(p, x):
+        y, counters = layer.apply({"params": p}, x)
+        return (y.reshape(-1, 32) * probe).sum(), (y, counters)
+
+    def theirs(p, x):
+        y, chosen = plain.expert_layer(cfg, x.reshape(-1, 32), p)
+        return (y * probe).sum(), (y, chosen)
+
+    g_ours, (y, counters) = jax.grad(ours, argnums=(0, 1), has_aux=True)(
+        params, x)
+    g_theirs, (want, chosen) = jax.grad(theirs, argnums=(0, 1), has_aux=True)(
+        params, x)
+    assert rel(y.reshape(-1, 32), want) < 1e-5
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_ours),
+                            jax.tree_util.tree_leaves(g_theirs)):
+        assert rel(a, b) < 1e-5, jax.tree_util.keystr(path)
+    by_hand = np.bincount(np.asarray(chosen).reshape(-1),
+                          minlength=16)[BRANCH_HELD]
+    assert float(counters[ROWS_BUFFERED]) == capacity
+    assert float(counters[ASSIGNMENTS_HELD]) == by_hand.sum()
+    assert float(counters[EXPERT_TOKENS_MAX]) == by_hand.max()
+    if kind == "all_held":
+        assert by_hand.tolist() == [BRANCH_T, BRANCH_T]
+    elif kind == "exactly_short":
+        assert by_hand.tolist() == [BRANCH_T, 0]
+    else:
+        assert 256 < by_hand.sum() < 1024
+
+
+def rehearsal_config():
+    from benchmark import cells
+
+    return dict(cells.load_cell("mellum2_silo_code8k", rehearsal=True).config)
+
+
+@pytest.mark.parametrize("config", [WHOLE, SHARE, rehearsal_config],
+                         ids=["whole", "share", "rehearsal"])
+def test_a_share_no_shorter_than_the_worst_case_has_no_branch(config):
+    """Where twice the level share is no shorter than the worst case the
+    layer is one path: the lowered model holds no conditional."""
+    cfg = config() if callable(config) else config
+    bundle = decoder_lm({**cfg, "n_positions": 32})
+    x = jnp.zeros((2, 32), jnp.int32)
+    variables = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
+    text = jax.jit(jax.grad(lambda v: bundle.apply_train(v, x)[0].sum())
+                   ).lower(variables).as_text()
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert "conditional" not in text
+
+
+def conds_of(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from conds_of(sub)
+
+
+def test_the_fallback_hands_the_backward_no_buffer_of_its_own():
+    """Where the branch exists, the gradient holds two ``cond``s (forward and
+    backward) and neither returns an array of the worst case's length: what
+    the worst case needs again it computes again."""
+    params, x = branch_case("level")
+    layer = ExpertLayer(16, tuple(BRANCH_HELD), 4, 24)
+    short, worst = buffer_capacities(BRANCH_T, 4, 2, 16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, x: layer.apply({"params": p}, x)[0].sum(),
+        argnums=(0, 1)))(params, x)
+    conds = list(conds_of(jaxpr.jaxpr))
+    assert len(conds) == 2
+    shapes = [v.aval.shape for eqn in conds for v in eqn.outvars]
+    assert all(s[:1] != (worst,) for s in shapes), shapes
+    # the forward returns y and the short path's five buffers, nothing else
+    assert [v.aval.shape for v in conds[0].outvars] == [
+        (BRANCH_T, 32), (short, 32), (short,), (short, 24), (short, 24),
+        (short, 24)]
+    text = jax.jit(layer.apply).lower({"params": params}, x).as_text()
+    assert "stablehlo.case" in text or "stablehlo.if" in text
+
+
 # -- through the round path ---------------------------------------------------------
 
 def round_of(cfg, client_axis_impl="map"):
@@ -276,7 +386,37 @@ def test_counters_leave_with_the_metrics_and_never_enter_variables():
     # 2 clients x 2 steps x 4 layers x 64 tokens x top 2, 3 of 8 experts held
     assert 0 < held < 2 * 2 * 4 * 64 * 2 and held == int(held)
     assert held / 3 <= fullest <= held
+    # one path at these shapes: every layer-step buffers its worst case
+    assert float(metrics[ROWS_BUFFERED][0]) == 2 * 2 * 4 * 64 * 2
     assert float(metrics["count"][0]) == 2 * 2 * 2 * 32
+
+
+@pytest.mark.parametrize("counters, want", [
+    ({ASSIGNMENTS_HELD: [8192.0, 8200.0], ROWS_BUFFERED: [16384.0, 16384.0]},
+     100 * 16392 / 32768),
+    ({ASSIGNMENTS_HELD: [8192.0, 20000.0], ROWS_BUFFERED: [16384.0, 65536.0]},
+     100 * 28192 / 81920),  # a fallback pulls the fill down
+    ({ASSIGNMENTS_HELD: [8192.0, 8200.0]}, None),  # the parent's program
+    ({}, None),
+], ids=["short", "one_fallback", "no_rows_counter", "no_counter"])
+def test_buffer_fill_is_held_assignments_over_rows_buffered(counters, want):
+    """``benchmark/layer_metrics/expert_buffer_fill_pct.py`` on a made-up
+    context: two traced calls' metrics, one entry a round."""
+    import types
+
+    from benchmark import cells
+
+    calls = [(0.0, 0.1, 1, {"count": np.array([8192.0]),
+                            **{k: np.array([v[i]]) for k, v in counters.items()}})
+             for i in range(2)]
+    got = cells.load_layer_metric("expert_buffer_fill_pct").read(
+        types.SimpleNamespace(calls=calls))
+    assert got == (want if want is None else pytest.approx(want))
+    entry = {m["name"]: m for m in cells.manifest()["per_layer"]}[
+        "expert_buffer_fill_pct"]
+    assert entry["workloads"] == ["mellum2_silo_code8k"]
+    assert (entry["source"], entry["moves"]) == ("program_counter",
+                                                 "tokens_per_s")
 
 
 def test_a_model_without_counters_reports_none():

@@ -140,6 +140,41 @@ def test_the_expert_layers_grouped_products_compile_for_v5e(one_chip):
         assert text.count("tpu_custom_call") >= 2
 
 
+def test_the_expert_layer_with_its_short_buffer_compiles_for_v5e(
+        one_chip, monkeypatch):
+    """One ``ExpertLayer`` at the decoder cell's shapes (8192 tokens, 8 of 64
+    experts held, top 8), forward and backward: the branch on the count of
+    routed rows is two conditionals, the nine grouped-product kernels stand
+    in the 16,384-row branch only (the other runs plain matmuls), and the
+    program's scratch (0.65 GiB) stays well under what the 65,536-row buffer
+    took (1.21 GiB, compiled the same way at PR 32's commit)."""
+    from fedml_tpu.models.decoder import ExpertLayer, buffer_capacities
+
+    T, h, f, held = 8192, 2304, 896, 8
+    assert buffer_capacities(T, 8, held, 64) == (16384, 65536)
+    # the layer asks the backend for its grouped product; the compile is for
+    # the described chip whatever this process runs on
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = ExpertLayer(64, tuple(range(held)), 8, f)
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    params = {"router": spec((h, 64), jnp.float32), "gate": spec((held, h, f)),
+              "up": spec((held, h, f)), "down": spec((held, f, h))}
+
+    def loss(p, x, dy):
+        y, counters = layer.apply({"params": p}, x)
+        return (y.astype(jnp.float32) * dy).sum() + sum(counters.values())
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            params, spec((1, T, h), jnp.float32), spec((1, T, h), jnp.float32)
+        ).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 2
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8 * 2**30
+
+
 def test_folded_round_peak_is_below_the_stacked_rounds_by_two_models(one_chip):
     """K = 4 clients on one chip: the fused round whose client loop carries
     the weighted sum never holds the fp32 ``[K, ...]`` stack of trained

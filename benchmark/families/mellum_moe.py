@@ -10,6 +10,7 @@ Beside what every family exposes (``families/transformer_lm.py`` lists it):
                             for a driver to hand ``run.py:check_reference``
   attention_pairs(config)   (query, key) pairs the masks need, by layer type
   attention_pairs_per_sample(config)   the same through every layer
+  attention_heads(config)   (q heads, head size) of the flash kernels
   held_share(config)        expected held assignments a token a layer
   expert_flops_per_assignment(config), expert_train_bytes(config, ...)
 """
@@ -47,6 +48,10 @@ def attention_pairs_per_sample(config: dict) -> int:
     """Pairs of one sequence through every layer."""
     pairs = attention_pairs(config)
     return sum(pairs[k] for k in mellum_moe_plain.layer_types(config))
+
+
+def attention_heads(config: dict) -> tuple:
+    return config["num_attention_heads"], config["head_dim"]
 
 
 def held_share(config: dict) -> float:

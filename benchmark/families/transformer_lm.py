@@ -11,6 +11,9 @@ in the harness knows a family by name:
   units_per_sample(config)    what the throughput metric counts in one sample
   fwd_flops_per_unit(config)  {op class: forward FLOPs of one unit}
   train_bytes_per_unit(config, batch_units)  {op class: least HBM bytes}
+
+and, where its attention runs in the flash kernels, what ``flash_roofline``
+asks for: ``attention_pairs_per_sample(config)``, ``attention_heads(config)``.
 """
 
 from __future__ import annotations
@@ -37,6 +40,18 @@ def make_samples(config: dict, n: int, rng: np.random.Generator):
 def units_per_sample(config: dict) -> int:
     """A sample is one context: the metric counts its tokens."""
     return config["n_positions"]
+
+
+def attention_pairs_per_sample(config: dict) -> int:
+    """(query, key) pairs of one sequence that the causal mask lets through,
+    over every layer: a query at i sees i + 1 keys."""
+    L = config["n_positions"]
+    return config["n_layer"] * (L * (L + 1) // 2)
+
+
+def attention_heads(config: dict) -> tuple:
+    """(q heads, head size)."""
+    return config["n_head"], config["n_embd"] // config["n_head"]
 
 
 def fwd_flops_per_unit(config: dict) -> dict:

@@ -59,7 +59,11 @@ class CompileWatch:
 
 def check_reference(cell, session, seed: int) -> dict:
     """One round of the system on the reduced cohort against the plain
-    reference (``reference.py``), from the session's current state."""
+    reference (``reference.py``), from the session's current state, which
+    it leaves whole.  The system's new variables wait on the host while the
+    reference runs: it needs the room (12-16 bytes a parameter beside the
+    state), and ``compare`` takes them back when its trees are gone."""
+    import jax
     import numpy as np
 
     from benchmark import reference
@@ -67,6 +71,7 @@ def check_reference(cell, session, seed: int) -> dict:
     block = reference.reference_block(cell.config, cell.reference, seed)
     old = session.state
     new_vars, metrics = session.reference_round(block)
+    new_vars = jax.device_get(new_vars)  # and the device's copy is dropped
     sys_loss = float(np.sum(metrics["loss_sum"]) / np.sum(metrics["count"]))
     ref_delta, ref_loss = reference.reference_round(
         session.bundle, cell.config, old.variables, old.key,
@@ -202,13 +207,18 @@ def main() -> int:
     compiles_in_window = len(watch.compiles) - n_compiles
     rounds_run = sum(c[2] for c in calls)
     failed = sum(not call_ok(c[3], session.cohort) for c in calls)
-    advanced = session.round_idx() == round_idx0 + rounds_run
+    rounds_short = round_idx0 + rounds_run - session.round_idx()
     # memory is read before the reference runs: the peak is the system's own
     memory = {**memory_stats(devices), "live_peak_before_calls": live_before}
     agreement = check_reference(cell, session, args.seed)
     phases["reference_end_s"] = time.time() - T_START
+    # the process's peaks again: what the check itself took, where it took
+    # more than the calls (a peak never falls)
+    after = memory_stats(devices)
+    reference_memory = {f"reference_{k}": after.get(k, 0) for k in (
+        "peak_bytes_in_use", "peak_bytes_reserved")}
     correct = bool(agreement["ok"] and compiles_in_window == 0
-                   and failed == 0 and calls and advanced)
+                   and failed == 0 and calls and rounds_short == 0)
 
     rates = [float(np.sum(c[3]["count"])) / (c[1] - c[0]) for c in calls]
     rate = statistics.median(rates) if rates else None
@@ -249,7 +259,8 @@ def main() -> int:
         "rounds": rounds_run, "call_s": [round(c[1] - c[0], 4) for c in calls][:32],
         "warmup_call_s": round(warm[1] - warm[0], 4),
         "setup_s": round(setup_s, 2),
-        "reference": agreement, "compiles_in_window": compiles_in_window,
+        "reference": agreement, "reference_memory": reference_memory,
+        "compiles_in_window": compiles_in_window,
         "compile_s": round(sum(s for _, s in watch.compiles), 2),
         "cache": watch.cache, "cache_dir": cache_dir,
         "memory_stats": memory, "phases_s": {k: round(v, 2)
@@ -264,7 +275,19 @@ def main() -> int:
            "metrics": metrics, "device": device, "detail": detail}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    # every number ``correct`` compared, beside its limit: the last key of
+    # the line, and the last lines on standard error
+    compared = (
+        ("delta_rel_l2", agreement["delta_rel_l2"], agreement["delta_limit"]),
+        ("loss_rel", agreement["loss_rel"], agreement["loss_limit"]),
+        ("state_finite", int(agreement["finite"]), 1),
+        ("compiles_in_window", compiles_in_window, 0),
+        ("failed_calls", failed, 0),
+        ("rounds_not_counted", rounds_short, 0))
+    out["checks"] = {n: {"value": v, "limit": l} for n, v, l in compared}
     print(json.dumps(out), flush=True)
+    for n, v, l in compared:
+        print(f"check {n} {v} limit {l}", file=sys.stderr, flush=True)
     return 0  # a result was printed; ``correct`` says whether it counts
 
 

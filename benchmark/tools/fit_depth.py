@@ -1,7 +1,11 @@
 """Compile-only rehearsal: which depth of a transformer configuration fits one
 described v5e chip through a cell's round program (on-chip-measurement guide,
 section 2.3).  Nothing runs and no chip is needed; what it prints is the
-compiler's own memory analysis, never a chip measurement.
+compiler's own memory analysis, never a chip measurement.  The program
+chooses its attention and its grouped products from ``jax.default_backend()``,
+which is the CPU here: while the program is traced the tool answers "tpu" in
+its place, so that what compiles is the program the cells run (the flash
+kernels, ``megablox.gmm``), not the lax path of this sandbox.
 
     JAX_PLATFORMS=cpu python benchmark/tools/fit_depth.py \
         --workload gpt2l_silo_fused --layers 12 18 24
@@ -10,6 +14,7 @@ compiler's own memory analysis, never a chip measurement.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -20,6 +25,18 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
+
+
+@contextlib.contextmanager
+def backend_reads_tpu():
+    import jax
+
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        yield
+    finally:
+        jax.default_backend = real
 
 
 def main() -> None:
@@ -62,7 +79,8 @@ def main() -> None:
                        on_block)
         t0 = time.time()
         try:
-            compiled = round_fn.lower(state, *block).compile()
+            with backend_reads_tpu():
+                compiled = round_fn.lower(state, *block).compile()
         except Exception as e:  # the compiler's refusal is the answer
             print(str(e)[:6000], file=sys.stderr)  # the largest buffers
             print(json.dumps({"n_layer": n_layer, "fits": False,

@@ -2,13 +2,33 @@
 
 Where ``models/transformer.py`` is one fixed block (LayerNorm, learned
 positions, GELU MLP, tied head), this one reads what a published
-``config.json`` says: RMSNorm, rotary positions (plain or YaRN, chosen by
-the layer's type), q heads that share k/v heads, a head size of its own,
-sliding-window and full attention layers mixed by ``layer_types``, a sparse
-expert layer in place of the MLP, and an untied head.  It shares
-``MultiHeadAttention`` (and so the attention policy and the flash kernels)
-with the transformer, and trains through ``make_local_update`` like any
-``ModelBundle``.
+``config.json`` says: pre-norm RMSNorm blocks and an untied head, and a layer
+at a time a sequence mixer and an MLP of the kinds the configuration names.
+It shares ``MultiHeadAttention`` (and so the attention policy and the flash
+kernels) with the transformer, and trains through ``make_local_update`` like
+any ``ModelBundle``.
+
+Mixer kinds (``DecoderConfig.layer_types``, one a layer):
+
+- ``sliding_attention`` / ``full_attention``: softmax attention with q heads
+  that share k/v heads and a head size of its own, a window or none; rotary
+  positions (plain or YaRN, by the layer's type) where the configuration has
+  ``rope_parameters``, no positional encoding where it has none.
+- ``latent_attention``: softmax attention whose keys and values are expanded
+  from a normed low-rank latent, plus a key part that all heads share; q and k
+  heads of ``qk_nope_head_dim + qk_rope_head_dim``, v heads of ``v_head_dim``
+  (``LatentQKV``, handed to ``MultiHeadAttention`` as its projection).
+- ``linear_attention``: the gated delta rule with a per-channel decay
+  (``ops/linear_attention.py``), behind short causal depthwise convolutions,
+  with low-rank decay and output gates and a gated RMSNorm a head
+  (``LinearAttention``).  Position comes from the recurrence.
+
+MLP kinds (``mlp_types``): ``sparse``, the expert layer below, with a shared
+expert (a ``GatedMLP`` every token passes) added outside the routed sum where
+the configuration has one; ``dense``, one ``GatedMLP``.  Router kinds
+(``router_activation``): ``softmax`` over all routed experts, or ``sigmoid``
+scores; either renormalises the chosen ``top_k`` where ``norm_topk_prob``
+and scales them by ``routed_scaling_factor``.
 
 The expert layer (``ExpertLayer``) is told which experts it holds.  The
 router keeps its full width and picks ``top_k`` of all experts; this
@@ -41,12 +61,17 @@ from fedml_tpu.models.transformer import (
     AttnFn, MultiHeadAttention, _default_attn,
 )
 from fedml_tpu.obs import scopes
+from fedml_tpu.ops.linear_attention import gated_delta_rule, short_causal_conv
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+LINEAR, LATENT = "linear_attention", "latent_attention"
+SPARSE, DENSE = "sparse", "dense"
 # the scalar counters ``DecoderLM`` sows into ``COUNTERS`` in train mode
 ASSIGNMENTS_HELD = "moe_assignments_held"  # token-expert pairs on held experts
 EXPERT_TOKENS_MAX = "moe_expert_tokens_max"  # the fullest held expert's rows
 ROWS_BUFFERED = "moe_rows_buffered"  # rows of the buffer the layer took
+# a linear-attention layer's mean log decay over tokens, heads and channels
+KDA_LOG_DECAY_MEAN = "kda_log_decay_mean"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,7 +82,7 @@ class DecoderConfig:
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    layer_types: Tuple[str, ...]  # one a layer: SLIDING or FULL
+    layer_types: Tuple[str, ...]  # one a layer: SLIDING, FULL, LINEAR, LATENT
     sliding_window: int
     rope: Tuple[Tuple[str, Tuple[Tuple[str, object], ...]], ...]  # by layer type
     rms_norm_eps: float
@@ -68,24 +93,54 @@ class DecoderConfig:
     norm_topk_prob: bool
     max_len: int
     remat: bool = False
+    mlp_types: Tuple[str, ...] = ()  # one a layer: SPARSE or DENSE; () all SPARSE
+    intermediate_size: int = 0  # the dense MLP's width
+    shared_expert_size: int = 0  # the shared expert's width; 0: none
+    router_activation: str = "softmax"  # or "sigmoid"
+    routed_scaling_factor: float = 1.0
+    # LINEAR layers: heads, head size, convolution taps, the scan's chunk
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    conv_kernel: int = 4
+    chunk: int = 64
+    # LATENT layers: the latent's rank and the three head sizes
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     @classmethod
     def from_dict(cls, c: dict) -> "DecoderConfig":
         """From a ``config.json``'s keys.  Depth is ``n_layer`` or
-        ``num_hidden_layers`` and ``layer_types`` is cycled to it;
-        ``num_experts_routed`` defaults to ``num_experts`` and
-        ``experts_held`` to the first ``num_experts`` ids."""
+        ``num_hidden_layers``.  A layer's mixer comes from
+        ``linear_attn_config`` (``kda_layers`` and ``full_attn_layers``,
+        numbered from 1; a full layer is LATENT where ``kv_lora_rank`` is
+        set) or from ``layer_types``, cycled to the depth; its MLP from
+        ``mlp_layer_types`` or from ``first_k_dense_replace`` leading dense
+        layers.  ``num_experts_routed`` defaults to ``num_experts``,
+        ``experts_held`` to the first ``num_experts`` ids, and
+        ``linear_attn_heads`` to ``linear_attn_config.num_heads``."""
         depth = c.get("n_layer", c.get("num_hidden_layers"))
-        if set(c.get("mlp_layer_types", ["sparse"])) != {"sparse"}:
-            raise ValueError("only sparse (expert) MLP layers are built")
-        kinds = c.get("layer_types") or [FULL]
+        linear = c.get("linear_attn_config") or {}
+        if linear:
+            kinds = [LINEAR if i + 1 in linear["kda_layers"]
+                     else LATENT if c.get("kv_lora_rank") else FULL
+                     for i in range(depth)]
+        else:
+            kinds = c.get("layer_types") or [FULL]
+        mlp = c.get("mlp_layer_types") or [
+            DENSE if i < c.get("first_k_dense_replace", 0) else SPARSE
+            for i in range(depth)]
+        if not set(mlp) <= {SPARSE, DENSE}:
+            raise ValueError(f"MLP kinds {sorted(set(mlp))}: only "
+                             f"{SPARSE!r} and {DENSE!r} are built")
         routed = c.get("num_experts_routed", c["num_experts"])
         held = tuple(c.get("experts_held", range(c["num_experts"])))
         if len(held) != c["num_experts"] or not all(
                 0 <= e < routed for e in held):
             raise ValueError(f"experts_held {held} against num_experts "
                              f"{c['num_experts']} of {routed} routed")
-        rope = c["rope_parameters"]
+        rope = c.get("rope_parameters") or {}  # none: no positional encoding
         if "rope_type" in rope:  # one block for every layer type
             rope = {SLIDING: rope, FULL: rope}
         return cls(
@@ -102,10 +157,25 @@ class DecoderConfig:
             rms_norm_eps=c.get("rms_norm_eps", 1e-6),
             moe_intermediate_size=c["moe_intermediate_size"],
             num_experts_routed=routed, experts_held=held,
-            top_k=c["num_experts_per_tok"],
-            norm_topk_prob=c.get("norm_topk_prob", True),
+            top_k=c.get("num_experts_per_tok", c.get("num_experts_per_token")),
+            norm_topk_prob=c.get("norm_topk_prob",
+                                 c.get("moe_renormalize", True)),
             max_len=c.get("n_positions", c.get("max_position_embeddings")),
             remat=c.get("remat", False),
+            mlp_types=tuple(mlp[i % len(mlp)] for i in range(depth)),
+            intermediate_size=c.get("intermediate_size", 0),
+            shared_expert_size=(c.get("num_shared_experts", 0)
+                                * c["moe_intermediate_size"]),
+            router_activation=c.get("moe_router_activation_func", "softmax"),
+            routed_scaling_factor=c.get("routed_scaling_factor", 1.0),
+            linear_heads=c.get("linear_attn_heads", linear.get("num_heads", 0)),
+            linear_head_dim=linear.get("head_dim", 0),
+            conv_kernel=linear.get("short_conv_kernel_size", 4),
+            chunk=c.get("chunk", 64),
+            kv_lora_rank=c.get("kv_lora_rank") or 0,
+            qk_nope_head_dim=c.get("qk_nope_head_dim", 0),
+            qk_rope_head_dim=c.get("qk_rope_head_dim", 0),
+            v_head_dim=c.get("v_head_dim", 0),
         )
 
 
@@ -395,7 +465,10 @@ class ExpertLayer(nn.Module):
     ``x`` [B, L, h] float32 (the normed input).  Router and selection run in
     float32; the expert products run in the dtype of the expert weights.
     Returns (``sum over e in top-k and held of w_e f_e(x)`` in the experts'
-    dtype, the three counters as float32 scalars).
+    dtype, the three counters as float32 scalars).  The scores are a softmax
+    over all routed experts or a sigmoid an expert (``router_activation``);
+    the ``top_k`` largest are chosen, renormalised to sum to 1 where
+    ``norm_topk_prob`` and multiplied by ``routed_scaling_factor``.
 
     The row buffer is as long as ``buffer_capacities`` says.  Where the short
     one is shorter than the worst case, a ``lax.cond`` on this call's count of
@@ -418,6 +491,8 @@ class ExpertLayer(nn.Module):
     top_k: int
     intermediate: int
     norm_topk_prob: bool = True
+    router_activation: str = "softmax"  # or "sigmoid": a score an expert
+    routed_scaling_factor: float = 1.0  # on the chosen experts' weights
 
     @nn.compact
     def __call__(self, x):
@@ -437,9 +512,18 @@ class ExpertLayer(nn.Module):
         with jax.named_scope(scopes.MOE_ROUTER):
             logits = jnp.dot(x, router.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+            if self.router_activation == "softmax":
+                scores = jax.nn.softmax(logits, axis=-1)
+            elif self.router_activation == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+            else:
+                raise ValueError(
+                    f"unknown router activation {self.router_activation!r}")
+            top_p, top_e = jax.lax.top_k(scores, k)
             if self.norm_topk_prob:
                 top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+            if self.routed_scaling_factor != 1.0:
+                top_p = top_p * self.routed_scaling_factor
             # for a caller that asks for "intermediates": the selection
             self.sow("intermediates", "top_e", top_e)
 
@@ -481,30 +565,185 @@ def _scoped(attn: AttnFn, name: str) -> AttnFn:
     return fn
 
 
+class GatedMLP(nn.Module):
+    """``(silu(x Wg) * (x Wu)) Wd`` with no bias: the dense MLP of a layer,
+    and the shared expert beside the routed ones."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        gate = nn.Dense(self.width, use_bias=False, name="gate")(x)
+        up = nn.Dense(self.width, use_bias=False, name="up")(x)
+        act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+        return nn.Dense(x.shape[-1], use_bias=False, name="down")(
+            act.astype(x.dtype))
+
+
+class LatentQKV(nn.Module):
+    """The projections of a latent-attention layer, for ``MultiHeadAttention``
+    to call in place of its fused one: ``q`` a head of ``nope + rope`` from
+    ``x``; a ``rank + rope`` wide down-projection whose first ``rank``
+    columns are normed and expanded to every head's ``nope`` key part and
+    its value, and whose last ``rope`` columns are a key part all heads
+    share.  Nothing is rotated: the layer has no positional encoding."""
+
+    num_heads: int
+    rank: int
+    nope: int
+    rope: int
+    v_head_dim: int
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        (B, L, _), H = x.shape, self.num_heads
+        with jax.named_scope(scopes.MLA_PROJ):
+            q = nn.Dense(H * (self.nope + self.rope), use_bias=False,
+                         name="q")(x).reshape(B, L, H, self.nope + self.rope)
+            c = nn.Dense(self.rank + self.rope, use_bias=False, name="kv_a")(x)
+            latent = RMSNorm(self.eps, name="kv_norm")(
+                c[..., :self.rank]).astype(x.dtype)
+            kv = nn.Dense(H * (self.nope + self.v_head_dim), use_bias=False,
+                          name="kv_b")(latent).reshape(
+                B, L, H, self.nope + self.v_head_dim)
+            shared = jnp.broadcast_to(c[:, :, None, self.rank:],
+                                      (B, L, H, self.rope))
+            k = jnp.concatenate([kv[..., :self.nope], shared], axis=-1)
+        return q, k, kv[..., self.nope:]
+
+
+def _log_uniform(low: float, high: float, transform: Callable) -> Callable:
+    """An initializer: ``transform`` of ``exp(U[log low, log high])``."""
+    def init(key, shape, dtype=jnp.float32):
+        return transform(jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, math.log(low), math.log(high)))
+        ).astype(dtype)
+
+    return init
+
+
+def _conv_taps(key, shape, dtype=jnp.float32):
+    return jax.random.uniform(key, shape, dtype, -0.5, 0.5)
+
+
+class LinearAttention(nn.Module):
+    """The gated delta rule's layer.  ``x`` [B, L, h], the normed input.
+
+    ``q``, ``k``, ``v`` = SiLU of a short causal depthwise convolution of
+    their projections, ``q`` and ``k`` L2-normed a head (``q`` also divided
+    by ``sqrt(head_dim)``); a log decay a channel ``g = -exp(A_log) *
+    softplus(Wfb (Wfa x) + dt_bias)`` and a ``beta = sigmoid(x Wbeta)`` a
+    head; ``ops.linear_attention.gated_delta_rule``; the output RMS-normed a
+    head, gated by ``sigmoid(Wgb (Wga x))`` and projected back.  Convolution,
+    norms, decay and gates are float32; the scan's state products take ``x``'s
+    dtype.  Returns (the output [B, L, h], the mean of ``g`` as a float32
+    scalar)."""
+
+    num_heads: int
+    head_dim: int
+    conv_kernel: int
+    chunk: int
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        (B, L, h), H, d = x.shape, self.num_heads, self.head_dim
+        f32 = jnp.float32
+
+        def dense(width, name, t=x):
+            return nn.Dense(width, use_bias=False, name=name)(t)
+
+        def mixed(name):
+            taps = self.param(f"{name}_conv", _conv_taps,
+                              (self.conv_kernel, H * d))
+            u = short_causal_conv(dense(H * d, f"{name}_proj").astype(f32),
+                                  taps.astype(f32))
+            return jax.nn.silu(u).reshape(B, L, H, d)
+
+        def l2_normed(t):
+            return t * jax.lax.rsqrt((t * t).sum(axis=-1, keepdims=True)
+                                     + 1e-6)
+
+        with jax.named_scope(scopes.KDA_PROJ):
+            q = l2_normed(mixed("q")) / math.sqrt(d)
+            k = l2_normed(mixed("k"))
+            v = mixed("v").astype(x.dtype)
+        with jax.named_scope(scopes.KDA_GATES):
+            a_log = self.param("A_log", _log_uniform(1, 16, jnp.log), (H,))
+            dt_bias = self.param(
+                "dt_bias", _log_uniform(
+                    1e-3, 1e-1, lambda dt: dt + jnp.log(-jnp.expm1(-dt))),
+                (H * d,))
+            g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+                dense(H * d, "f_b", dense(d, "f_a")).astype(f32)
+                + dt_bias.astype(f32)).reshape(B, L, H, d)
+            beta = jax.nn.sigmoid(dense(H, "b_proj").astype(f32))
+            gate = jax.nn.sigmoid(
+                dense(H * d, "g_b", dense(d, "g_a")).astype(f32))
+        with jax.named_scope(scopes.KDA_SCAN):
+            o = jax.vmap(functools.partial(gated_delta_rule, chunk=self.chunk)
+                         )(q, k, v, g, beta)
+        with jax.named_scope(scopes.KDA_OUT):
+            o = RMSNorm(self.eps, name="o_norm")(o).reshape(B, L, H * d) * gate
+            return dense(h, "o_proj", o.astype(x.dtype)), g.mean()
+
+
 class DecoderBlock(nn.Module):
     cfg: DecoderConfig
     kind: str
     attn_fn: Optional[AttnFn] = None
+    mlp: str = SPARSE
+
+    @nn.nowrap
+    def mixer(self, a):
+        """The layer's sequence mixer over the normed input: (its output, its
+        counters)."""
+        c = self.cfg
+        if self.kind == LINEAR:
+            y, log_decay = LinearAttention(
+                c.linear_heads, c.linear_head_dim, c.conv_kernel, c.chunk,
+                c.rms_norm_eps)(a)
+            return y, {KDA_LOG_DECAY_MEAN: log_decay}
+        attn_fn = self.attn_fn or _default_attn
+        if self.kind == LATENT:
+            return MultiHeadAttention(
+                c.num_heads, attn_fn=_scoped(attn_fn, scopes.ATTN_LATENT),
+                qkv=LatentQKV(c.num_heads, c.kv_lora_rank, c.qk_nope_head_dim,
+                              c.qk_rope_head_dim, c.v_head_dim,
+                              c.rms_norm_eps, parent=None))(a), {}
+        sliding = self.kind == SLIDING
+        rope = dict(c.rope).get(self.kind)
+        return MultiHeadAttention(
+            c.num_heads,
+            attn_fn=_scoped(attn_fn, scopes.ATTN_SLIDING if sliding
+                            else scopes.ATTN_FULL),
+            num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
+            rope_fn=make_rope_fn(dict(rope), c.head_dim) if rope else None,
+            window=c.sliding_window if sliding else None,
+        )(a), {}
 
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        sliding = self.kind == SLIDING
-        attn = MultiHeadAttention(
-            c.num_heads,
-            attn_fn=_scoped(self.attn_fn or _default_attn,
-                            scopes.ATTN_SLIDING if sliding
-                            else scopes.ATTN_FULL),
-            num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
-            rope_fn=make_rope_fn(dict(dict(c.rope)[self.kind]), c.head_dim),
-            window=c.sliding_window if sliding else None,
-        )
-        x = x + attn(RMSNorm(c.rms_norm_eps)(x).astype(x.dtype))
-        y, counters = ExpertLayer(
+        y, counters = self.mixer(RMSNorm(c.rms_norm_eps)(x).astype(x.dtype))
+        x = x + y
+        b = RMSNorm(c.rms_norm_eps)(x)
+        if self.mlp == DENSE:
+            with jax.named_scope(scopes.MLP_DENSE):
+                y = GatedMLP(c.intermediate_size, name="mlp")(b.astype(x.dtype))
+            return x + y, counters
+        y, routed = ExpertLayer(
             c.num_experts_routed, c.experts_held, c.top_k,
-            c.moe_intermediate_size, c.norm_topk_prob,
-        )(RMSNorm(c.rms_norm_eps)(x))
-        return x + y.astype(x.dtype), counters
+            c.moe_intermediate_size, c.norm_topk_prob, c.router_activation,
+            c.routed_scaling_factor,
+        )(b)
+        y = y.astype(x.dtype)
+        if c.shared_expert_size:
+            with jax.named_scope(scopes.MOE_SHARED):
+                y = y + GatedMLP(c.shared_expert_size, name="shared_expert")(
+                    b.astype(x.dtype))
+        return x + y, {**counters, **routed}
 
 
 class DecoderLM(nn.Module):
@@ -528,8 +767,9 @@ class DecoderLM(nn.Module):
         block_cls = nn.remat(DecoderBlock) if c.remat else DecoderBlock
         totals = {}
         for i, kind in enumerate(c.layer_types):
-            h, counters = block_cls(c, kind, self.attn_fn,
-                                    name=f"Block_{i}")(h)
+            h, counters = block_cls(
+                c, kind, self.attn_fn, c.mlp_types[i] if c.mlp_types else SPARSE,
+                name=f"Block_{i}")(h)
             totals = {n: totals.get(n, 0.0) + v for n, v in counters.items()}
         if train and not self.is_initializing():
             for n, v in totals.items():

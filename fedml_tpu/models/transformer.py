@@ -28,7 +28,8 @@ from fedml_tpu.obs import scopes
 from fedml_tpu.parallel.ring_attention import blockwise_attention
 
 # (q, k, v, causal) over [L, H, D] per example; a layer with a window also
-# passes ``window=``, and k, v may hold fewer heads than q
+# passes ``window=``, k, v may hold fewer heads than q, and v's head size
+# may differ from q's and k's
 AttnFn = Callable
 
 
@@ -46,7 +47,8 @@ def _default_attn(q, k, v, causal, window=None):
 
     - on a TPU, for a shape the fused kernels take (``pick_block`` finds
       a block that divides L, ``head_group`` a lane layout for the head
-      size) and inputs in a 16-bit compute dtype: the Pallas flash
+      size, or for q/k's and v's where they differ) and inputs in a
+      16-bit compute dtype: the Pallas flash
       kernels (``ops/flash_attention.py``), forward and backward, which
       keep scores and probabilities in VMEM and save only ``o`` and
       ``lse``.  At the benchmark cells' shape (bf16 [8, 1024, 20, 64])
@@ -69,13 +71,15 @@ def _default_attn(q, k, v, causal, window=None):
     )
 
     L, H, D = q.shape
+    Dv = v.shape[-1]
     block = pick_block(L, D)
     if q.dtype.itemsize == 2:
         fits = block > 0
     else:
         fits = block == 512 and L >= 2048
     # k/v heads shared among q heads: one head a column block
-    groups = head_group(H, D) if k.shape[1] == H else D % 128 == 0
+    groups = head_group(H, D, Dv) if k.shape[1] == H else (
+        D % 128 == 0 and Dv % 128 == 0)
     if fits and groups and jax.default_backend() == "tpu":
         return flash_attention(
             q, k, v, causal=causal, block_q=block, block_k=block,
@@ -90,7 +94,10 @@ class MultiHeadAttention(nn.Module):
     own k/v head of size ``E // num_heads``; ``num_kv_heads`` shares each
     k/v head among ``num_heads // num_kv_heads`` q heads, ``head_dim`` sets
     a head size that is not ``E // num_heads``, ``rope_fn`` rotates q and k
-    ([B, L, H, D] -> the same) and ``window`` is handed to ``attn_fn``."""
+    ([B, L, H, D] -> the same) and ``window`` is handed to ``attn_fn``.
+    ``qkv``, a module ``x -> (q, k, v)`` with parameters of its own, takes
+    the fused projection's place: its v heads may be of another size than its
+    q and k heads, and the output projection reads what comes back."""
 
     num_heads: int
     attn_fn: Optional[AttnFn] = None
@@ -99,6 +106,7 @@ class MultiHeadAttention(nn.Module):
     head_dim: Optional[int] = None
     rope_fn: Optional[Callable] = None
     window: Optional[int] = None
+    qkv: Optional[nn.Module] = None
 
     @nn.compact
     def __call__(self, x):
@@ -106,9 +114,12 @@ class MultiHeadAttention(nn.Module):
         H = self.num_heads
         G = self.num_kv_heads or H
         D = self.head_dim or E // H
-        qkv = nn.Dense((H + 2 * G) * D, use_bias=False)(x)
-        q, k, v = jnp.split(qkv.reshape(B, L, H + 2 * G, D), [H, H + G],
-                            axis=2)
+        if self.qkv is not None:
+            q, k, v = self.qkv(x)
+        else:
+            qkv = nn.Dense((H + 2 * G) * D, use_bias=False)(x)
+            q, k, v = jnp.split(qkv.reshape(B, L, H + 2 * G, D), [H, H + G],
+                                axis=2)
         if self.rope_fn is not None:
             with jax.named_scope(scopes.ROPE):
                 q, k = self.rope_fn(q), self.rope_fn(k)
@@ -116,7 +127,8 @@ class MultiHeadAttention(nn.Module):
         if self.window is not None:
             attn = functools.partial(attn, window=self.window)
         out = jax.vmap(lambda a, b, c: attn(a, b, c, self.causal))(q, k, v)
-        return nn.Dense(E, use_bias=False)(out.reshape(B, L, H * D))
+        return nn.Dense(E, use_bias=False)(
+            out.reshape(B, L, H * out.shape[-1]))
 
 
 class Block(nn.Module):

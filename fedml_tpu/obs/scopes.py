@@ -39,6 +39,15 @@ MOE_ROUTER = "model.moe_router"  # router logits, softmax, top-k, weights
 MOE_DISPATCH = "model.moe_dispatch"  # grouping by expert and the gather of rows
 MOE_EXPERTS = "model.moe_experts"  # the grouped matrix products and the gate
 MOE_COMBINE = "model.moe_combine"  # rows back to tokens, summed over the k
+MOE_SHARED = "model.moe_shared"  # the shared expert, beside the routed sum
+MLP_DENSE = "model.mlp_dense"  # the gated MLP of a layer without experts
+ATTN_LATENT = "model.attn_latent"  # the attention function of a latent-attention layer
+MLA_PROJ = "model.mla_proj"  # q, the latent down- and up-projections, the latent's norm
+KDA_PROJ = "model.kda_proj"  # linear attention: q/k/v projections, convolutions, SiLU, L2 norms
+KDA_GATES = "model.kda_gates"  # decay, beta and output-gate projections, softplus, sigmoids
+KDA_SCAN = "model.kda_scan"  # ops.linear_attention.gated_delta_rule and nothing else
+KDA_OUT = "model.kda_out"  # the gated norm a head and the output projection
 
 MODEL_SCOPES = (ROPE, ATTN_SLIDING, ATTN_FULL, MOE_ROUTER, MOE_DISPATCH,
-                MOE_EXPERTS, MOE_COMBINE)
+                MOE_EXPERTS, MOE_COMBINE, MOE_SHARED, MLP_DENSE, ATTN_LATENT,
+                MLA_PROJ, KDA_PROJ, KDA_GATES, KDA_SCAN, KDA_OUT)

@@ -10,6 +10,13 @@ path (``_default_attn``) and the per-step local attention of
 ``parallel.ring_attention.ring_flash_attention``; the lax ring keeps its
 own blockwise inner loop.
 
+Head sizes: q and k of one size ``D`` and v (so ``o`` and ``dO``) of the
+same or of another, ``Dv``.  On a chip ``D = Dv`` of 128 or a divisor of it
+(``head_group``), or two sizes whose columns of 1, 2 or 4 heads are whole
+128-lane tiles in both views: a latent-attention layer's 192 / 128 runs two
+heads a block, 384 lanes of q and k beside 256 of v, with no padded or
+repeated copy of either in HBM.
+
 Layout: the kernels read the model's own [L, H, D] arrays as [L, H·D]
 (a free reshape: no head transpose in HBM) in column blocks of
 ``group`` heads, 128 lanes wide where the head size divides 128.  A head
@@ -75,11 +82,17 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
                                preferred_element_type=jnp.float32)
 
 
-def head_group(num_heads: int, head_dim: int) -> int:
+def head_group(num_heads: int, head_dim: int, v_head_dim: int = 0) -> int:
     """Heads per column block of the [L, H·D] view, 0 if the kernels do
     not take the shape on a TPU: a block is ``head_dim`` lanes wide when
     that is whole 128-lane tiles, else 128 lanes of ``128 // head_dim``
-    heads."""
+    heads.  Where v's head size differs from q's and k's, the fewest heads
+    (1, 2 or 4) whose columns are whole tiles in both views: two heads of
+    192 and 128 make blocks of 384 and 256 lanes."""
+    if v_head_dim and v_head_dim != head_dim:
+        return next((g for g in (1, 2, 4) if num_heads % g == 0
+                     and g * head_dim % LANES == 0
+                     and g * v_head_dim % LANES == 0), 0)
     if head_dim % LANES == 0:
         return 1
     group = LANES // head_dim
@@ -94,6 +107,16 @@ def _head_lanes(shape, a: int, head_dim: int, group: int):
         return None
     lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     return (lane >= a * head_dim) & (lane < (a + 1) * head_dim)
+
+
+def _lanes_of(qk_shape, v_shape, a: int, head_dim: int, v_head_dim: int,
+              group: int):
+    """(``_head_lanes`` of head ``a`` in a block of q or k, the same in a
+    block of v, o or dO): one mask where the two views are alike."""
+    qk = _head_lanes(qk_shape, a, head_dim, group)
+    if tuple(v_shape) == tuple(qk_shape) and v_head_dim == head_dim:
+        return qk, qk
+    return qk, _head_lanes(v_shape, a, v_head_dim, group)
 
 
 def _only(x, lanes):
@@ -176,15 +199,16 @@ def _q_range(ki, block_q, block_k, nq, causal, window=None):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
                 causal: bool, window, block_k: int, head_dim: int,
-                group: int):
-    block_q, width = q_ref.shape
+                v_head_dim: int, group: int):
+    block_q, width = o_ref.shape
     qi, nk = pl.program_id(1), k_ref.shape[0] // block_k
     bounds = _kv_range(qi, block_q, block_k, nk, causal, window)
     q = q_ref[...]
-    out = jnp.zeros(q.shape, jnp.float32)
+    out = jnp.zeros(o_ref.shape, jnp.float32)
     for a in range(group):
-        lanes = _head_lanes(q.shape, a, head_dim, group)
-        qa = _only(q, lanes)
+        q_lanes, lanes = _lanes_of(q.shape, o_ref.shape, a, head_dim,
+                                   v_head_dim, group)
+        qa = _only(q, q_lanes)
 
         def body(j, carry, masked):
             m_prev, l_prev, acc = carry
@@ -216,8 +240,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, sm_scale: float,
                 causal: bool, window, block_q: int, head_dim: int,
-                group: int):
-    block_k, width = k_ref.shape
+                v_head_dim: int, group: int):
+    block_k = k_ref.shape[0]
     ki, nq = pl.program_id(1), q_ref.shape[0] // block_q
     bounds = _q_range(ki, block_q, block_k, nq, causal, window)
 
@@ -230,8 +254,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     k, v = k_ref[...], v_ref[...]
     heads = []
     for a in range(group):
-        lanes = _head_lanes(k.shape, a, head_dim, group)
-        heads.append((_only(k, lanes), _only(v, lanes)))
+        k_lanes, v_lanes = _lanes_of(k.shape, v.shape, a, head_dim,
+                                     v_head_dim, group)
+        heads.append((_only(k, k_lanes), _only(v, v_lanes)))
 
     def body(i, carry, masked):
         rows = pl.ds(i * block_q, block_q)
@@ -242,15 +267,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         # every right-hand operand is zero off head a's lanes, so the
         # heads of a block add up in one accumulator a gradient
         for a, (ka, va) in enumerate(heads):
-            lanes = _head_lanes(q.shape, a, head_dim, group)
+            q_lanes, do_lanes = _lanes_of(q.shape, do.shape, a, head_dim,
+                                          v_head_dim, group)
             st = _dot(ka, q, _NT) * sm_scale                 # [BK, BQ]
             if masked:
                 st = jnp.where(keep, st, NEG_INF)
             pt = jnp.exp(st - lse_ref[a, pl.ds(i, 1), :])
-            dv_acc[...] += _dot(pt.astype(do.dtype), _only(do, lanes))
+            dv_acc[...] += _dot(pt.astype(do.dtype), _only(do, do_lanes))
             dst = (pt * (_dot(va, do, _NT) - delta_ref[a, pl.ds(i, 1), :])
                    ).astype(q.dtype)
-            dk_acc[...] += _dot(dst, _only(q, lanes))
+            dk_acc[...] += _dot(dst, _only(q, q_lanes))
             dq_acc[rows, :] += _dot(dst, ka, _TN)            # [BQ, W]
         return carry  # nothing: every sum lives in a scratch ref
 
@@ -269,10 +295,10 @@ class _Plan:
     score block is a grid axis, the other is whole in VMEM and walked by
     the kernel's own loop."""
 
-    def __init__(self, q, k, block_q, block_k, causal, interpret,
+    def __init__(self, q, k, v, block_q, block_k, causal, interpret,
                  window=None):
         self.Lq, self.H, self.D = q.shape
-        self.Lk = k.shape[0]
+        self.Lk, self.Dv = k.shape[0], v.shape[-1]
         if window is not None and not causal:
             raise ValueError("a window is defined under the causal mask only")
         self.bq, self.bk = min(block_q, self.Lq), min(block_k, self.Lk)
@@ -283,8 +309,9 @@ class _Plan:
             )
         # a shape head_group refuses runs as one block of every head:
         # right for the interpreter, not sent to a chip by the policy
-        self.group = head_group(self.H, self.D) or self.H
-        self.W = self.group * self.D
+        self.group = head_group(self.H, self.D, self.Dv) or self.H
+        # columns of a block of q and k, and of v, o and dO
+        self.W, self.Wv = self.group * self.D, self.group * self.Dv
         self.nh = self.H // self.group
         # q heads to a k/v head: a q head block reads the column block of
         # its k/v head, so no repeated k or v is made in HBM
@@ -297,20 +324,25 @@ class _Plan:
         self.nq, self.nk = self.Lq // self.bq, self.Lk // self.bk
         self.interpret = interpret
         self.consts = dict(sm_scale=1.0 / (self.D ** 0.5), causal=causal,
-                           window=window, head_dim=self.D, group=self.group)
+                           window=window, head_dim=self.D,
+                           v_head_dim=self.Dv, group=self.group)
 
     def column(self, kv):
         """q head block -> column block: its own, or its k/v head's."""
         rep = self.rep
         return (lambda h: h // rep) if kv and rep > 1 else (lambda h: h)
 
-    def block(self, rows, kv=False):
+    def block(self, rows, kv=False, v=False):
+        """``rows`` of the grid step's row block in the head block's columns:
+        of q or k, or (``v``) of v, o or dO."""
         col = self.column(kv)
-        return pl.BlockSpec((rows, self.W), lambda h, i: (i, col(h)))
+        return pl.BlockSpec((rows, self.Wv if v else self.W),
+                            lambda h, i: (i, col(h)))
 
-    def whole(self, rows, kv=False, **kw):
+    def whole(self, rows, kv=False, v=False, **kw):
         col = self.column(kv)
-        return pl.BlockSpec((rows, self.W), lambda h, i: (0, col(h)), **kw)
+        return pl.BlockSpec((rows, self.Wv if v else self.W),
+                            lambda h, i: (0, col(h)), **kw)
 
     def call(self, name, kernel, grid, in_specs, out_specs, out_shape,
              sequential=False, scratch_shapes=(), **consts):
@@ -332,24 +364,24 @@ class _Plan:
         )
 
     def flat(self, t):
-        return t.reshape(t.shape[0], t.shape[1] * self.D)
+        return t.reshape(t.shape[0], t.shape[1] * t.shape[2])
 
 
 def _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret,
                       window=None):
-    """(o [L, H, D], lse [H, L]) of [L, H, D] inputs."""
-    pn = _Plan(q, k, block_q, block_k, causal, interpret, window)
+    """(o [L, H, Dv], lse [H, L]) of q, k [L, H, D] and v [L, H, Dv]."""
+    pn = _Plan(q, k, v, block_q, block_k, causal, interpret, window)
     out, lse = pn.call(
         "flash_fwd", _fwd_kernel, (pn.nh, pn.nq),
         [pn.block(pn.bq), pn.whole(pn.Lk, kv=True),
-         pn.whole(pn.Lk, kv=True)],
-        [pn.block(pn.bq),
+         pn.whole(pn.Lk, kv=True, v=True)],
+        [pn.block(pn.bq, v=True),
          pl.BlockSpec((None, pn.group, pn.bq), lambda h, i: (h, 0, i))],
-        [jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.D), q.dtype),
+        [jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.Dv), q.dtype),
          jax.ShapeDtypeStruct((pn.nh, pn.group, pn.Lq), jnp.float32)],
         block_k=pn.bk,
     )(pn.flat(q), pn.flat(k), pn.flat(v))
-    return out.reshape(q.shape), lse.reshape(pn.H, pn.Lq)
+    return out.reshape(pn.Lq, pn.H, pn.Dv), lse.reshape(pn.H, pn.Lq)
 
 
 def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
@@ -368,12 +400,13 @@ def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
     ``dk`` and ``dv`` of a k/v head that several q heads share leave the
     kernel a q head and are summed over the group here.
     """
-    pn = _Plan(q, k, block_q, block_k, causal, interpret, window)
+    pn = _Plan(q, k, v, block_q, block_k, causal, interpret, window)
     delta = (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(-1).T \
         - dlse.astype(jnp.float32)                           # [H, Lq]
     ops = (pn.flat(q), pn.flat(k), pn.flat(v), pn.flat(do))
     flat_q = jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.D), q.dtype)
     flat_k = jax.ShapeDtypeStruct((pn.Lk, pn.H * pn.D), k.dtype)  # a q head
+    flat_v = jax.ShapeDtypeStruct((pn.Lk, pn.H * pn.Dv), v.dtype)
 
     # [H, L] statistics by head block, a q block's row on a leading dim
     # for the kernel's loop to pick
@@ -384,22 +417,23 @@ def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
     # no room under VMEM_LIMIT, q and dO get one buffer: their block
     # changes only with the head block, and a long sequence hides the
     # wait (at the cells' L = 1024 it cost 0.17 ms in 1.22: PERF.md §6)
-    resident = pn.Lq * pn.W * (6 * q.dtype.itemsize + 4)
+    resident = pn.Lq * (pn.W * (4 * q.dtype.itemsize + 4)
+                        + pn.Wv * 2 * q.dtype.itemsize)
     whole = pn.whole if resident <= VMEM_LIMIT * 3 // 4 else \
         functools.partial(pn.whole, pipeline_mode=pl.Buffered(1))
     dq, dk, dv = pn.call(
         "flash_bwd", _bwd_kernel, (pn.nh, pn.nk),
-        [whole(pn.Lq), pn.block(pn.bk, kv=True), pn.block(pn.bk, kv=True),
-         whole(pn.Lq), rows, rows],
-        [pn.whole(pn.Lq), pn.block(pn.bk), pn.block(pn.bk)],
-        [flat_q, flat_k, flat_k], sequential=True,
-        scratch_shapes=[pltpu.VMEM((n, pn.W), jnp.float32)
-                        for n in (pn.Lq, pn.bk, pn.bk)],
+        [whole(pn.Lq), pn.block(pn.bk, kv=True),
+         pn.block(pn.bk, kv=True, v=True), whole(pn.Lq, v=True), rows, rows],
+        [pn.whole(pn.Lq), pn.block(pn.bk), pn.block(pn.bk, v=True)],
+        [flat_q, flat_k, flat_v], sequential=True,
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in (
+            (pn.Lq, pn.W), (pn.bk, pn.W), (pn.bk, pn.Wv))],
         block_q=pn.bq,
     )(*ops, *(t.reshape(pn.nh, pn.group, pn.nq, pn.bq)
               for t in (lse, delta)))
     if pn.rep > 1:
-        dk, dv = (t.reshape(pn.Lk, pn.H // pn.rep, pn.rep, pn.D).astype(
+        dk, dv = (t.reshape(pn.Lk, pn.H // pn.rep, pn.rep, -1).astype(
             jnp.float32).sum(axis=2).astype(k.dtype) for t in (dk, dv))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
@@ -440,6 +474,9 @@ def flash_attention(
     window: int | None = None,
 ) -> jax.Array:
     """Flash attention over [L, H, D] (no batch; vmap for batches).
+
+    ``v`` may have a head size of its own, [L, H, Dv]: ``o`` then has it too
+    (scores are scaled by ``1 / sqrt(D)`` of q and k).
 
     ``window`` (causal only): a query sees the ``window`` latest keys,
     itself included; block pairs wholly behind it are skipped like those

@@ -109,7 +109,8 @@ def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset,
     zero = jnp.zeros_like(q[:, :, 0])       # [Lq, H]
     m0 = zero + jnp.asarray(NEG_INF, q.dtype)
     l0 = zero
-    o0 = jnp.zeros_like(q)
+    o0 = jnp.zeros_like(q) if v.shape[-1] == D else jnp.broadcast_to(
+        zero[..., None], (Lq, H, v.shape[-1]))  # v heads of another size
     (m, l, o), _ = lax.scan(body, (m0, l0, o0), jnp.arange(n_blocks))
     return m, l, o
 
@@ -135,7 +136,8 @@ def blockwise_attention(
     query sees only the ``window`` latest keys, itself included.  ``k`` and
     ``v`` may hold fewer heads than ``q``: k/v head ``g`` serves q heads
     ``g * rep .. (g + 1) * rep - 1``, which are folded into rows so that no
-    repeated ``k`` or ``v`` is made.
+    repeated ``k`` or ``v`` is made.  ``v``'s head size may differ from
+    ``q``'s and ``k``'s: the output has ``v``'s.
     """
     Lq, H, D = q.shape
     rep = H // k.shape[1]
@@ -149,8 +151,8 @@ def blockwise_attention(
         q_offset=q_offset, kv_offset=kv_offset, window=window, q_copies=rep,
     ))
     if rep > 1:
-        out = out.reshape(rep, Lq, H // rep, D).transpose(1, 2, 0, 3).reshape(
-            Lq, H, D)
+        out = out.reshape(rep, Lq, H // rep, -1).transpose(1, 2, 0, 3).reshape(
+            Lq, H, -1)
     return out
 
 

@@ -201,3 +201,70 @@ def test_flash_trains_through_local_update():
                      jax.tree_util.tree_leaves(vb)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-3, atol=2e-4)
+
+
+# (L, H, q/k head size, v head size, block): the latent-attention layer's
+# 192 / 128 (two heads a column block: 384 lanes of q and k, 256 of v), and
+# a toy pair the policy sends to no chip (one block of every head)
+UNEQUAL = [
+    pytest.param((256, 4, 192, 128, 128), id="d192_v128_two_heads_a_block"),
+    pytest.param((64, 2, 12, 8, 32), id="d12_v8_every_head_one_block"),
+]
+
+
+@pytest.mark.parametrize("shape", UNEQUAL)
+def test_flash_takes_a_v_head_size_of_its_own(shape):
+    """Forward and the three gradients against explicit scores and softmax,
+    q and k of one head size and v of another."""
+    L, H, D, Dv, block = shape
+    rng = np.random.RandomState(5)
+    q, k = (jnp.asarray(rng.randn(L, H, D).astype(np.float32)) for _ in "qk")
+    v, w = (jnp.asarray(rng.randn(L, H, Dv).astype(np.float32)) for _ in "vw")
+
+    def explicit(q, k, v):
+        s = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(D)
+        s = jnp.where(jnp.tril(jnp.ones((L, L), bool))[None], s, -jnp.inf)
+        return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block,
+                               block_k=block, interpret=True)
+
+    assert flash(q, k, v).shape == (L, H, Dv)
+    np.testing.assert_allclose(flash(q, k, v), explicit(q, k, v),
+                               rtol=2e-5, atol=2e-5)
+    ours, theirs = (jax.grad(lambda *a: (f(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v) for f in (flash, explicit))
+    for name, a, b in zip("qkv", ours, theirs):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def test_policy_sends_unequal_head_sizes_to_the_kernels(monkeypatch):
+    """From what the call can see: 4 heads of 192 / 128 lie two a column
+    block; 3 heads do not (no whole number of blocks) and take the lax scan."""
+    from jax.experimental import pallas as pl
+
+    from fedml_tpu.models.transformer import _default_attn
+    from fedml_tpu.ops.flash_attention import head_group
+
+    assert head_group(4, 192, 128) == 2 and head_group(32, 192, 128) == 2
+    assert head_group(3, 192, 128) == 0 and head_group(4, 128, 256) == 1
+    assert head_group(4, 128, 128) == head_group(4, 128) == 1
+    traced = []
+    real = pl.pallas_call
+    monkeypatch.setattr(pl, "pallas_call", lambda kernel, *a, **kw: (
+        traced.append(kernel.func.__name__), real(kernel, *a, **kw))[1])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def kernels(H):
+        del traced[:]
+        spec = lambda d: jax.ShapeDtypeStruct(  # noqa: E731
+            (1024, H, d), jnp.bfloat16)
+        out = jax.eval_shape(lambda q, k, v: _default_attn(q, k, v, True),
+                             spec(192), spec(192), spec(128))
+        assert out.shape == (1024, H, 128)
+        return list(traced)
+
+    assert kernels(4) == ["_fwd_kernel"]
+    assert kernels(3) == []

@@ -112,6 +112,34 @@ def test_window_and_shared_kv_heads_compile_for_v5e(one_chip, heads,
     assert "flash_fwd" in text and "flash_bwd" in text
 
 
+def test_unequal_head_sizes_compile_for_v5e(one_chip):
+    """The latent-attention layer of the ``kimilin_silo_doc8k`` cell under
+    the model's vmap: 4 heads, q and k 192 wide, v 128 wide, bf16 at 8192
+    tokens: two heads a column block, 384 lanes of q and k, 256 of v."""
+    from fedml_tpu.ops.flash_attention import head_group
+
+    L, H = 8192, 4
+    assert head_group(H, 192, 128) == 2 and pick_block(L, 192) == 512
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=512, block_k=512)
+
+    def grads(q, k, v, do):
+        return jax.grad(lambda q, k, v: (
+            jax.vmap(attn)(q, k, v).astype(jnp.float32)
+            * do.astype(jnp.float32)).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    spec = lambda d: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, L, H, d), jnp.bfloat16, sharding=one_chip)
+    args = (spec(192), spec(192), spec(128), spec(128))
+    text = jax.jit(grads).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
+    dq, dk, dv = jax.eval_shape(grads, *args)
+    assert (dq.shape, dk.shape, dv.shape) == (
+        (1, L, H, 192), (1, L, H, 192), (1, L, H, 128))
+
+
 def test_the_expert_layers_grouped_products_compile_for_v5e(one_chip):
     """``megablox.gmm`` at the decoder cell's expert shapes and the tiling
     ``gmm_tiling`` picks, forward and both gradients, with group sizes

@@ -41,7 +41,9 @@ a buffer of twice what a level router sends it when the count of a call
 allows, and runs every held expert over every token, with no buffer, when it
 does not; where that buffer would be no shorter than the worst case,
 ``tokens x min(top_k, experts held)``, the layer is one path through the
-worst case (``buffer_capacities``).
+worst case (``buffer_capacities``).  Rows move between tokens and buffer
+through ``ops/expert_rows.py``'s pair, which moves the rows held and builds
+no array of ``tokens x top_k`` rows where its kernel runs.
 """
 
 from __future__ import annotations
@@ -61,6 +63,7 @@ from fedml_tpu.models.transformer import (
     AttnFn, MultiHeadAttention, _default_attn,
 )
 from fedml_tpu.obs import scopes
+from fedml_tpu.ops.expert_rows import from_buffer, sorted_route, to_buffer
 from fedml_tpu.ops.linear_attention import gated_delta_rule, short_causal_conv
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -257,31 +260,6 @@ class RMSNorm(nn.Module):
         return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
 
 
-@jax.custom_vjp
-def _take(src, idx, readers, readers_valid):
-    """``src[idx]``, for an ``idx`` whose inverse is known: row ``n`` of
-    ``src`` is read by the rows ``readers[n, :]`` of the result (where
-    ``readers_valid``).  The backward is then a gather too, not a
-    scatter-add."""
-    return src[idx]
-
-
-def _take_fwd(src, idx, readers, readers_valid):
-    return src[idx], (readers, readers_valid)
-
-
-def _take_bwd(res, g):
-    readers, valid = res
-    rows = g[readers]  # [N, c, ...]
-    valid = valid.reshape(valid.shape + (1,) * (rows.ndim - valid.ndim))
-    d_src = jnp.where(valid, rows, jnp.zeros_like(rows)).astype(
-        jnp.float32).sum(axis=1).astype(g.dtype)
-    return d_src, None, None, None
-
-
-_take.defvjp(_take_fwd, _take_bwd)
-
-
 def gmm_tiling(m: int, k: int, n: int):
     """(rows, contraction, columns) tile of the grouped-product kernel for
     ``[m, k] @ [groups, k, n]``, or None where it does not take the shape:
@@ -352,29 +330,36 @@ _kept_for.defvjp(lambda computed, kept: (kept, None),
 
 def _expert_rows(capacity, kept, x, top_p, w_gate, w_up, w_down, local, order,
                  rank, group_sizes):
-    """(the held experts' part of the layer's output [T, h] float32, the
-    buffers a backward reads again: the rows, their weights, their two grouped
-    products and the gated product) through a row buffer of ``capacity`` rows
-    (static; at least ``group_sizes.sum()``).
+    """(the held experts' part of the layer's output [T, h] in the experts'
+    dtype, the buffers a backward reads again: the rows, their weights, their
+    two grouped products and the gated product) through a row buffer of
+    ``capacity`` rows (static; at least ``group_sizes.sum()``).
 
     ``x`` [T, h], ``top_p`` [T, k]; ``local`` [T, k] is the local index of
     each assignment's expert (the count of experts held: not here), ``order``
     [T x k] sorts the assignments by it and ``rank`` [T, k] is the inverse.
     ``kept``: those buffers from an earlier pass, to be read and not computed
-    again, or None."""
-    (T, k), h = top_p.shape, x.shape[-1]
-    A, here = T * k, local < w_gate.shape[0]
+    again, or None.
+
+    Rows cross between tokens and buffer four times, and each crossing moves
+    the rows held and nothing else (``ops/expert_rows.py``): ``to_buffer``
+    for the experts' input and, as ``from_buffer``'s backward, for the
+    output's cotangent; ``from_buffer`` for the experts' output and, as
+    ``to_buffer``'s backward, for the input's cotangent.  None passes through
+    an array of ``T x k`` rows where the kernel runs.  The routing weights
+    cross as ``C`` scalars out of ``T x k``, and come back as a scatter of
+    ``C`` into ``T x k``."""
     keep = iter(kept or ())
 
     def buffer(computed):
         return _kept_for(computed, next(keep)) if kept else computed
 
     with jax.named_scope(scopes.MOE_DISPATCH):
-        order, rank = order[:capacity], jnp.minimum(rank, capacity - 1)
-        row_live = (jnp.arange(capacity) < group_sizes.sum())[:, None]
-        rows = buffer(_take(x.astype(w_gate.dtype), order // k, rank, here))
-        row_w = buffer(_take(top_p.reshape(A), order, rank.reshape(A, 1),
-                             here.reshape(A, 1)))
+        route = sorted_route(local, order, rank, group_sizes, capacity)
+        rows = buffer(to_buffer(x.astype(w_gate.dtype), route))
+        row_w = buffer(jnp.where(
+            route.row_live, top_p.reshape(-1).at[order[:capacity]].get(
+                unique_indices=True), 0))
 
     with jax.named_scope(scopes.MOE_EXPERTS):
         gate = buffer(_grouped_dot(rows, w_gate, group_sizes))
@@ -385,9 +370,7 @@ def _expert_rows(capacity, kept, x, top_p, w_gate, w_up, w_down, local, order,
         out = _grouped_dot(act, w_down, group_sizes)
 
     with jax.named_scope(scopes.MOE_COMBINE):
-        back = _take(out, rank.reshape(A), order[:, None], row_live)
-        y = jnp.where(here[..., None], back.reshape(T, k, h), 0).astype(
-            jnp.float32).sum(axis=1)
+        y = from_buffer(out, route)
     return y, (rows, row_w, gate, up, act)
 
 
@@ -413,7 +396,7 @@ def _every_expert(x, top_p, w_gate, w_up, w_down, local):
         y, _ = jax.lax.scan(
             jax.checkpoint(one), jnp.zeros(x.shape, jnp.float32),
             (jnp.arange(w_gate.shape[0]), w_gate, w_up, w_down))
-    return y
+    return y.astype(w_down.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -480,6 +463,14 @@ class ExpertLayer(nn.Module):
     short buffer's rows, or ``tokens x experts held``.  Where the short
     buffer is no shorter (every expert held) there is no branch and the
     buffer is the worst case.
+
+    Two transfers carry rows between the tokens [T, h] and the buffer [C, h],
+    each the other's backward (``ops/expert_rows.py``): ``to_buffer``, a
+    gather of C rows out of T, and ``from_buffer``, a token's held rows added
+    in float32: on a TPU one kernel pass over token tiles that copies the
+    held rows' windows and nothing else, elsewhere a gather over the slots.
+    Forward and backward use each twice, and none passes through ``T x k``
+    rows where the kernel runs.
 
     Not under a ``vmap`` over clients (``client_axis_impl="vmap"``):
     ``lax.ragged_dot`` refuses stacked expert weights ("ragged_dot vmap ...

@@ -341,6 +341,138 @@ def test_the_fallback_hands_the_backward_no_buffer_of_its_own():
     assert "stablehlo.case" in text or "stablehlo.if" in text
 
 
+# -- the row transfers between tokens and buffer ------------------------------------
+
+from fedml_tpu.ops import expert_rows  # noqa: E402
+
+
+def route_of(key, tokens, k, routed, held, capacity, skew=0.0):
+    """The ``Route`` the expert layer builds for a router with random scores
+    (``skew`` added to expert ``held[0]``'s), through ``capacity`` rows."""
+    scores = jax.random.normal(key, (tokens, routed)).at[:, held[0]].add(skew)
+    _, top_e = jax.lax.top_k(scores, k)
+    local_of = np.full((routed,), len(held), np.int32)
+    local_of[list(held)] = np.arange(len(held))
+    local = jnp.asarray(local_of)[top_e]
+    order = jnp.argsort(local.reshape(-1), stable=True)
+    rank = jnp.argsort(order).reshape(tokens, k)
+    sizes = (local.reshape(-1, 1) == jnp.arange(len(held))).sum(
+        axis=0, dtype=jnp.int32)
+    assert int(sizes.sum()) <= capacity
+    return expert_rows.sorted_route(local, order, rank, sizes, capacity)
+
+
+# (tokens, k, experts routed, held, buffer rows): a share through a short
+# buffer with dead rows past the routed count; tokens with none, one and all k
+# of their slots here; every expert held, C = T x k and no dead row
+PAIRS = [
+    pytest.param(64, 4, 16, (3, 9), 128, id="share_with_dead_rows"),
+    pytest.param(96, 2, 5, (0, 2, 4), 192, id="none_one_and_k_slots_here"),
+    pytest.param(48, 4, 8, tuple(range(8)), 192, id="every_expert_held"),
+]
+
+
+@pytest.mark.parametrize("tokens, k, routed, held, capacity", PAIRS)
+def test_the_two_row_transfers_are_each_others_transpose(tokens, k, routed,
+                                                         held, capacity):
+    """``to_buffer`` and ``from_buffer`` against the [C, T] matrix of ones
+    they stand for, and each one's vjp against the other and against what JAX
+    derives from the plain gather."""
+    route = route_of(jax.random.PRNGKey(20), tokens, k, routed, held, capacity)
+    here = np.asarray(route.here).sum(axis=1)
+    live = int(route.group_sizes.sum())
+    if len(held) == routed:
+        assert live == capacity == tokens * k and (here == k).all()
+    else:
+        assert live < capacity and {0, 1}.issubset(here)
+    if capacity == 192 and routed == 5:
+        assert k in here
+    ones = (np.asarray(route.row_live)[:, None]
+            & (np.asarray(route.tok)[:, None] == np.arange(tokens)))
+    ones = jnp.asarray(ones, jnp.float32)  # [C, T]
+    x = jax.random.normal(jax.random.PRNGKey(21), (tokens, 32))
+    buf = jax.random.normal(jax.random.PRNGKey(22), (capacity, 32))
+    # rows past the routed count may hold anything: nothing reads them
+    poisoned = jnp.where(route.row_live[:, None], buf, jnp.nan)
+    hi = jax.lax.Precision.HIGHEST
+    assert rel(expert_rows.to_buffer(x, route),
+               jnp.dot(ones, x, precision=hi)) < 1e-6
+    assert rel(expert_rows.from_buffer(poisoned, route),
+               jnp.dot(ones.T, buf, precision=hi)) < 1e-6
+    (d_x,) = jax.vjp(lambda x: expert_rows.to_buffer(x, route), x)[1](poisoned)
+    (d_buf,) = jax.vjp(lambda b: expert_rows.from_buffer(b, route), buf)[1](x)
+    assert rel(d_x, expert_rows.from_buffer(poisoned, route)) == 0
+    assert rel(d_buf, expert_rows.to_buffer(x, route)) == 0
+    (by_jax,) = jax.vjp(lambda x: jnp.where(
+        route.row_live[:, None], x[route.tok], 0), x)[1](buf)
+    assert rel(d_x, by_jax) < 1e-6
+    (by_jax,) = jax.vjp(lambda b: jnp.where(
+        route.here[..., None], b[route.rank], 0).sum(axis=1), buf)[1](x)
+    assert rel(d_buf, by_jax) < 1e-6
+
+
+# (tokens, k, experts routed, experts held, buffer rows, h, dtype, skew): the
+# two decoder cells' buffers at their tokens and top-k (8 of 64 and of 256
+# held, narrow rows); float32 rows; a router that sends every token to one
+# held expert, whose range of a tile is four windows long
+KERNEL_SHAPES = [
+    pytest.param(8192, 8, 64, 8, 16384, 128, jnp.bfloat16, 0.0,
+                 id="mellum2_silo_code8k_buffer"),
+    pytest.param(8192, 8, 256, 8, 4096, 128, jnp.bfloat16, 0.0,
+                 id="kimilin_silo_doc8k_buffer"),
+    pytest.param(512, 4, 16, 4, 1024, 256, jnp.float32, 0.0, id="float32"),
+    pytest.param(512, 2, 8, 2, 1024, 128, jnp.float32, 9.0,
+                 id="a_range_of_four_windows"),
+]
+
+
+@pytest.mark.parametrize(
+    "tokens, k, routed, held, capacity, h, dtype, skew", KERNEL_SHAPES)
+def test_the_from_buffer_kernel_matches_the_lax_form(tokens, k, routed, held,
+                                                     capacity, h, dtype, skew):
+    """The Pallas kernel in interpret mode against the gather, select and
+    sum, with NaN in the buffer's rows past the routed count."""
+    route = route_of(jax.random.PRNGKey(23), tokens, k, routed,
+                     tuple(range(held)), capacity, skew)
+    if skew:
+        assert int(route.group_sizes[0]) == tokens
+    buf = jnp.where(route.row_live[:, None], jax.random.normal(
+        jax.random.PRNGKey(24), (capacity, h)), jnp.nan).astype(dtype)
+    # the window the layer would pick; 64 rows where a range must outgrow it
+    window = 64 if skew else expert_rows.window_rows(
+        tokens, capacity, h, held, buf.dtype.itemsize)
+    got = expert_rows.from_buffer_windows(buf, route, window, interpret=True)
+    want = expert_rows.from_buffer_lax(buf, route)
+    assert got.dtype == want.dtype == dtype
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+    # the same rows, added in float32 by expert where the lax form adds by slot
+    assert rel(got.astype(jnp.float32), want.astype(jnp.float32)) < (
+        1e-6 if dtype == jnp.float32 else 4e-3)
+
+
+@pytest.mark.parametrize("tokens, capacity, h, held, itemsize, window", [
+    (8192, 16384, 2304, 8, 2, 64),  # mellum2_silo_code8k
+    (8192, 4096, 2304, 8, 2, 64),  # kimilin_silo_doc8k
+    (8192, 65536, 2304, 8, 2, None),  # a share's worst case: windows past VMEM
+    (8200, 16384, 2304, 8, 2, None),  # tokens that are no whole tiles
+    (8192, 16384, 2300, 8, 2, None),  # rows that are no whole lanes
+    (256, 32, 128, 2, 4, None),  # a buffer shorter than a window
+    (8192, 65536, 2304, 64, 2, None),  # every expert held: the same
+], ids=["mellum", "kimi", "worst_case", "ragged_tokens", "ragged_lanes",
+        "short_buffer", "every_expert"])
+def test_the_kernel_takes_the_shapes_it_tiles(tokens, capacity, h, held,
+                                              itemsize, window):
+    assert expert_rows.window_rows(tokens, capacity, h, held,
+                                   itemsize) == window
+
+
+def test_the_kernel_refuses_tokens_that_are_no_whole_tiles_by_name():
+    route = route_of(jax.random.PRNGKey(25), 300, 2, 8, (0, 1), 256)
+    with pytest.raises(ValueError, match="300 tokens in tiles of 256"):
+        expert_rows.from_buffer_windows(jnp.zeros((256, 128)), route, 64,
+                                        interpret=True)
+
+
 # -- through the round path ---------------------------------------------------------
 
 def round_of(cfg, client_axis_impl="map"):

@@ -168,26 +168,40 @@ def test_the_expert_layers_grouped_products_compile_for_v5e(one_chip):
         assert text.count("tpu_custom_call") >= 2
 
 
+# (experts routed, rows of the short buffer, the scratch the parent of PR 36
+# compiled to at that shape in GiB): the two decoder cells' expert layers
+EXPERT_LAYERS = [
+    pytest.param(64, 16384, 0.515, id="mellum2_silo_code8k"),
+    pytest.param(256, 4096, 0.408, id="kimilin_silo_doc8k"),
+]
+
+
+@pytest.mark.parametrize("routed, short, parent_gib", EXPERT_LAYERS)
 def test_the_expert_layer_with_its_short_buffer_compiles_for_v5e(
-        one_chip, monkeypatch):
-    """One ``ExpertLayer`` at the decoder cell's shapes (8192 tokens, 8 of 64
-    experts held, top 8), forward and backward: the branch on the count of
-    routed rows is two conditionals, the nine grouped-product kernels stand
-    in the 16,384-row branch only (the other runs plain matmuls), and the
-    program's scratch (0.65 GiB) stays well under what the 65,536-row buffer
-    took (1.21 GiB, compiled the same way at PR 32's commit)."""
+        one_chip, monkeypatch, routed, short, parent_gib):
+    """One ``ExpertLayer`` at a decoder cell's shapes (8192 tokens, top 8, 8
+    experts held of ``routed``), forward and backward: the branch on the
+    count of routed rows is two conditionals; the nine grouped-product kernels
+    stand in the short buffer's branch only (the other runs plain matmuls),
+    beside two calls of the ``from_buffer`` kernel (combine forward, dispatch
+    backward) and not a third in the backward branch, where combine's forward
+    is dead; no array of the 65,536 (token, slot) pairs by the hidden size
+    exists anywhere; and the program's scratch stays under the parent's."""
+    import re
+
     from fedml_tpu.models.decoder import ExpertLayer, buffer_capacities
 
-    T, h, f, held = 8192, 2304, 896, 8
-    assert buffer_capacities(T, 8, held, 64) == (16384, 65536)
-    # the layer asks the backend for its grouped product; the compile is for
-    # the described chip whatever this process runs on
+    T, h, f, held, k = 8192, 2304, 896, 8, 8
+    assert buffer_capacities(T, k, held, routed) == (short, T * k)
+    # the layer asks the backend for its kernels; the compile is for the
+    # described chip whatever this process runs on
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    layer = ExpertLayer(64, tuple(range(held)), 8, f)
+    layer = ExpertLayer(routed, tuple(range(held)), k, f)
     spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=one_chip)
-    params = {"router": spec((h, 64), jnp.float32), "gate": spec((held, h, f)),
-              "up": spec((held, h, f)), "down": spec((held, f, h))}
+    params = {"router": spec((h, routed), jnp.float32),
+              "gate": spec((held, h, f)), "up": spec((held, h, f)),
+              "down": spec((held, f, h))}
 
     def loss(p, x, dy):
         y, counters = layer.apply({"params": p}, x)
@@ -199,8 +213,12 @@ def test_the_expert_layer_with_its_short_buffer_compiles_for_v5e(
         ).compile()
     text = compiled.as_text()
     assert text.count(" conditional(") == 2
-    assert text.count('custom_call_target="tpu_custom_call"') == 9
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.8 * 2**30
+    assert text.count('custom_call_target="tpu_custom_call"') == 9 + 2
+    assert sum("tpu_custom_call" in line and "from_buffer" in line
+               for line in text.splitlines()) == 2
+    pairs = re.findall(rf"\w+\[(?:{T * k},{h}|{T},{k},{h})\]", text)
+    assert not pairs, sorted(set(pairs))
+    assert compiled.memory_analysis().temp_size_in_bytes < parent_gib * 2**30
 
 
 def test_folded_round_peak_is_below_the_stacked_rounds_by_two_models(one_chip):

@@ -95,7 +95,7 @@ class MetricsLogger:
 
     def log_telemetry(self) -> dict:
         """Merge the telemetry registry into the record stream: pending
-        events (compile, trace_rounds, ...) become their own records,
+        events (compile, trace, ...) become their own records,
         then one ``kind=telemetry`` snapshot of every counter / gauge /
         histogram is written.  Call at eval boundaries and at shutdown."""
         for ev in self.telemetry.drain_events():

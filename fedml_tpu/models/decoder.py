@@ -60,7 +60,7 @@ import numpy as np
 
 from fedml_tpu.models.base import COUNTERS, ModelBundle
 from fedml_tpu.models.transformer import (
-    AttnFn, MultiHeadAttention, _default_attn,
+    AttnFn, MultiHeadAttention, _default_attn, scoped,
 )
 from fedml_tpu.obs import scopes
 from fedml_tpu.ops.expert_rows import from_buffer, sorted_route, to_buffer
@@ -548,14 +548,6 @@ class ExpertLayer(nn.Module):
         return y.astype(w_down.dtype).reshape(B, L, h), counters
 
 
-def _scoped(attn: AttnFn, name: str) -> AttnFn:
-    def fn(*args, **kwargs):
-        with jax.named_scope(name):
-            return attn(*args, **kwargs)
-
-    return fn
-
-
 class GatedMLP(nn.Module):
     """``(silu(x Wg) * (x Wu)) Wd`` with no bias: the dense MLP of a layer,
     and the shared expert beside the routed ones."""
@@ -699,7 +691,7 @@ class DecoderBlock(nn.Module):
         attn_fn = self.attn_fn or _default_attn
         if self.kind == LATENT:
             return MultiHeadAttention(
-                c.num_heads, attn_fn=_scoped(attn_fn, scopes.ATTN_LATENT),
+                c.num_heads, attn_fn=scoped(attn_fn, scopes.ATTN_LATENT),
                 qkv=LatentQKV(c.num_heads, c.kv_lora_rank, c.qk_nope_head_dim,
                               c.qk_rope_head_dim, c.v_head_dim,
                               c.rms_norm_eps, parent=None))(a), {}
@@ -707,7 +699,7 @@ class DecoderBlock(nn.Module):
         rope = dict(c.rope).get(self.kind)
         return MultiHeadAttention(
             c.num_heads,
-            attn_fn=_scoped(attn_fn, scopes.ATTN_SLIDING if sliding
+            attn_fn=scoped(attn_fn, scopes.ATTN_SLIDING if sliding
                             else scopes.ATTN_FULL),
             num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
             rope_fn=make_rope_fn(dict(rope), c.head_dim) if rope else None,
@@ -717,9 +709,12 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        y, counters = self.mixer(RMSNorm(c.rms_norm_eps)(x).astype(x.dtype))
+        with jax.named_scope(scopes.NORM):
+            a = RMSNorm(c.rms_norm_eps)(x).astype(x.dtype)
+        y, counters = self.mixer(a)
         x = x + y
-        b = RMSNorm(c.rms_norm_eps)(x)
+        with jax.named_scope(scopes.NORM):
+            b = RMSNorm(c.rms_norm_eps)(x)
         if self.mlp == DENSE:
             with jax.named_scope(scopes.MLP_DENSE):
                 y = GatedMLP(c.intermediate_size, name="mlp")(b.astype(x.dtype))
@@ -752,9 +747,10 @@ class DecoderLM(nn.Module):
         # neighbouring tokens share, outweighs the token's own embedding;
         # every router then sees one common input and a few experts take
         # nearly every token (measured: PERF.md §6, PR 32)
-        h = nn.Embed(c.vocab_size, c.hidden_size, name="wte",
-                     embedding_init=nn.initializers.normal(1.0))(
-            x.astype(jnp.int32))
+        with jax.named_scope(scopes.EMBED):
+            h = nn.Embed(c.vocab_size, c.hidden_size, name="wte",
+                         embedding_init=nn.initializers.normal(1.0))(
+                x.astype(jnp.int32))
         block_cls = nn.remat(DecoderBlock) if c.remat else DecoderBlock
         totals = {}
         for i, kind in enumerate(c.layer_types):
@@ -766,8 +762,10 @@ class DecoderLM(nn.Module):
             for n, v in totals.items():
                 self.sow(COUNTERS, n, v, reduce_fn=lambda _, new: new,
                          init_fn=lambda: jnp.zeros((), jnp.float32))
-        h = RMSNorm(c.rms_norm_eps, name="norm_f")(h).astype(h.dtype)
-        return nn.Dense(c.vocab_size, use_bias=False, name="lm_head")(h)
+        with jax.named_scope(scopes.NORM):
+            h = RMSNorm(c.rms_norm_eps, name="norm_f")(h).astype(h.dtype)
+        with jax.named_scope(scopes.HEAD):
+            return nn.Dense(c.vocab_size, use_bias=False, name="lm_head")(h)
 
 
 def decoder_lm(config, attn_fn: Optional[AttnFn] = None) -> ModelBundle:

@@ -88,6 +88,15 @@ def _default_attn(q, k, v, causal, window=None):
     return lax_attention(q, k, v, causal, window)
 
 
+def scoped(attn: AttnFn, name: str) -> AttnFn:
+    """``attn`` with its ops under the scope ``name``."""
+    def fn(*args, **kwargs):
+        with jax.named_scope(name):
+            return attn(*args, **kwargs)
+
+    return fn
+
+
 class MultiHeadAttention(nn.Module):
     """One fused q/k/v projection, the attention function under ``vmap``
     over the batch, the output projection.  By default every q head has its
@@ -117,9 +126,10 @@ class MultiHeadAttention(nn.Module):
         if self.qkv is not None:
             q, k, v = self.qkv(x)
         else:
-            qkv = nn.Dense((H + 2 * G) * D, use_bias=False)(x)
-            q, k, v = jnp.split(qkv.reshape(B, L, H + 2 * G, D), [H, H + G],
-                                axis=2)
+            with jax.named_scope(scopes.ATTN_PROJ):
+                qkv = nn.Dense((H + 2 * G) * D, use_bias=False)(x)
+                q, k, v = jnp.split(qkv.reshape(B, L, H + 2 * G, D),
+                                    [H, H + G], axis=2)
         if self.rope_fn is not None:
             with jax.named_scope(scopes.ROPE):
                 q, k = self.rope_fn(q), self.rope_fn(k)
@@ -127,8 +137,9 @@ class MultiHeadAttention(nn.Module):
         if self.window is not None:
             attn = functools.partial(attn, window=self.window)
         out = jax.vmap(lambda a, b, c: attn(a, b, c, self.causal))(q, k, v)
-        return nn.Dense(E, use_bias=False)(
-            out.reshape(B, L, H * out.shape[-1]))
+        with jax.named_scope(scopes.ATTN_PROJ):
+            return nn.Dense(E, use_bias=False)(
+                out.reshape(B, L, H * out.shape[-1]))
 
 
 class Block(nn.Module):
@@ -139,13 +150,16 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         E = x.shape[-1]
-        x = x + MultiHeadAttention(self.num_heads, self.attn_fn)(
-            nn.LayerNorm()(x)
-        )
-        h = nn.LayerNorm()(x)
-        h = nn.Dense(self.mlp_ratio * E)(h)
-        h = nn.gelu(h)
-        return x + nn.Dense(E)(h)
+        with jax.named_scope(scopes.NORM):
+            h = nn.LayerNorm()(x)
+        x = x + MultiHeadAttention(self.num_heads, scoped(
+            self.attn_fn or _default_attn, scopes.ATTN_FULL))(h)
+        with jax.named_scope(scopes.NORM):
+            h = nn.LayerNorm()(x)
+        with jax.named_scope(scopes.MLP_DENSE):
+            h = nn.Dense(self.mlp_ratio * E)(h)
+            h = nn.Dense(E)(nn.gelu(h))
+        return x + h
 
 
 class TransformerLM(nn.Module):
@@ -169,14 +183,15 @@ class TransformerLM(nn.Module):
     def __call__(self, x, train: bool = False):
         B, L = x.shape
         tok = nn.Embed(self.vocab_size, self.embed_dim, name="wte")
-        h = tok(x.astype(jnp.int32))
         pos0 = self.pos_offset_fn(L) if self.pos_offset_fn else 0
         if isinstance(pos0, int) and pos0 + L > self.max_len:
             raise ValueError(
                 f"sequence length {L} exceeds max_len {self.max_len}"
             )
         wpe = nn.Embed(self.max_len, self.embed_dim, name="wpe")
-        h = h + wpe(pos0 + jnp.arange(L))[None]
+        with jax.named_scope(scopes.EMBED):
+            h = tok(x.astype(jnp.int32))
+            h = h + wpe(pos0 + jnp.arange(L))[None]
         # explicit names keep the parameter tree identical with and
         # without remat (nn.remat would auto-name "CheckpointBlock_i")
         block_cls = nn.remat(Block) if self.remat else Block
@@ -186,9 +201,11 @@ class TransformerLM(nn.Module):
         # named so partition-rule tables (parallel/partition.py) can
         # address the final norm distinctly from the blocks' auto-named
         # LayerNorm_{0,1} — the GPT convention
-        h = nn.LayerNorm(name="ln_f")(h)
+        with jax.named_scope(scopes.NORM):
+            h = nn.LayerNorm(name="ln_f")(h)
         # weight-tied head
-        return tok.attend(h)
+        with jax.named_scope(scopes.HEAD):
+            return tok.attend(h)
 
 
 def transformer_lm(

@@ -9,7 +9,7 @@ core/comm can depend on it without cycles or jax import cost):
   backends (wired into ``CommBackend``, so transports and algorithms
   need no changes to be measured);
 - ``jax_hooks``  — compile-event tracking per jit signature, device
-  memory high-water gauges, ``trace_rounds`` profiler bracketing;
+  memory high-water gauges;
 - ``digest``     — mergeable registry digests (the in-band stats plane:
   associative ``merge``, delta sources, the server-side rollup);
 - ``slo``        — the declarative federation SLO engine + the atomic

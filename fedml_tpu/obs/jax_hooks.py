@@ -1,6 +1,6 @@
-"""JAX runtime hooks: compile tracking, memory gauges, profiler traces.
+"""JAX runtime hooks: compile tracking and memory gauges.
 
-Three observability gaps this closes (ISSUE 1):
+Two observability gaps this closes (ISSUE 1):
 
 - **Recompile storms are invisible.**  ``instrument_jit`` wraps a jitted
   callable and tracks the abstract signature (treedef + shape/dtype per
@@ -13,9 +13,6 @@ Three observability gaps this closes (ISSUE 1):
 - **Device memory pressure is invisible.**  ``record_device_memory``
   snapshots ``Device.memory_stats()`` (``None`` on the CPU backend, which
   is skipped) into high-water gauges.
-- **Profiler bracketing is manual.**  ``trace_rounds`` wraps N fully
-  synced rounds (``utils/timing.sync_round`` — block AND scalar
-  readback) in a ``jax.profiler`` trace.
 
 ``install_jax_monitoring`` additionally subscribes to
 ``jax.monitoring`` duration events (event names containing "compile"),
@@ -27,7 +24,7 @@ includes dispatch.
 from __future__ import annotations
 
 import time
-from typing import Any, Callable, Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 from fedml_tpu.obs.telemetry import Telemetry, get_telemetry
 
@@ -137,51 +134,3 @@ def record_device_memory(telemetry: Optional[Telemetry] = None) -> dict:
         if in_use is not None:
             t.gauge_set("jax.device_mem_bytes", in_use, device=d.id)
     return out
-
-
-def trace_rounds(
-    round_fn: Callable,
-    state: Any,
-    args: Tuple,
-    rounds: int = 2,
-    *,
-    log_dir: Optional[str] = None,
-    logger=None,
-    telemetry: Optional[Telemetry] = None,
-) -> Tuple[Any, list]:
-    """Bracket N fully-synced rounds in a ``jax.profiler`` trace.
-
-    Every round is synced with ``utils/timing.sync_round`` (block AND
-    scalar readback), so the trace spans real device work, not enqueues.
-    ``log_dir`` defaults through ``core.metrics.trace`` to the logger's
-    ``run_dir``; the trace path and per-round seconds are logged into
-    the metrics stream.  Returns ``(final_state, per_round_seconds)``.
-    """
-    import jax
-
-    from fedml_tpu.core.metrics import trace
-    from fedml_tpu.utils.timing import sync_round
-
-    t = telemetry or get_telemetry()
-    times = []
-    with trace(log_dir, logger=logger) as tdir:
-        for _ in range(rounds):
-            t0 = time.perf_counter()
-            # marks the round on the trace's own clock: what
-            # benchmark/tools/scope_table.py takes for the window
-            with jax.profiler.TraceAnnotation("fed.traced_round"):
-                state, metrics = round_fn(state, *args)
-                sync_round(state, metrics)
-            dt = time.perf_counter() - t0
-            times.append(dt)
-            t.observe("span.traced_round_s", dt)
-    # exactly one trace_rounds record: straight into the logger's stream
-    # when one is given, else into the event ring for a later
-    # log_telemetry drain (both would double it up)
-    rec = {"trace_dir": tdir, "rounds": rounds,
-           "round_s": [round(x, 6) for x in times]}
-    if logger is not None:
-        logger.log({"kind": "trace_rounds", **rec})
-    else:
-        t.event("trace_rounds", **rec)
-    return state, times

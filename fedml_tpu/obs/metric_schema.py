@@ -123,7 +123,6 @@ HISTOGRAMS = {
     "span.server_round_s": "server round wall time, open to close",
     "span.reconnect_s": "outage span, first EOF to re-registered",
     "robust.upload_norm": "L2 norm of each decoded upload's delta vs the broadcast base",
-    "span.traced_round_s": "per-round synced seconds under trace_rounds",
     "slo.round_wall_s": "server round wall (open->close) — the SLO percentile source",
     "slo.round_bytes": "server-visible comm bytes folded per round (sent+recv delta)",
     "async.upload_staleness": "round gap r-b of each accepted async upload (0 = current)",
@@ -146,7 +145,6 @@ METRIC_PATTERNS = {
 EVENTS = {
     "compile": "one jit compilation {fn, signature, seconds}",
     "trace": "profiler trace written {trace_dir}",
-    "trace_rounds": "profiler round bracketing {trace_dir, per-round seconds}",
     "config": "the full experiment dataclass (MetricsLogger record)",
     "telemetry": "registry snapshot record (MetricsLogger.log_telemetry)",
     "resume": "checkpoint resume {round}",

@@ -29,25 +29,32 @@ SCOPES = (ROUNDS, ROUND, CLIENTS, LOCAL_UPDATE, CODEC, AGG_TRANSFORM,
           LOSS, OPTIMIZER)
 
 # Inside ``fed.model``: the parts of a decoder that differ from layer to
-# layer (``models/decoder.py``).  They must not match ``fed\.[a-z_]+``: a
-# stage reader takes an op's last such segment as its stage, and these ops
-# stay the model's.
+# layer (``models/decoder.py``) and the dense parts every model shares (both
+# model files).  They must not match ``fed\.[a-z_]+``: a stage reader takes
+# an op's last such segment as its stage, and these ops stay the model's.
+# None goes around a whole block or mixer: ``benchmark/model_scopes.py``
+# takes an op's last ``model\.[a-z_]+`` segment as its part.
 ROPE = "model.rope"  # rotary tables and the rotation of q and k
 ATTN_SLIDING = "model.attn_sliding"  # the attention function of a windowed layer
-ATTN_FULL = "model.attn_full"  # the attention function of a full layer
+ATTN_FULL = "model.attn_full"  # the attention function of a full layer (and of TransformerLM's Block)
 MOE_ROUTER = "model.moe_router"  # router logits, softmax, top-k, weights
 MOE_DISPATCH = "model.moe_dispatch"  # grouping by expert and the gather of rows
 MOE_EXPERTS = "model.moe_experts"  # the grouped matrix products and the gate
 MOE_COMBINE = "model.moe_combine"  # rows back to tokens, summed over the k
 MOE_SHARED = "model.moe_shared"  # the shared expert, beside the routed sum
-MLP_DENSE = "model.mlp_dense"  # the gated MLP of a layer without experts
+MLP_DENSE = "model.mlp_dense"  # the MLP of a layer without experts, gated or not
 ATTN_LATENT = "model.attn_latent"  # the attention function of a latent-attention layer
 MLA_PROJ = "model.mla_proj"  # q, the latent down- and up-projections, the latent's norm
 KDA_PROJ = "model.kda_proj"  # linear attention: q/k/v projections, convolutions, SiLU, L2 norms
 KDA_GATES = "model.kda_gates"  # decay, beta and output-gate projections, softplus, sigmoids
 KDA_SCAN = "model.kda_scan"  # ops.linear_attention.gated_delta_rule and nothing else
 KDA_OUT = "model.kda_out"  # the gated norm a head and the output projection
+EMBED = "model.embed"  # the token embedding lookup (and wpe and its add); its backward is the scatter-add
+ATTN_PROJ = "model.attn_proj"  # MultiHeadAttention's fused q/k/v Dense with its split, and its output Dense
+NORM = "model.norm"  # the norms before the mixer and the MLP and the final norm, at their call sites
+HEAD = "model.head"  # the vocabulary head's product (tok.attend, lm_head) and nothing else
 
 MODEL_SCOPES = (ROPE, ATTN_SLIDING, ATTN_FULL, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_COMBINE, MOE_SHARED, MLP_DENSE, ATTN_LATENT,
-                MLA_PROJ, KDA_PROJ, KDA_GATES, KDA_SCAN, KDA_OUT)
+                MLA_PROJ, KDA_PROJ, KDA_GATES, KDA_SCAN, KDA_OUT, EMBED,
+                ATTN_PROJ, NORM, HEAD)

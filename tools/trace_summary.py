@@ -92,8 +92,7 @@ def hist_quantile(hist: dict, q: float) -> Optional[float]:
 def summarize(records: List[dict]) -> dict:
     rounds = [r for r in records if "round" in r and "kind" not in r]
     compiles = [r for r in records if r.get("kind") == "compile"]
-    traces = [r for r in records
-              if r.get("kind") in ("trace", "trace_rounds")]
+    traces = [r for r in records if r.get("kind") == "trace"]
     config = next((r for r in records if r.get("kind") == "config"), None)
     telemetry = None
     for r in records:

@@ -140,6 +140,53 @@ def test_unequal_head_sizes_compile_for_v5e(one_chip):
         (1, L, H, 192), (1, L, H, 192), (1, L, H, 128))
 
 
+def test_the_gated_delta_rules_kernels_compile_for_v5e(one_chip, monkeypatch):
+    """The linear-attention layer's scan of the ``kimilin_silo_doc8k`` cell
+    under the model's vmap: [1, 8192, 4, 128] in chunks of 64, ``v`` bf16,
+    forward and backward: the pair sums' kernel and the recurrence's, each
+    with its hand-written backward, four Mosaic kernels around XLA's
+    triangular solve; no [n, H, nb, 16, 16, d] block of differences and no
+    ``while`` over the chunks is left."""
+    import re
+
+    from fedml_tpu.ops import linear_attention as la
+
+    L, H, d, chunk = 8192, 4, 128, 64
+    assert la.kernels_tile(d, d, chunk)
+    # the path asks the backend whether to interpret its kernels; the compile
+    # is for the described chip whatever this process runs on
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def grads(q, k, v, g, beta, do):
+        return jax.grad(lambda *a: (
+            jax.vmap(lambda *t: la.gated_delta_rule(*t, chunk=chunk))(*a)
+            .astype(jnp.float32) * do.astype(jnp.float32)).sum(),
+            argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    spec = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    wide, half = spec((1, L, H, d)), spec((1, L, H, d), jnp.bfloat16)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(grads).lower(
+            wide, wide, half, wide, spec((1, L, H)), half).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    for name in ("kda_pairs_fwd", "kda_pairs_bwd", "kda_scan_fwd",
+                 "kda_scan_bwd"):
+        assert sum("tpu_custom_call" in line and name in line
+                   for line in text.splitlines()) == 1, name
+    assert " while(" not in text
+    blocks = re.findall(rf"\w+\[(?:\d+,)*{la.SUB},{la.SUB},{d}\]", text)
+    assert not blocks, sorted(set(blocks))
+    dq, dk, dv, dg, dbeta = jax.eval_shape(grads, wide, wide, half, wide,
+                                           spec((1, L, H)), half)
+    assert (dq.shape, dv.dtype, dbeta.shape) == (
+        (1, L, H, d), jnp.bfloat16, (1, L, H))
+    # what the scan keeps for its backward and works in: the chunks' entry
+    # states, 32 MiB, and the terms between the kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2**30
+
+
 def test_the_expert_layers_grouped_products_compile_for_v5e(one_chip):
     """``megablox.gmm`` at the decoder cell's expert shapes and the tiling
     ``gmm_tiling`` picks, forward and both gradients, with group sizes

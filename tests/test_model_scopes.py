@@ -453,3 +453,51 @@ def test_benchmark_json_has_the_metric_with_its_fields_and_cells(name):
     assert set(reads) <= set(entry["workloads"])
     assert not set(silent) & set(entry["workloads"])
     assert len(json.dumps(manifest)) < 64 * 1024
+
+
+# -- linear_attn_kernel_pct (PR 38): the scan's seconds that are kernels -------
+
+KDA_FWD = FWD + "DecoderLM/Block_1/LinearAttention_0/model.kda_scan/vmap("
+KDA_BWD = BWD + "DecoderLM/Block_1/LinearAttention_0/model.kda_scan/vmap("
+
+
+def scan_op(tf_op, seconds, category):
+    made = op(tf_op, seconds, "other")
+    made.stats["hlo_category"] = category
+    return made
+
+
+SCAN = [
+    scan_op(KDA_FWD + "kda_pairs_fwd)/pallas_call", 0.3, "custom-call"),
+    scan_op(KDA_BWD + "kda_scan_bwd)/pallas_call", 0.5, "custom-call"),
+    # the solve between the kernels is a custom call too, but no Pallas one
+    scan_op(KDA_FWD + "triangular_solve)", 0.15, "custom-call"),
+    scan_op(KDA_BWD + "mul)", 0.05, "data formatting"),
+    # a kernel of another layer is no part of the scan
+    scan_op(FWD + "DecoderLM/Block_4/model.attn_latent/vmap(flash_fwd)/"
+            "pallas_call", 0.7, "custom-call"),
+]
+
+
+@pytest.mark.parametrize("ops, want", [
+    (SCAN, 100 * 0.8 / 1.0), (SCAN[2:], 0.0), (SCAN[4:] + SCOPED, None),
+], ids=["kernels_and_lax_ops", "lax_ops_alone", "no_op_under_the_scope"])
+def test_the_scans_kernel_share_is_its_pallas_calls_seconds(ops, want):
+    read = cells.load_layer_metric("linear_attn_kernel_pct").read
+    got = read(context(ops, cell=KIMI))
+    assert got is None if want is None else got == pytest.approx(want,
+                                                                 rel=1e-12)
+
+
+def test_benchmark_json_names_the_scans_kernel_share_and_its_file():
+    (entry,) = [m for m in cells.manifest()["per_layer"]
+                if m["name"] == "linear_attn_kernel_pct"]
+    assert entry == {"name": "linear_attn_kernel_pct", "unit": "%",
+                     "better": "higher", "source": "device_trace",
+                     "layer": "kernels", "moves": "tokens_per_s",
+                     "workloads": [KIMI]}
+    root = os.path.dirname(os.path.abspath(cells.__file__))
+    assert os.path.isfile(os.path.join(
+        root, "layer_metrics", "linear_attn_kernel_pct.py"))
+    # the newest entry stands last: nothing before it moved
+    assert cells.manifest()["per_layer"][-1] == entry

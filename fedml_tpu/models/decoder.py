@@ -18,10 +18,22 @@ Mixer kinds (``DecoderConfig.layer_types``, one a layer):
   from a normed low-rank latent, plus a key part that all heads share; q and k
   heads of ``qk_nope_head_dim + qk_rope_head_dim``, v heads of ``v_head_dim``
   (``LatentQKV``, handed to ``MultiHeadAttention`` as its projection).
+- ``sparse_attention`` (a configuration with ``sa_config``): softmax attention
+  over the keys a learned indexer picks for each query, the ``topk`` highest of
+  ``sum_j w_j relu(qI_j . kI)`` over the causal positions (``Indexer``,
+  ``ops/sparse_select.py``, ``chosen_keys``); one set a token for every head.
+  The choice is discrete: the next-token loss gives the indexer's leaves a
+  gradient of exactly zero, and q, k, v that of a softmax over the set held
+  fixed.
 - ``linear_attention``: the gated delta rule with a per-channel decay
   (``ops/linear_attention.py``), behind short causal depthwise convolutions,
   with low-rank decay and output gates and a gated RMSNorm a head
   (``LinearAttention``).  Position comes from the recurrence.
+
+Any softmax kind RMS-norms its q and k heads before the rotation where the
+configuration says ``qk_norm``.  Rotary positions come from ``rope_parameters``
+or from a top-level ``rope_theta`` (with ``rope_scaling``: an ``mrope_section``
+block is plain RoPE on text, whose three position streams coincide).
 
 MLP kinds (``mlp_types``): ``sparse``, the expert layer below, with a shared
 expert (a ``GatedMLP`` every token passes) added outside the routed sum where
@@ -60,14 +72,16 @@ import numpy as np
 
 from fedml_tpu.models.base import COUNTERS, ModelBundle
 from fedml_tpu.models.transformer import (
-    AttnFn, MultiHeadAttention, _default_attn, scoped,
+    AttnFn, MultiHeadAttention, RMSNorm, _default_attn, scoped,
 )
 from fedml_tpu.obs import scopes
 from fedml_tpu.ops.expert_rows import from_buffer, sorted_route, to_buffer
 from fedml_tpu.ops.linear_attention import gated_delta_rule, short_causal_conv
+from fedml_tpu.ops.sparse_select import select_topk
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 LINEAR, LATENT = "linear_attention", "latent_attention"
+SELECTED = "sparse_attention"  # keys chosen by an indexer
 SPARSE, DENSE = "sparse", "dense"
 # the scalar counters ``DecoderLM`` sows into ``COUNTERS`` in train mode
 ASSIGNMENTS_HELD = "moe_assignments_held"  # token-expert pairs on held experts
@@ -75,6 +89,8 @@ EXPERT_TOKENS_MAX = "moe_expert_tokens_max"  # the fullest held expert's rows
 ROWS_BUFFERED = "moe_rows_buffered"  # rows of the buffer the layer took
 # a linear-attention layer's mean log decay over tokens, heads and channels
 KDA_LOG_DECAY_MEAN = "kda_log_decay_mean"
+# a SELECTED layer's count of 512 x 512 causal tiles that hold a chosen pair
+ATTN_TILES_LIVE = "attn_tiles_live"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +101,7 @@ class DecoderConfig:
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    layer_types: Tuple[str, ...]  # one a layer: SLIDING, FULL, LINEAR, LATENT
+    layer_types: Tuple[str, ...]  # one a layer: SLIDING, FULL, LINEAR, LATENT, SELECTED
     sliding_window: int
     rope: Tuple[Tuple[str, Tuple[Tuple[str, object], ...]], ...]  # by layer type
     rms_norm_eps: float
@@ -111,6 +127,11 @@ class DecoderConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    qk_norm: bool = False  # RMSNorm on q and k heads (softmax kinds)
+    # SELECTED layers: index heads, their size, keys a query keeps
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    index_topk: int = 0
 
     @classmethod
     def from_dict(cls, c: dict) -> "DecoderConfig":
@@ -118,7 +139,9 @@ class DecoderConfig:
         ``num_hidden_layers``.  A layer's mixer comes from
         ``linear_attn_config`` (``kda_layers`` and ``full_attn_layers``,
         numbered from 1; a full layer is LATENT where ``kv_lora_rank`` is
-        set) or from ``layer_types``, cycled to the depth; its MLP from
+        set), from ``layer_types``, cycled to the depth, or, where the
+        configuration carries ``sa_config`` and no ``layer_types``, is SELECTED
+        throughout; its MLP from
         ``mlp_layer_types`` or from ``first_k_dense_replace`` leading dense
         layers.  ``num_experts_routed`` defaults to ``num_experts``,
         ``experts_held`` to the first ``num_experts`` ids, and
@@ -130,7 +153,8 @@ class DecoderConfig:
                      else LATENT if c.get("kv_lora_rank") else FULL
                      for i in range(depth)]
         else:
-            kinds = c.get("layer_types") or [FULL]
+            kinds = c.get("layer_types") or [
+                SELECTED if c.get("sa_config") else FULL]
         mlp = c.get("mlp_layer_types") or [
             DENSE if i < c.get("first_k_dense_replace", 0) else SPARSE
             for i in range(depth)]
@@ -143,9 +167,20 @@ class DecoderConfig:
                 0 <= e < routed for e in held):
             raise ValueError(f"experts_held {held} against num_experts "
                              f"{c['num_experts']} of {routed} routed")
-        rope = c.get("rope_parameters") or {}  # none: no positional encoding
+        rope = c.get("rope_parameters") or {}
+        if not rope and "rope_theta" in c and not c.get("mla_use_nope"):
+            # the older top-level keys.  ``rope_scaling`` may carry an
+            # ``mrope_section``: three position streams that coincide on text
+            scaling = c.get("rope_scaling") or {}
+            kind = scaling.get("rope_type", scaling.get("type", "default"))
+            if kind != "default":
+                raise ValueError(f"rope_scaling of rope_type {kind!r}: only "
+                                 "'default' is read from the top-level keys")
+            rope = {"rope_type": "default", "rope_theta": c["rope_theta"]}
+        # none: no positional encoding
         if "rope_type" in rope:  # one block for every layer type
-            rope = {SLIDING: rope, FULL: rope}
+            rope = {SLIDING: rope, FULL: rope, SELECTED: rope}
+        sparse = c.get("sa_config") or {}
         return cls(
             vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
             num_layers=depth, num_heads=c["num_attention_heads"],
@@ -179,6 +214,10 @@ class DecoderConfig:
             qk_nope_head_dim=c.get("qk_nope_head_dim", 0),
             qk_rope_head_dim=c.get("qk_rope_head_dim", 0),
             v_head_dim=c.get("v_head_dim", 0),
+            qk_norm=c.get("qk_norm", False),
+            indexer_heads=sparse.get("indexer_num_heads", 0),
+            indexer_head_dim=sparse.get("indexer_head_dim", 0),
+            index_topk=sparse.get("topk", 0),
         )
 
 
@@ -245,20 +284,6 @@ def make_rope_fn(params: dict, head_dim: int) -> Callable:
 
 
 # -- layers -------------------------------------------------------------------
-
-class RMSNorm(nn.Module):
-    """``x / sqrt(mean(x^2) + eps) * g``, computed and returned in float32
-    (the caller rounds it where it wants to)."""
-
-    eps: float = 1e-6
-
-    @nn.compact
-    def __call__(self, x):
-        g = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        x = x.astype(jnp.float32)
-        var = jnp.mean(x * x, axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
-
 
 def gmm_tiling(m: int, k: int, n: int):
     """(rows, contraction, columns) tile of the grouped-product kernel for
@@ -596,6 +621,63 @@ class LatentQKV(nn.Module):
         return q, k, kv[..., self.nope:]
 
 
+class Indexer(nn.Module):
+    """The index projections of a SELECTED layer, for ``MultiHeadAttention``
+    to hand its attention function: ``x`` [B, L, h] -> (``qI`` [B, L, Hi, di],
+    ``kI`` [B, L, di], ``w`` [B, L, Hi]).  ``qI = x WqI`` a head,
+    ``kI = LayerNorm(x WkI)``, one index key a token for all index heads,
+    ``w = x Ww``; ``qI`` and ``kI`` rotated over all their channels.  The
+    projections, the norm and the rotation are float32 at full precision
+    whatever ``x``'s dtype (the choice they feed is a comparison of close
+    numbers); ``qI`` and ``kI`` are then rounded to ``x``'s dtype, the
+    operands of the index scores' products (``ops/sparse_select.py``: on the
+    chip in bf16 the 16-head product is 4/5 of the choice's time at float32
+    precision, and rounding its operands flips 0.02 % more of the chosen pairs
+    against the reference than the residual stream's own rounding does, 0.35-
+    1.0 %: PERF.md §6, PR 39); ``w`` stays float32."""
+
+    num_heads: int
+    head_dim: int
+    eps: float
+    rope_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x):
+        (B, L, _), H, d = x.shape, self.num_heads, self.head_dim
+        operand = x.dtype
+        x = x.astype(jnp.float32)
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, name=name,
+                            precision=jax.lax.Precision.HIGHEST)(x)
+
+        with jax.named_scope(scopes.ATTN_INDEXER):
+            q = dense(H * d, "q").reshape(B, L, H, d)
+            k = nn.LayerNorm(epsilon=self.eps, name="k_norm")(dense(d, "k"))
+            w = dense(H, "w")
+            if self.rope_fn is not None:
+                q = self.rope_fn(q)
+                k = self.rope_fn(k[:, :, None, :])[:, :, 0]
+        return q.astype(operand), k.astype(operand), w
+
+
+def chosen_keys(attn: AttnFn, topk: int) -> AttnFn:
+    """``attn`` over the ``topk`` keys an indexer picks a query, as
+    ``MultiHeadAttention`` calls the attention function of a layer with an
+    ``indexer``: ``index`` = one example's (qI, kI, w).  The choice
+    (``select_topk``) goes to ``attn`` as ``keep=`` and ``tiles=``; beside
+    the output comes the count of tiles that hold a chosen pair."""
+    def fn(q, k, v, causal, index):
+        with jax.named_scope(scopes.ATTN_SELECT):
+            keep, tiles = select_topk(*index, topk)
+            live = tiles.sum().astype(jnp.float32)
+        with jax.named_scope(scopes.ATTN_SPARSE):
+            out = attn(q, k, v, causal, keep=keep, tiles=tiles)
+        return out, {ATTN_TILES_LIVE: live}
+
+    return fn
+
+
 def _log_uniform(low: float, high: float, transform: Callable) -> Callable:
     """An initializer: ``transform`` of ``exp(U[log low, log high])``."""
     def init(key, shape, dtype=jnp.float32):
@@ -696,15 +778,26 @@ class DecoderBlock(nn.Module):
                               c.qk_rope_head_dim, c.v_head_dim,
                               c.rms_norm_eps, parent=None))(a), {}
         sliding = self.kind == SLIDING
-        rope = dict(c.rope).get(self.kind)
+        rope = dict(dict(c.rope).get(self.kind) or {})
+
+        def rope_fn(head_dim):
+            return make_rope_fn(rope, head_dim) if rope else None
+
+        shared = dict(
+            num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
+            rope_fn=rope_fn(c.head_dim),
+            qk_norm=c.rms_norm_eps if c.qk_norm else None)
+        if self.kind == SELECTED:
+            return MultiHeadAttention(
+                c.num_heads, attn_fn=chosen_keys(attn_fn, c.index_topk),
+                indexer=Indexer(c.indexer_heads, c.indexer_head_dim,
+                                c.rms_norm_eps, rope_fn(c.indexer_head_dim),
+                                parent=None), **shared)(a)
         return MultiHeadAttention(
             c.num_heads,
             attn_fn=scoped(attn_fn, scopes.ATTN_SLIDING if sliding
                             else scopes.ATTN_FULL),
-            num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
-            rope_fn=make_rope_fn(dict(rope), c.head_dim) if rope else None,
-            window=c.sliding_window if sliding else None,
-        )(a), {}
+            window=c.sliding_window if sliding else None, **shared)(a), {}
 
     @nn.compact
     def __call__(self, x):

@@ -29,20 +29,38 @@ from fedml_tpu.parallel.ring_attention import blockwise_attention
 
 # (q, k, v, causal) over [L, H, D] per example; a layer with a window also
 # passes ``window=``, k, v may hold fewer heads than q, and v's head size
-# may differ from q's and k's
+# may differ from q's and k's.  A layer whose keys are chosen from the data
+# passes ``keep=`` ([L, L] int8: 1 where the query's row sees the key's
+# column, causal) and ``tiles=`` (the table of 512-tiles that hold a kept
+# pair): ``ops/sparse_select.py``.  ``MultiHeadAttention`` itself hands an
+# ``indexer``'s per-example output on as ``index=`` and takes (output, a dict
+# of per-example scalars) back: the function it is given then makes the
+# choice (``models/decoder.py`` ``chosen_keys``).
 AttnFn = Callable
 
 
-def lax_attention(q, k, v, causal, window=None):
+def lax_attention(q, k, v, causal, window=None, keep=None, tiles=None):
     """The lax blockwise scan under the ``AttnFn`` signature: the policy's
     fallback, and what a caller whose heads are sharded by GSPMD passes
     as ``attn_fn`` (a ``pallas_call`` has no partitioning rule, so XLA
-    would gather q, k, v and run every head on every chip)."""
-    return blockwise_attention(q, k, v, causal=causal, block_size=512,
-                               window=window)
+    would gather q, k, v and run every head on every chip).
+
+    With a choice of keys (``keep``; the scan reads no tile table) the call
+    runs under ``jax.checkpoint``, as the scan then runs each kv block: a
+    query sees keys all along its row, and neither the carries a block nor
+    the probability blocks a plain scan saves for its backward (all of
+    [H, L, L]: 4.3 GB a layer at 32 heads of 8192 in bf16) outlive the
+    layer's own backward."""
+    if keep is None:
+        return blockwise_attention(q, k, v, causal=causal, block_size=512,
+                                   window=window)
+    del tiles
+    return jax.checkpoint(functools.partial(
+        blockwise_attention, causal=causal, block_size=512, window=window,
+    ))(q, k, v, keep=keep)
 
 
-def _default_attn(q, k, v, causal, window=None):
+def _default_attn(q, k, v, causal, window=None, keep=None, tiles=None):
     """Single-device attention policy, from what the call can see:
 
     - on a TPU, for a shape the fused kernels take (``pick_block`` finds
@@ -83,9 +101,9 @@ def _default_attn(q, k, v, causal, window=None):
     if fits and groups and jax.default_backend() == "tpu":
         return flash_attention(
             q, k, v, causal=causal, block_q=block, block_k=block,
-            window=window,
+            window=window, keep=keep, tiles=tiles,
         )
-    return lax_attention(q, k, v, causal, window)
+    return lax_attention(q, k, v, causal, window, keep, tiles)
 
 
 def scoped(attn: AttnFn, name: str) -> AttnFn:
@@ -97,6 +115,20 @@ def scoped(attn: AttnFn, name: str) -> AttnFn:
     return fn
 
 
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * g``, computed and returned in float32
+    (the caller rounds it where it wants to)."""
+
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        g = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        var = jnp.mean(x * x, axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
+
+
 class MultiHeadAttention(nn.Module):
     """One fused q/k/v projection, the attention function under ``vmap``
     over the batch, the output projection.  By default every q head has its
@@ -106,7 +138,15 @@ class MultiHeadAttention(nn.Module):
     ([B, L, H, D] -> the same) and ``window`` is handed to ``attn_fn``.
     ``qkv``, a module ``x -> (q, k, v)`` with parameters of its own, takes
     the fused projection's place: its v heads may be of another size than its
-    q and k heads, and the output projection reads what comes back."""
+    q and k heads, and the output projection reads what comes back.
+
+    ``qk_norm`` (an ``eps``; off by default) RMS-norms every q head and every
+    k head over its channels before the rotation, one weight [D] for q and
+    one for k.  ``indexer``, a module ``x -> (qI, kI, w)`` with parameters of
+    its own, makes the layer one whose keys are chosen from the data: its
+    per-example output reaches ``attn_fn`` inside the ``vmap`` as ``index=``,
+    ``attn_fn`` returns (output, {name: scalar}) and so does this module, the
+    scalars summed over the batch."""
 
     num_heads: int
     attn_fn: Optional[AttnFn] = None
@@ -116,6 +156,8 @@ class MultiHeadAttention(nn.Module):
     rope_fn: Optional[Callable] = None
     window: Optional[int] = None
     qkv: Optional[nn.Module] = None
+    qk_norm: Optional[float] = None
+    indexer: Optional[nn.Module] = None
 
     @nn.compact
     def __call__(self, x):
@@ -130,16 +172,28 @@ class MultiHeadAttention(nn.Module):
                 qkv = nn.Dense((H + 2 * G) * D, use_bias=False)(x)
                 q, k, v = jnp.split(qkv.reshape(B, L, H + 2 * G, D),
                                     [H, H + G], axis=2)
+        if self.qk_norm is not None:
+            with jax.named_scope(scopes.NORM):
+                q = RMSNorm(self.qk_norm, name="q_norm")(q).astype(x.dtype)
+                k = RMSNorm(self.qk_norm, name="k_norm")(k).astype(x.dtype)
         if self.rope_fn is not None:
             with jax.named_scope(scopes.ROPE):
                 q, k = self.rope_fn(q), self.rope_fn(k)
         attn = self.attn_fn or _default_attn
         if self.window is not None:
             attn = functools.partial(attn, window=self.window)
-        out = jax.vmap(lambda a, b, c: attn(a, b, c, self.causal))(q, k, v)
+        sums = None
+        if self.indexer is not None:
+            out, scalars = jax.vmap(
+                lambda a, b, c, i: attn(a, b, c, self.causal, index=i)
+            )(q, k, v, self.indexer(x))
+            sums = {n: s.sum() for n, s in scalars.items()}
+        else:
+            out = jax.vmap(lambda a, b, c: attn(a, b, c, self.causal))(q, k, v)
         with jax.named_scope(scopes.ATTN_PROJ):
-            return nn.Dense(E, use_bias=False)(
+            y = nn.Dense(E, use_bias=False)(
                 out.reshape(B, L, H * out.shape[-1]))
+        return y if sums is None else (y, sums)
 
 
 class Block(nn.Module):

@@ -46,6 +46,20 @@ and writes, so no float32 gradient reaches HBM.  Under a causal mask the
 loops' bounds leave out the blocks above the diagonal, and only the
 blocks the diagonal crosses apply the mask.
 
+Which pairs count is said in one of four ways: nothing (every pair),
+``causal`` (the shapes alone), ``causal`` with a ``window``, or a **choice**,
+``keep`` [L, L] int8 with ``tiles``, its table of the block pairs that hold a
+kept pair (``ops/sparse_select.py``: the keys a learned indexer picks a
+query).  The first three are known from the shapes, and the loops' bounds
+leave whole blocks out.  A choice is data: both kernels read the table from
+SMEM and step over a block pair it marks empty, and a live pair masks by its
+[block, block] tile of ``keep``, which holds the causal mask too.  The
+forward takes the tiles of its q block along a leading dim ([nk, bq, bk]), the
+backward those of its kv block transposed ([nq, bk, bq], as it works on
+``s^T``); each is one relayout of ``keep`` in HBM.  No row of k or v is
+gathered: a chosen pair costs what a causal pair costs, and a tile's masked
+pairs are computed and dropped.
+
 ``interpret=True`` runs the same kernels on the CPU (tests);
 ``blockwise_attention`` remains the lax fallback.
 """
@@ -197,12 +211,17 @@ def _q_range(ki, block_q, block_k, nq, causal, window=None):
     return first, whole, jnp.clip((k0 + window) // block_q, whole, end), end
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale: float,
                 causal: bool, window, block_k: int, head_dim: int,
-                v_head_dim: int, group: int):
+                v_head_dim: int, group: int, chosen: bool = False):
+    # with a choice: its tiles of this q block [nk, bq, bk], the table [nq, nk]
+    keep_ref, tiles_ref, o_ref, lse_ref = refs if chosen else (None, None,
+                                                               *refs)
     block_q, width = o_ref.shape
     qi, nk = pl.program_id(1), k_ref.shape[0] // block_k
     bounds = _kv_range(qi, block_q, block_k, nk, causal, window)
+    if chosen:  # every block up to the diagonal's masks by its tile
+        bounds = (0, 0, 0, bounds[3])
     q = q_ref[...]
     out = jnp.zeros(o_ref.shape, jnp.float32)
     for a in range(group):
@@ -216,13 +235,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
             v = v_ref[pl.ds(j * block_k, block_k), :]
             s = _dot(qa, k, _NT) * sm_scale                  # [BQ, BK]
             if masked:
-                s = jnp.where(_keep(qi * block_q, j * block_k, s.shape, 1,
-                                    window), s, NEG_INF)
+                seen = keep_ref[j] != 0 if chosen else _keep(
+                    qi * block_q, j * block_k, s.shape, 1, window)
+                s = jnp.where(seen, s, NEG_INF)
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)                  # [BQ, 1]
             l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
             return m_new, l_new, acc * alpha + _dot(p.astype(v.dtype), v)
+
+        if chosen:  # a block pair with no chosen pair is stepped over
+            visit = body
+
+            def body(j, carry, masked):
+                return jax.lax.cond(
+                    tiles_ref[qi, j] != 0,
+                    functools.partial(visit, j, masked=masked),
+                    lambda carry: carry, carry)
 
         m, l, acc = _loop_blocks(bounds, MASKED, body, (
             jnp.full((block_q, 1), NEG_INF, jnp.float32),
@@ -237,13 +266,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, sm_scale: float,
-                causal: bool, window, block_q: int, head_dim: int,
-                v_head_dim: int, group: int):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                sm_scale: float, causal: bool, window, block_q: int,
+                head_dim: int, v_head_dim: int, group: int,
+                chosen: bool = False):
+    # with a choice: its transposed tiles of this kv block [nq, bk, bq], the
+    # table [nq, nk]
+    keep_ref, tiles_ref, *refs = refs if chosen else (None, None, *refs)
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
     block_k = k_ref.shape[0]
     ki, nq = pl.program_id(1), q_ref.shape[0] // block_q
     bounds = _q_range(ki, block_q, block_k, nq, causal, window)
+    if chosen:  # every block from the diagonal's on masks by its tile
+        bounds = (bounds[0], nq, nq, nq)
 
     @pl.when(ki == 0)
     def _():
@@ -262,8 +297,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         rows = pl.ds(i * block_q, block_q)
         q, do = q_ref[rows, :], do_ref[rows, :]
         if masked:
-            keep = _keep(i * block_q, ki * block_k, (block_k, block_q), 0,
-                         window)
+            keep = keep_ref[i] != 0 if chosen else _keep(
+                i * block_q, ki * block_k, (block_k, block_q), 0, window)
         # every right-hand operand is zero off head a's lanes, so the
         # heads of a block add up in one accumulator a gradient
         for a, (ka, va) in enumerate(heads):
@@ -279,6 +314,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             dk_acc[...] += _dot(dst, _only(q, q_lanes))
             dq_acc[rows, :] += _dot(dst, ka, _TN)            # [BQ, W]
         return carry  # nothing: every sum lives in a scratch ref
+
+    if chosen:  # a block pair with no chosen pair is stepped over
+        visit = body
+
+        def body(i, carry, masked):
+            pl.when(tiles_ref[i, ki] != 0)(
+                functools.partial(visit, i, carry, masked))
+            return carry
 
     _loop_blocks(bounds, MASKED, body, None)
     dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
@@ -366,26 +409,49 @@ class _Plan:
     def flat(self, t):
         return t.reshape(t.shape[0], t.shape[1] * t.shape[2])
 
+    def choice(self, keep, tiles, transposed: bool):
+        """(operands, block specs, the kernels' constant) of a choice, or
+        three empty ones without: ``keep`` [Lq, Lk] by tiles, those of a grid
+        step's row block (of its kv block, transposed, for the backward) on a
+        leading dim; the table whole in SMEM."""
+        if keep is None:
+            return (), [], {}
+        if tiles.shape != (self.nq, self.nk):
+            raise ValueError(f"a tile table of {tiles.shape} for "
+                             f"{(self.nq, self.nk)} blocks")
+        by_tile = keep.reshape(self.nq, self.bq, self.nk, self.bk)
+        if transposed:
+            spec = pl.BlockSpec((None, self.nq, self.bk, self.bq),
+                                lambda h, j: (j, 0, 0, 0))
+            by_tile = by_tile.transpose(2, 0, 3, 1)
+        else:
+            spec = pl.BlockSpec((None, self.nk, self.bq, self.bk),
+                                lambda h, i: (i, 0, 0, 0))
+            by_tile = by_tile.transpose(0, 2, 1, 3)
+        table = pl.BlockSpec(memory_space=pltpu.SMEM)
+        return (by_tile, tiles), [spec, table], {"chosen": True}
+
 
 def _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret,
-                      window=None):
+                      window=None, keep=None, tiles=None):
     """(o [L, H, Dv], lse [H, L]) of q, k [L, H, D] and v [L, H, Dv]."""
     pn = _Plan(q, k, v, block_q, block_k, causal, interpret, window)
+    choice, choice_specs, chosen = pn.choice(keep, tiles, transposed=False)
     out, lse = pn.call(
         "flash_fwd", _fwd_kernel, (pn.nh, pn.nq),
         [pn.block(pn.bq), pn.whole(pn.Lk, kv=True),
-         pn.whole(pn.Lk, kv=True, v=True)],
+         pn.whole(pn.Lk, kv=True, v=True), *choice_specs],
         [pn.block(pn.bq, v=True),
          pl.BlockSpec((None, pn.group, pn.bq), lambda h, i: (h, 0, i))],
         [jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.Dv), q.dtype),
          jax.ShapeDtypeStruct((pn.nh, pn.group, pn.Lq), jnp.float32)],
-        block_k=pn.bk,
-    )(pn.flat(q), pn.flat(k), pn.flat(v))
+        block_k=pn.bk, **chosen,
+    )(pn.flat(q), pn.flat(k), pn.flat(v), *choice)
     return out.reshape(pn.Lq, pn.H, pn.Dv), lse.reshape(pn.H, pn.Lq)
 
 
 def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
-                    interpret, window=None):
+                    interpret, window=None, keep=None, tiles=None):
     """Exact flash backward as one kernel.  Standard formulas:
 
         p_ij  = exp(s_ij - lse_i)
@@ -401,6 +467,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
     kernel a q head and are summed over the group here.
     """
     pn = _Plan(q, k, v, block_q, block_k, causal, interpret, window)
+    choice, choice_specs, chosen = pn.choice(keep, tiles, transposed=True)
     delta = (o.astype(jnp.float32) * do.astype(jnp.float32)).sum(-1).T \
         - dlse.astype(jnp.float32)                           # [H, Lq]
     ops = (pn.flat(q), pn.flat(k), pn.flat(v), pn.flat(do))
@@ -424,14 +491,15 @@ def _flash_bwd_impl(q, k, v, o, lse, do, dlse, causal, block_q, block_k,
     dq, dk, dv = pn.call(
         "flash_bwd", _bwd_kernel, (pn.nh, pn.nk),
         [whole(pn.Lq), pn.block(pn.bk, kv=True),
-         pn.block(pn.bk, kv=True, v=True), whole(pn.Lq, v=True), rows, rows],
+         pn.block(pn.bk, kv=True, v=True), whole(pn.Lq, v=True), rows, rows,
+         *choice_specs],
         [pn.whole(pn.Lq), pn.block(pn.bk), pn.block(pn.bk, v=True)],
         [flat_q, flat_k, flat_v], sequential=True,
         scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in (
             (pn.Lq, pn.W), (pn.bk, pn.W), (pn.bk, pn.Wv))],
-        block_q=pn.bq,
+        block_q=pn.bq, **chosen,
     )(*ops, *(t.reshape(pn.nh, pn.group, pn.nq, pn.bq)
-              for t in (lse, delta)))
+              for t in (lse, delta)), *choice)
     if pn.rep > 1:
         dk, dv = (t.reshape(pn.Lk, pn.H // pn.rep, pn.rep, -1).astype(
             jnp.float32).sum(axis=2).astype(k.dtype) for t in (dk, dv))
@@ -464,6 +532,31 @@ def _flash_bwd(causal, block_q, block_k, interpret, window, res, g):
 flash_attention_with_lse.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_chosen(q, k, v, keep, tiles, block, interpret):
+    """``o`` of causal attention over the pairs ``keep`` holds 1 for: the
+    softmax over a query's chosen keys.  ``keep`` and ``tiles`` are data and
+    get no gradient; q, k and v get that of the softmax over the set held
+    fixed."""
+    return _chosen_fwd(q, k, v, keep, tiles, block, interpret)[0]
+
+
+def _chosen_fwd(q, k, v, keep, tiles, block, interpret):
+    out, lse = _flash_heads_impl(q, k, v, True, block, block, interpret,
+                                 keep=keep, tiles=tiles)
+    return out, (q, k, v, keep, tiles, out, lse)
+
+
+def _chosen_bwd(block, interpret, res, do):
+    q, k, v, keep, tiles, out, lse = res
+    return (*_flash_bwd_impl(q, k, v, out, lse, do, jnp.zeros_like(lse), True,
+                             block, block, interpret, keep=keep, tiles=tiles),
+            None, None)
+
+
+_flash_chosen.defvjp(_chosen_fwd, _chosen_bwd)
+
+
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     *,
@@ -472,6 +565,8 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool = False,
     window: int | None = None,
+    keep: jax.Array | None = None,
+    tiles: jax.Array | None = None,
 ) -> jax.Array:
     """Flash attention over [L, H, D] (no batch; vmap for batches).
 
@@ -484,6 +579,11 @@ def flash_attention(
     (head size a multiple of 128): k/v head ``g`` serves q heads
     ``g * rep .. (g + 1) * rep - 1``.
 
+    ``keep`` [L, L] int8 with ``tiles`` [L / block, L / block]
+    (``ops/sparse_select.select_topk``, at ``block_q = block_k = block``): a
+    choice of keys a query, under the causal mask; a query sees the keys its
+    row holds 1 for, and a block pair the table marks empty is stepped over.
+
     Drop-in for ``parallel.ring_attention.blockwise_attention`` where
     shapes divide the block sizes.  DIFFERENTIABLE: the custom backward
     recomputes p per KV block from the kernel's saved log-sum-exp — an
@@ -491,6 +591,13 @@ def flash_attention(
     [L, L] (tests/test_flash_attention.py pins grads against dense
     attention).
     """
+    if keep is not None:
+        if tiles is None or not causal or window is not None \
+                or block_q != block_k:
+            raise ValueError("a choice of keys comes with its tile table, "
+                             "under the causal mask, with no window and "
+                             "square blocks")
+        return _flash_chosen(q, k, v, keep, tiles, block_q, interpret)
     if window is not None and window >= k.shape[0]:
         window = None  # every key a causal query sees is inside it
     out, _ = flash_attention_with_lse(
@@ -503,10 +610,10 @@ def flash_attn_fn(block_q: int = 128, block_k: int = 128,
                   interpret: bool = False):
     """Adapter matching the TransformerLM ``attn_fn`` signature."""
 
-    def attn(q, k, v, causal, window=None):
+    def attn(q, k, v, causal, window=None, keep=None, tiles=None):
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret,
-                               window=window)
+                               window=window, keep=keep, tiles=tiles)
 
     return attn
 

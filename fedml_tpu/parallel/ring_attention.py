@@ -58,7 +58,7 @@ def _merge(m1, l1, o1, m2, l2, o2):
 
 
 def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset,
-                       window=None, q_copies=1):
+                       window=None, q_copies=1, keep=None):
     """(m, l, o) partials of Q [Lq,H,D] against K/V [Lk,H,D], scanned in
     KV blocks.  Pads ragged K/V to a block multiple and masks the pad —
     the ONE shared inner loop for both the single-device and ring paths.
@@ -68,7 +68,11 @@ def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset,
     (causal only) also hides keys ``window`` or more positions behind the
     query.  ``q_copies`` says the rows of ``q`` are that many runs of the
     same positions, one after another (the q heads that share a k/v head,
-    folded into rows by ``blockwise_attention``).
+    folded into rows by ``blockwise_attention``).  ``keep`` [positions of q,
+    Lk] (int8 or bool) also hides every pair it holds 0 for, and a kv
+    block's scores and probabilities are then computed again in the backward
+    and not saved: a query with a choice sees keys all along its row, so the
+    saved blocks would be all of [H, Lq, Lk].
     """
     if window is not None and not causal:
         raise ValueError("a window is defined under the causal mask only")
@@ -80,6 +84,8 @@ def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset,
     if pad:
         k = jnp.pad(k, ((0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, pad), (0, 0), (0, 0)))
+        if keep is not None:
+            keep = jnp.pad(keep, ((0, 0), (0, pad)))
 
     qpos = q_offset + jnp.arange(Lq)
     if q_copies > 1:
@@ -100,6 +106,10 @@ def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset,
             bias = bias + jnp.where(seen, 0.0, NEG_INF)
         else:
             bias = jnp.broadcast_to(bias, (Lq, bs))
+        if keep is not None:
+            chosen = lax.dynamic_slice_in_dim(keep, i * bs, bs, axis=1) != 0
+            bias = bias + jnp.where(jnp.tile(chosen, (q_copies, 1)), 0.0,
+                                    NEG_INF)
         mb, lb, ob = _block_attn(q, kb, vb, bias.astype(q.dtype))
         return _merge(m, l, o, mb, lb, ob), None
 
@@ -111,7 +121,8 @@ def _partial_attention(q, k, v, *, causal, block_size, q_offset, kv_offset,
     l0 = zero
     o0 = jnp.zeros_like(q) if v.shape[-1] == D else jnp.broadcast_to(
         zero[..., None], (Lq, H, v.shape[-1]))  # v heads of another size
-    (m, l, o), _ = lax.scan(body, (m0, l0, o0), jnp.arange(n_blocks))
+    (m, l, o), _ = lax.scan(body if keep is None else jax.checkpoint(body),
+                            (m0, l0, o0), jnp.arange(n_blocks))
     return m, l, o
 
 
@@ -128,6 +139,7 @@ def blockwise_attention(
     q_offset: int = 0,
     kv_offset: int = 0,
     window: Optional[int] = None,
+    keep: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Exact attention over [L, H, D] tensors in KV blocks (O(L) memory).
 
@@ -137,7 +149,10 @@ def blockwise_attention(
     ``v`` may hold fewer heads than ``q``: k/v head ``g`` serves q heads
     ``g * rep .. (g + 1) * rep - 1``, which are folded into rows so that no
     repeated ``k`` or ``v`` is made.  ``v``'s head size may differ from
-    ``q``'s and ``k``'s: the output has ``v``'s.
+    ``q``'s and ``k``'s: the output has ``v``'s.  ``keep`` [Lq, Lk] (int8 or
+    bool; ``ops/sparse_select.py``): a pair it holds 0 for is hidden, whatever
+    ``causal`` and ``window`` say, for every head alike, and a kv block's
+    probabilities are computed again in the backward, not saved.
     """
     Lq, H, D = q.shape
     rep = H // k.shape[1]
@@ -149,6 +164,7 @@ def blockwise_attention(
     out = _normalize(*_partial_attention(
         q, k, v, causal=causal, block_size=block_size,
         q_offset=q_offset, kv_offset=kv_offset, window=window, q_copies=rep,
+        keep=keep,
     ))
     if rep > 1:
         out = out.reshape(rep, Lq, H // rep, -1).transpose(1, 2, 0, 3).reshape(

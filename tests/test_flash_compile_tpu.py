@@ -112,6 +112,37 @@ def test_window_and_shared_kv_heads_compile_for_v5e(one_chip, heads,
     assert "flash_fwd" in text and "flash_bwd" in text
 
 
+@pytest.mark.parametrize("heads, kv_heads", [
+    pytest.param(32, 4, id="sparse_cell_8_to_1"),
+    pytest.param(4, 4, id="a_head_each"),
+])
+def test_a_choice_of_keys_compiles_for_v5e(one_chip, heads, kv_heads):
+    """The sparse-attention layer of the ``keyevl2_silo_text8k`` cell under
+    the model's vmap: bf16 [1, 8192, 32, 128] over 4 k/v heads, the choice as
+    an [8192, 8192] int8 mask by 512-tiles and its [16, 16] table in SMEM."""
+    L, D = 8192, 128
+    block = pick_block(L, D)
+
+    def attn(q, k, v, keep, tiles):
+        return flash_attention(q, k, v, causal=True, block_q=block,
+                               block_k=block, keep=keep, tiles=tiles)
+
+    def grads(q, k, v, do, keep, tiles):
+        return jax.grad(lambda q, k, v: (
+            jax.vmap(attn)(q, k, v, keep, tiles).astype(jnp.float32)
+            * do.astype(jnp.float32)).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    text = jax.jit(grads).lower(
+        spec((1, L, heads, D)), spec((1, L, kv_heads, D)),
+        spec((1, L, kv_heads, D)), spec((1, L, heads, D)),
+        spec((1, L, L), jnp.int8),
+        spec((1, L // block, L // block), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
+
+
 def test_unequal_head_sizes_compile_for_v5e(one_chip):
     """The latent-attention layer of the ``kimilin_silo_doc8k`` cell under
     the model's vmap: 4 heads, q and k 192 wide, v 128 wide, bf16 at 8192

@@ -77,7 +77,7 @@ from fedml_tpu.models.transformer import (
 from fedml_tpu.obs import scopes
 from fedml_tpu.ops.expert_rows import from_buffer, sorted_route, to_buffer
 from fedml_tpu.ops.linear_attention import gated_delta_rule, short_causal_conv
-from fedml_tpu.ops.sparse_select import select_topk
+from fedml_tpu.ops.sparse_select import select_topk, tiles_scored
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 LINEAR, LATENT = "linear_attention", "latent_attention"
@@ -91,6 +91,9 @@ ROWS_BUFFERED = "moe_rows_buffered"  # rows of the buffer the layer took
 KDA_LOG_DECAY_MEAN = "kda_log_decay_mean"
 # a SELECTED layer's count of 512 x 512 causal tiles that hold a chosen pair
 ATTN_TILES_LIVE = "attn_tiles_live"
+# a SELECTED layer's count of tiles whose index scores were computed: the
+# causal ones where the choice ran in its kernel, every tile as lax ops
+SELECT_TILES_SCORED = "select_tiles_scored"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -666,14 +669,16 @@ def chosen_keys(attn: AttnFn, topk: int) -> AttnFn:
     ``MultiHeadAttention`` calls the attention function of a layer with an
     ``indexer``: ``index`` = one example's (qI, kI, w).  The choice
     (``select_topk``) goes to ``attn`` as ``keep=`` and ``tiles=``; beside
-    the output comes the count of tiles that hold a chosen pair."""
+    the output come the count of tiles that hold a chosen pair and the count
+    of tiles the choice scored."""
     def fn(q, k, v, causal, index):
         with jax.named_scope(scopes.ATTN_SELECT):
             keep, tiles = select_topk(*index, topk)
             live = tiles.sum().astype(jnp.float32)
+            scored = jnp.float32(tiles_scored(*index[:2]))
         with jax.named_scope(scopes.ATTN_SPARSE):
             out = attn(q, k, v, causal, keep=keep, tiles=tiles)
-        return out, {ATTN_TILES_LIVE: live}
+        return out, {ATTN_TILES_LIVE: live, SELECT_TILES_SCORED: scored}
 
     return fn
 

@@ -143,6 +143,38 @@ def test_a_choice_of_keys_compiles_for_v5e(one_chip, heads, kv_heads):
     assert "flash_fwd" in text and "flash_bwd" in text
 
 
+def test_the_choice_of_keys_is_one_kernel_on_a_v5e(one_chip, monkeypatch):
+    """``select_topk`` of the ``keyevl2_silo_text8k`` cell under the model's
+    vmap: bf16 index queries [1, 8192, 16, 64] against one index key a token,
+    ``topk`` 2048.  Where the shape test reads a TPU it is one Mosaic kernel,
+    ``select_topk``, whose row block keeps its [512, 8192] images, a head's
+    weights along the lanes and two buffers of its int8 mask in VMEM; no
+    ``while`` over row blocks and no [512, 16, 8192] product is left."""
+    from fedml_tpu.ops import sparse_select as ss
+
+    L, heads, dim, topk = 8192, 16, 64, 2048
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    qI, kI = spec((1, L, heads, dim)), spec((1, L, dim))
+    assert ss.kernel_tiles(*jax.eval_shape(lambda q, k: (q[0], k[0]), qI, kI))
+    compiled = jax.jit(jax.vmap(
+        lambda *i: ss.select_topk(*i, topk))).lower(
+        qI, kI, spec((1, L, heads), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert sum("tpu_custom_call" in line and "select_topk" in line
+               for line in text.splitlines()) == 1
+    assert " while(" not in text and f"[512,{heads},{L}]" not in text
+    keep, tiles = jax.eval_shape(
+        jax.vmap(lambda *i: ss.select_topk(*i, topk)), qI, kI,
+        spec((1, L, heads), jnp.float32))
+    assert (keep.shape, keep.dtype) == ((1, L, L), jnp.int8)
+    assert (tiles.shape, tiles.dtype) == ((1, 16, 16), jnp.int32)
+    # the operands' relayouts beside the kernel: qI by columns, the keys twice
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05 * 2**30
+
+
 def test_unequal_head_sizes_compile_for_v5e(one_chip):
     """The latent-attention layer of the ``kimilin_silo_doc8k`` cell under
     the model's vmap: 4 heads, q and k 192 wide, v 128 wide, bf16 at 8192

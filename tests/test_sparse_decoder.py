@@ -18,7 +18,8 @@ from benchmark.families import keye_sparse_plain as plain  # noqa: E402
 from fedml_tpu.models import decoder  # noqa: E402
 from fedml_tpu.models.base import COUNTERS  # noqa: E402
 from fedml_tpu.models.decoder import (  # noqa: E402
-    ATTN_TILES_LIVE, SELECTED, DecoderBlock, DecoderConfig, decoder_lm,
+    ATTN_TILES_LIVE, SELECT_TILES_SCORED, SELECTED, DecoderBlock,
+    DecoderConfig, decoder_lm,
 )
 from fedml_tpu.ops import sparse_select  # noqa: E402
 
@@ -217,7 +218,40 @@ def test_tiles_live_counts_the_tiles_that_hold_a_chosen_pair():
         axis=(2, 4)).sum()) for c in chosen)
     assert 2 * 2 * 2 <= by_hand <= 2 * 2 * 3
     assert float(new_vars[COUNTERS][ATTN_TILES_LIVE]) == by_hand
+    # the lax form scored every tile: 2 sequences x 2 layers x 2 x 2
+    assert float(new_vars[COUNTERS][SELECT_TILES_SCORED]) == 2 * 2 * 4
     assert set(variables) == {"params"}
+
+
+@pytest.mark.parametrize("in_kernel, scored", [(True, 3), (False, 4)])
+def test_tiles_scored_says_which_form_of_the_choice_ran(monkeypatch,
+                                                        in_kernel, scored):
+    """``chosen_keys`` counts the tiles ``select_topk`` scores for its
+    operands: the causal ones where the shape test sends the call to the
+    kernel, all of them as lax ops; summed over sequences like the live
+    tiles."""
+    monkeypatch.setattr(sparse_select, "kernel_tiles",
+                        lambda *a, **k: in_kernel)
+    monkeypatch.setattr(
+        sparse_select, "select_in_kernel",
+        lambda *i: sparse_select.select_in_lax(*i))
+    calls = []
+
+    def attn(q, k, v, causal, keep, tiles):
+        calls.append(tiles.shape)
+        return v
+
+    index = (jnp.ones((3, 1024, 2, 4)), jnp.ones((3, 1024, 4)),
+             jnp.ones((3, 1024, 2)))
+    v = jnp.zeros((3, 1024, 1, 4))
+    _, scalars = jax.vmap(
+        lambda v, i: decoder.chosen_keys(attn, 8)(v, v, v, True, i)
+    )(v, index)
+    assert calls == [(2, 2)]
+    assert scalars[SELECT_TILES_SCORED].dtype == jnp.float32
+    assert float(scalars[SELECT_TILES_SCORED].sum()) == 3 * scored
+    # equal scores everywhere: a row's 8 keys are the first 8 positions
+    assert float(scalars[ATTN_TILES_LIVE].sum()) == 3 * 2
 
 
 def round_of(cfg):
@@ -244,6 +278,7 @@ def test_the_counter_leaves_with_the_metrics_and_the_indexer_stays_put():
     # one tile a sequence and layer at 32 positions:
     # 2 clients x 2 steps x 2 sequences x 2 layers
     assert float(metrics[ATTN_TILES_LIVE][0]) == 2 * 2 * 2 * 2
+    assert float(metrics[SELECT_TILES_SCORED][0]) == 2 * 2 * 2 * 2
     assert float(metrics["count"][0]) == 2 * 2 * 2 * 32
     old = state.variables["params"]["Block_0"]["MultiHeadAttention_0"]
     new = new_state.variables["params"]["Block_0"]["MultiHeadAttention_0"]
@@ -266,7 +301,7 @@ def test_one_round_agrees_with_the_benchmark_reference_through_the_driver():
     assert isinstance(session.bundle, plain.PlainBundle)
     rounds, metrics = session.call()
     assert rounds == 1 and bench_run.call_ok(metrics, session.cohort)
-    assert ATTN_TILES_LIVE in metrics
+    assert ATTN_TILES_LIVE in metrics and SELECT_TILES_SCORED in metrics
     agreement = bench_run.check_reference(cell, session, 11)
     assert agreement["ok"], agreement
     assert agreement["delta_rel_l2"] < 0.01 and agreement["loss_rel"] < 1e-5

@@ -33,14 +33,34 @@ Mixer kinds (``DecoderConfig.layer_types``, one a layer):
 Any softmax kind RMS-norms its q and k heads before the rotation where the
 configuration says ``qk_norm``.  Rotary positions come from ``rope_parameters``
 or from a top-level ``rope_theta`` (with ``rope_scaling``: an ``mrope_section``
-block is plain RoPE on text, whose three position streams coincide).
+block is plain RoPE on text, whose three position streams coincide); a
+top-level ``rope_theta`` rotates every softmax kind unless ``rope_layer_types``
+names the kinds it rotates, and a kind left out has no positional encoding
+(``DecoderConfig.rope`` has no entry for it).
+
+What a block may have besides, each a field of ``DecoderConfig`` that is off
+unless the configuration's keys state it (``from_dict`` says which), and with
+all of them off the parameter tree and the program are what they were:
+``attn_gate``, a sigmoid gate of the layer's normed input on the attention
+output before the output projection (``MultiHeadAttention``'s ``gate``);
+``post_norm``, an RMSNorm **after** the mixer and after the MLP, inside the
+residual branch (``x + norm(mixer(norm(x)))``: four norms a layer, the two new
+ones named ``post_attn_norm`` and ``post_mlp_norm``, their weights starting at
+``POST_NORM_INIT``);
+``embed_scale``, a factor on the embedding's output (the function's own: the
+table's gradient carries it, and the table's initial std is its inverse, so
+that the scaled output has unit variance); ``selection_bias``, a leaf
+``[routed]`` of the expert layer that shifts which experts a token chooses and
+not how it weighs them, outside the gradient.
 
 MLP kinds (``mlp_types``): ``sparse``, the expert layer below, with a shared
 expert (a ``GatedMLP`` every token passes) added outside the routed sum where
 the configuration has one; ``dense``, one ``GatedMLP``.  Router kinds
 (``router_activation``): ``softmax`` over all routed experts, or ``sigmoid``
 scores; either renormalises the chosen ``top_k`` where ``norm_topk_prob``
-and scales them by ``routed_scaling_factor``.
+and scales them by ``routed_scaling_factor``.  With a ``selection_bias``
+the ``top_k`` are the largest of ``scores + bias`` and the weights come from
+the scores without it.
 
 The expert layer (``ExpertLayer``) is told which experts it holds.  The
 router keeps its full width and picks ``top_k`` of all experts; this
@@ -94,6 +114,16 @@ ATTN_TILES_LIVE = "attn_tiles_live"
 # a SELECTED layer's count of tiles whose index scores were computed: the
 # causal ones where the choice ran in its kernel, every tile as lax ops
 SELECT_TILES_SCORED = "select_tiles_scored"
+# an expert layer with a selection bias: tokens whose chosen set is not the
+# ``top_k`` largest of the scores alone
+TOKENS_BIAS_MOVED = "moe_tokens_bias_moved"
+# what the weights of the norms after the sublayers start from, not 1: a
+# fresh attention's output is close to one mean of ``v`` for every token, and
+# a norm whose weight is 1 makes that a common component of unit size in
+# every token's stream, under which a fresh router sends some experts
+# several times their share (measured: PERF.md §6, PR 41); a tenth is about
+# what a fresh sublayer without such a norm adds to the stream
+POST_NORM_INIT = 0.1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,6 +165,13 @@ class DecoderConfig:
     indexer_heads: int = 0
     indexer_head_dim: int = 0
     index_topk: int = 0
+    attn_gate: bool = False  # a sigmoid gate on the attention output
+    post_norm: bool = False  # an RMSNorm after the mixer and after the MLP
+    # a factor on the embedding's output; the table's std is its inverse
+    embed_scale: float = 1.0
+    # the router's selection bias: None, no leaf; else the std of its initial
+    # values (a leaf [routed], added to the scores for the choice alone)
+    selection_bias: Optional[float] = None
 
     @classmethod
     def from_dict(cls, c: dict) -> "DecoderConfig":
@@ -145,10 +182,23 @@ class DecoderConfig:
         set), from ``layer_types``, cycled to the depth, or, where the
         configuration carries ``sa_config`` and no ``layer_types``, is SELECTED
         throughout; its MLP from
-        ``mlp_layer_types`` or from ``first_k_dense_replace`` leading dense
-        layers.  ``num_experts_routed`` defaults to ``num_experts``,
-        ``experts_held`` to the first ``num_experts`` ids, and
-        ``linear_attn_heads`` to ``linear_attn_config.num_heads``."""
+        ``mlp_layer_types`` or from ``first_k_dense_replace`` (or
+        ``num_dense_layers``) leading dense layers.  ``num_experts_routed``
+        defaults to ``num_experts``, ``experts_held`` to the first
+        ``num_experts`` ids, and ``linear_attn_heads`` to
+        ``linear_attn_config.num_heads``.
+
+        The router reads either dialect of its keys:
+        ``moe_router_activation_func`` or ``score_func``; ``norm_topk_prob``,
+        ``moe_renormalize`` or ``route_norm``; ``routed_scaling_factor`` or
+        ``route_scale``; experts in groups (``n_group`` / ``topk_group``
+        other than 1) are refused.
+        ``selection_bias_init_std`` states a selection bias and the std its
+        leaf starts from.  ``mup_enabled`` scales the embedding's output by
+        ``sqrt(hidden_size)``.  ``attention_gate`` switches the gate on
+        the attention output, ``post_norm`` the norms after the sublayers;
+        ``rope_layer_types`` names the layer kinds a top-level ``rope_theta``
+        rotates (default: every softmax kind)."""
         depth = c.get("n_layer", c.get("num_hidden_layers"))
         linear = c.get("linear_attn_config") or {}
         if linear:
@@ -158,12 +208,17 @@ class DecoderConfig:
         else:
             kinds = c.get("layer_types") or [
                 SELECTED if c.get("sa_config") else FULL]
+        leading = c.get("first_k_dense_replace", c.get("num_dense_layers", 0))
         mlp = c.get("mlp_layer_types") or [
-            DENSE if i < c.get("first_k_dense_replace", 0) else SPARSE
-            for i in range(depth)]
+            DENSE if i < leading else SPARSE for i in range(depth)]
         if not set(mlp) <= {SPARSE, DENSE}:
             raise ValueError(f"MLP kinds {sorted(set(mlp))}: only "
                              f"{SPARSE!r} and {DENSE!r} are built")
+        if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
+            raise ValueError(
+                f"n_group {c.get('n_group')} / topk_group "
+                f"{c.get('topk_group')}: a choice among groups of experts is "
+                "not built, only a plain top-k (both 1)")
         routed = c.get("num_experts_routed", c["num_experts"])
         held = tuple(c.get("experts_held", range(c["num_experts"])))
         if len(held) != c["num_experts"] or not all(
@@ -181,8 +236,9 @@ class DecoderConfig:
                                  "'default' is read from the top-level keys")
             rope = {"rope_type": "default", "rope_theta": c["rope_theta"]}
         # none: no positional encoding
-        if "rope_type" in rope:  # one block for every layer type
-            rope = {SLIDING: rope, FULL: rope, SELECTED: rope}
+        if "rope_type" in rope:  # one block for every layer type it rotates
+            rope = {kind: rope for kind in c.get(
+                "rope_layer_types", (SLIDING, FULL, SELECTED))}
         sparse = c.get("sa_config") or {}
         return cls(
             vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
@@ -199,16 +255,18 @@ class DecoderConfig:
             moe_intermediate_size=c["moe_intermediate_size"],
             num_experts_routed=routed, experts_held=held,
             top_k=c.get("num_experts_per_tok", c.get("num_experts_per_token")),
-            norm_topk_prob=c.get("norm_topk_prob",
-                                 c.get("moe_renormalize", True)),
+            norm_topk_prob=c.get("norm_topk_prob", c.get(
+                "moe_renormalize", c.get("route_norm", True))),
             max_len=c.get("n_positions", c.get("max_position_embeddings")),
             remat=c.get("remat", False),
             mlp_types=tuple(mlp[i % len(mlp)] for i in range(depth)),
             intermediate_size=c.get("intermediate_size", 0),
             shared_expert_size=(c.get("num_shared_experts", 0)
                                 * c["moe_intermediate_size"]),
-            router_activation=c.get("moe_router_activation_func", "softmax"),
-            routed_scaling_factor=c.get("routed_scaling_factor", 1.0),
+            router_activation=c.get("moe_router_activation_func",
+                                    c.get("score_func", "softmax")),
+            routed_scaling_factor=c.get("routed_scaling_factor",
+                                        c.get("route_scale", 1.0)),
             linear_heads=c.get("linear_attn_heads", linear.get("num_heads", 0)),
             linear_head_dim=linear.get("head_dim", 0),
             conv_kernel=linear.get("short_conv_kernel_size", 4),
@@ -221,6 +279,11 @@ class DecoderConfig:
             indexer_heads=sparse.get("indexer_num_heads", 0),
             indexer_head_dim=sparse.get("indexer_head_dim", 0),
             index_topk=sparse.get("topk", 0),
+            attn_gate=c.get("attention_gate", False),
+            post_norm=c.get("post_norm", False),
+            embed_scale=(math.sqrt(c["hidden_size"])
+                         if c.get("mup_enabled") else 1.0),
+            selection_bias=c.get("selection_bias_init_std"),
         )
 
 
@@ -481,6 +544,16 @@ class ExpertLayer(nn.Module):
     the ``top_k`` largest are chosen, renormalised to sum to 1 where
     ``norm_topk_prob`` and multiplied by ``routed_scaling_factor``.
 
+    ``selection_bias`` (None: none, and no leaf) makes the choice the
+    ``top_k`` largest of ``scores + bias``, ``bias`` a leaf [routed] whose
+    initial values are normal with that std, while the weights stay the
+    chosen experts' scores **without** it.  The bias is outside the gradient:
+    it enters under ``stop_gradient`` and only through the discrete choice,
+    so the loss gives its leaf exactly zero (a rule of the model's own would
+    move it between steps; none is built).  A fourth counter then counts the
+    tokens whose chosen set is not the ``top_k`` largest of the scores alone
+    (one whose least chosen score is under its greatest unchosen one).
+
     The row buffer is as long as ``buffer_capacities`` says.  Where the short
     one is shorter than the worst case, a ``lax.cond`` on this call's count of
     routed rows (``_expert_rows_fitted``) takes it when the count fits, and
@@ -512,6 +585,7 @@ class ExpertLayer(nn.Module):
     norm_topk_prob: bool = True
     router_activation: str = "softmax"  # or "sigmoid": a score an expert
     routed_scaling_factor: float = 1.0  # on the chosen experts' weights
+    selection_bias: Optional[float] = None  # the std of the bias leaf's init
 
     @nn.compact
     def __call__(self, x):
@@ -538,7 +612,23 @@ class ExpertLayer(nn.Module):
             else:
                 raise ValueError(
                     f"unknown router activation {self.router_activation!r}")
-            top_p, top_e = jax.lax.top_k(scores, k)
+            moved = None
+            if self.selection_bias is None:
+                top_p, top_e = jax.lax.top_k(scores, k)
+            else:
+                bias = self.param(
+                    "selection_bias",
+                    nn.initializers.normal(self.selection_bias),
+                    (self.num_experts_routed,))
+                shifted = scores + jax.lax.stop_gradient(
+                    bias.astype(jnp.float32))
+                kth, top_e = jax.lax.top_k(shifted, k)
+                top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+                # moved: the least score chosen is under the greatest not
+                chosen = shifted >= kth[:, -1:]
+                moved = (jnp.where(chosen, scores, jnp.inf).min(axis=-1)
+                         < jnp.where(chosen, -jnp.inf, scores).max(axis=-1)
+                         ).sum().astype(jnp.float32)
             if self.norm_topk_prob:
                 top_p = top_p / top_p.sum(axis=-1, keepdims=True)
             if self.routed_scaling_factor != 1.0:
@@ -573,6 +663,8 @@ class ExpertLayer(nn.Module):
             EXPERT_TOKENS_MAX: group_sizes.max().astype(jnp.float32),
             ROWS_BUFFERED: jnp.asarray(buffered, jnp.float32),
         }
+        if moved is not None:
+            counters[TOKENS_BIAS_MOVED] = moved
         return y.astype(w_down.dtype).reshape(B, L, h), counters
 
 
@@ -791,7 +883,7 @@ class DecoderBlock(nn.Module):
         shared = dict(
             num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
             rope_fn=rope_fn(c.head_dim),
-            qk_norm=c.rms_norm_eps if c.qk_norm else None)
+            qk_norm=c.rms_norm_eps if c.qk_norm else None, gate=c.attn_gate)
         if self.kind == SELECTED:
             return MultiHeadAttention(
                 c.num_heads, attn_fn=chosen_keys(attn_fn, c.index_topk),
@@ -809,25 +901,35 @@ class DecoderBlock(nn.Module):
         c = self.cfg
         with jax.named_scope(scopes.NORM):
             a = RMSNorm(c.rms_norm_eps)(x).astype(x.dtype)
+
+        def after(y, name):
+            """The sublayer's output through its own norm, where a block has
+            one, before the residual add."""
+            if not c.post_norm:
+                return y
+            with jax.named_scope(scopes.NORM):
+                return RMSNorm(c.rms_norm_eps, POST_NORM_INIT, name=name)(
+                    y).astype(x.dtype)
+
         y, counters = self.mixer(a)
-        x = x + y
+        x = x + after(y, "post_attn_norm")
         with jax.named_scope(scopes.NORM):
             b = RMSNorm(c.rms_norm_eps)(x)
         if self.mlp == DENSE:
             with jax.named_scope(scopes.MLP_DENSE):
                 y = GatedMLP(c.intermediate_size, name="mlp")(b.astype(x.dtype))
-            return x + y, counters
+            return x + after(y, "post_mlp_norm"), counters
         y, routed = ExpertLayer(
             c.num_experts_routed, c.experts_held, c.top_k,
             c.moe_intermediate_size, c.norm_topk_prob, c.router_activation,
-            c.routed_scaling_factor,
+            c.routed_scaling_factor, c.selection_bias,
         )(b)
         y = y.astype(x.dtype)
         if c.shared_expert_size:
             with jax.named_scope(scopes.MOE_SHARED):
                 y = y + GatedMLP(c.shared_expert_size, name="shared_expert")(
                     b.astype(x.dtype))
-        return x + y, {**counters, **routed}
+        return x + after(y, "post_mlp_norm"), {**counters, **routed}
 
 
 class DecoderLM(nn.Module):
@@ -844,11 +946,15 @@ class DecoderLM(nn.Module):
         # flax's, a fresh model's attention output, a running mean of v that
         # neighbouring tokens share, outweighs the token's own embedding;
         # every router then sees one common input and a few experts take
-        # nearly every token (measured: PERF.md §6, PR 32)
+        # nearly every token (measured: PERF.md §6, PR 32).  Where the
+        # configuration scales the embedding's output, the table starts at
+        # the inverse of the factor, and the scaled output has unit variance
         with jax.named_scope(scopes.EMBED):
             h = nn.Embed(c.vocab_size, c.hidden_size, name="wte",
-                         embedding_init=nn.initializers.normal(1.0))(
-                x.astype(jnp.int32))
+                         embedding_init=nn.initializers.normal(
+                             1.0 / c.embed_scale))(x.astype(jnp.int32))
+            if c.embed_scale != 1.0:
+                h = (h.astype(jnp.float32) * c.embed_scale).astype(h.dtype)
         block_cls = nn.remat(DecoderBlock) if c.remat else DecoderBlock
         totals = {}
         for i, kind in enumerate(c.layer_types):

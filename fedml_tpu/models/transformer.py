@@ -117,13 +117,15 @@ def scoped(attn: AttnFn, name: str) -> AttnFn:
 
 class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2) + eps) * g``, computed and returned in float32
-    (the caller rounds it where it wants to)."""
+    (the caller rounds it where it wants to); ``g`` starts at ``init``."""
 
     eps: float = 1e-6
+    init: float = 1.0
 
     @nn.compact
     def __call__(self, x):
-        g = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        g = self.param("scale", nn.initializers.constant(self.init),
+                       (x.shape[-1],))
         x = x.astype(jnp.float32)
         var = jnp.mean(x * x, axis=-1, keepdims=True)
         return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
@@ -146,7 +148,16 @@ class MultiHeadAttention(nn.Module):
     its own, makes the layer one whose keys are chosen from the data: its
     per-example output reaches ``attn_fn`` inside the ``vmap`` as ``index=``,
     ``attn_fn`` returns (output, {name: scalar}) and so does this module, the
-    scalars summed over the batch."""
+    scalars summed over the batch.
+
+    ``gate`` (off by default) makes the attention gated: a fourth projection
+    of the layer's input, ``z = x Wz`` as [B, L, H, Dv] (a ``Dense`` named
+    ``gate``, no bias, of its own: not part of the fused q/k/v), whose sigmoid
+    multiplies the attention function's output element by element, a head and
+    channel at a time, before the output projection:
+    ``y = (concat_i(o_i) * sigmoid(z)) Wo``.  The sigmoid and the multiply
+    run in float32 outside the ``vmap``, under ``model.attn_gate`` with the
+    projection; with the gate off the module is what it was, op for op."""
 
     num_heads: int
     attn_fn: Optional[AttnFn] = None
@@ -158,6 +169,7 @@ class MultiHeadAttention(nn.Module):
     qkv: Optional[nn.Module] = None
     qk_norm: Optional[float] = None
     indexer: Optional[nn.Module] = None
+    gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -190,6 +202,11 @@ class MultiHeadAttention(nn.Module):
             sums = {n: s.sum() for n, s in scalars.items()}
         else:
             out = jax.vmap(lambda a, b, c: attn(a, b, c, self.causal))(q, k, v)
+        if self.gate:
+            with jax.named_scope(scopes.ATTN_GATE):
+                z = nn.Dense(H * out.shape[-1], use_bias=False, name="gate")(x)
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    z.astype(jnp.float32).reshape(out.shape))).astype(x.dtype)
         with jax.named_scope(scopes.ATTN_PROJ):
             y = nn.Dense(E, use_bias=False)(
                 out.reshape(B, L, H * out.shape[-1]))

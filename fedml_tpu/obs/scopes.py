@@ -51,13 +51,15 @@ KDA_SCAN = "model.kda_scan"  # ops.linear_attention.gated_delta_rule and nothing
 KDA_OUT = "model.kda_out"  # the gated norm a head and the output projection
 EMBED = "model.embed"  # the token embedding lookup (and wpe and its add); its backward is the scatter-add
 ATTN_PROJ = "model.attn_proj"  # MultiHeadAttention's fused q/k/v Dense with its split, and its output Dense
-NORM = "model.norm"  # the norms before the mixer and the MLP and the final norm, at their call sites
+NORM = "model.norm"  # the norms before (and, where a block has them, after) the mixer and the MLP and the final norm, at their call sites
 HEAD = "model.head"  # the vocabulary head's product (tok.attend, lm_head) and nothing else
 ATTN_INDEXER = "model.attn_indexer"  # a sparse layer's three index projections, the index key's LayerNorm, the rotation of qI and kI
 ATTN_SELECT = "model.attn_select"  # index scores, the choice of keys, the tile table, and nothing else
 ATTN_SPARSE = "model.attn_sparse"  # the attention function of a layer whose keys are chosen: kernels or lax
+ATTN_GATE = "model.attn_gate"  # a gated attention's fourth projection, its sigmoid and the multiply of the attention output, and nothing else
 
 MODEL_SCOPES = (ROPE, ATTN_SLIDING, ATTN_FULL, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_COMBINE, MOE_SHARED, MLP_DENSE, ATTN_LATENT,
                 MLA_PROJ, KDA_PROJ, KDA_GATES, KDA_SCAN, KDA_OUT, EMBED,
-                ATTN_PROJ, NORM, HEAD, ATTN_INDEXER, ATTN_SELECT, ATTN_SPARSE)
+                ATTN_PROJ, NORM, HEAD, ATTN_INDEXER, ATTN_SELECT, ATTN_SPARSE,
+                ATTN_GATE)

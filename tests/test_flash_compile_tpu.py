@@ -80,11 +80,16 @@ def test_forward_and_backward_compile_for_v5e(one_chip, shape, dtype):
 
 # (q heads, k/v heads, window) at [1, 8192, ., 128] bf16 under the model's
 # vmap: the decoder cell's sliding and full layers (4 q heads share one k/v
-# head, window 1024), and a window that is no multiple of the block
+# head, window 1024), a window that is no multiple of the block, and the
+# ``trinitymini_silo_chat8k`` cell's layers (8 q heads share each of 4 k/v
+# heads: a window of 2048 = 4 blocks of 512 on its sliding layers, none on
+# its full one)
 WINDOWED = [
     pytest.param(4, 1, 1024, id="decoder_cell_sliding"),
     pytest.param(4, 1, None, id="decoder_cell_full"),
     pytest.param(8, 2, 1000, id="window_off_the_blocks"),
+    pytest.param(32, 4, 2048, id="gated_cell_sliding_8_to_1"),
+    pytest.param(32, 4, None, id="gated_cell_full_8_to_1"),
 ]
 
 

@@ -22,11 +22,13 @@ from benchmark import (  # noqa: E402
 )
 from fedml_tpu.obs import scopes  # noqa: E402
 
+from test_afmoe_decoder import SHARE as AFMOE_TOY  # noqa: E402
 from test_decoder import SHARE as MELLUM_TOY  # noqa: E402
 from test_decoder_kinds import SHARE as KIMI_TOY  # noqa: E402
 
 NEW = {scopes.EMBED, scopes.ATTN_PROJ, scopes.NORM, scopes.HEAD}
-CONFIGS = ["gpt2-large", "mellum2-12b-a2.5b", "kimi-linear-48b-a3b"]
+CONFIGS = ["gpt2-large", "mellum2-12b-a2.5b", "kimi-linear-48b-a3b",
+           "trinity-mini"]
 GPT2L = ["gpt2l_silo_fused", "gpt2l_silo_spmd4"]
 MELLUM, KIMI = "mellum2_silo_code8k", "kimilin_silo_doc8k"
 # metric: (the scope its reader names, layer, better, cells whose traces hold
@@ -56,8 +58,8 @@ def toy_bundle(family):
                               num_layers=2, seq_len=32)
     from fedml_tpu.models.decoder import decoder_lm
 
-    return decoder_lm({"mellum_moe": MELLUM_TOY, "kimi_linear": KIMI_TOY}[
-        family])
+    return decoder_lm({"mellum_moe": MELLUM_TOY, "kimi_linear": KIMI_TOY,
+                       "afmoe": AFMOE_TOY}[family])
 
 
 def lower_round(bundle):
@@ -107,6 +109,16 @@ MELLUM_BLOCK = EXPERTS + ["MultiHeadAttention_0/Dense_0/kernel 32x128",
                           ] + LAYER_NORMS
 DECODER_TOP = ["lm_head/kernel 32x64", "norm_f/scale 32",
                "wte/embedding 64x32"]
+# PR 41's family: a gate beside the fused q/k/v, q/k head norms, a norm after
+# each sublayer, a selection bias the router's width, 4 of 8 experts held
+GATED = [f"MultiHeadAttention_0/{leaf}" for leaf in (
+    "Dense_0/kernel 32x80", "Dense_1/kernel 64x32", "gate/kernel 32x64",
+    "k_norm/scale 8", "q_norm/scale 8")]
+POST_NORMS = ["post_attn_norm/scale 32", "post_mlp_norm/scale 32"]
+BIASED_EXPERTS = ["ExpertLayer_0/down 4x24x32", "ExpertLayer_0/gate 4x32x24",
+                  "ExpertLayer_0/router 32x8",
+                  "ExpertLayer_0/selection_bias 8",
+                  "ExpertLayer_0/up 4x32x24"]
 PINNED = {
     "transformer_lm": (
         2 * [GPT_BLOCK], ["ln_f/bias 32", "ln_f/scale 32",
@@ -117,13 +129,20 @@ PINNED = {
         + 2 * [EXPERTS + KDA + LAYER_NORMS + SHARED]
         + [EXPERTS + MLA + LAYER_NORMS + SHARED]
         + [EXPERTS + KDA + LAYER_NORMS + SHARED], DECODER_TOP),
+    "afmoe": (
+        [GATED + LAYER_NORMS + DENSE_MLP + POST_NORMS]
+        + 4 * [BIASED_EXPERTS + GATED + LAYER_NORMS + POST_NORMS + SHARED],
+        DECODER_TOP),
 }
 # the parts each toy model has: Mellum has no dense MLP
 PARTS = {"transformer_lm": NEW | {scopes.MLP_DENSE, scopes.ATTN_FULL},
          "mellum_moe": NEW | {scopes.ATTN_FULL, scopes.ATTN_SLIDING},
-         "kimi_linear": NEW | {scopes.MLP_DENSE}}
+         "kimi_linear": NEW | {scopes.MLP_DENSE},
+         "afmoe": NEW | {scopes.MLP_DENSE, scopes.ATTN_GATE,
+                         scopes.ATTN_FULL, scopes.ATTN_SLIDING,
+                         scopes.MOE_SHARED}}
 HEAD_MODULE = {"transformer_lm": "wte.attend", "mellum_moe": "lm_head",
-               "kimi_linear": "lm_head"}
+               "kimi_linear": "lm_head", "afmoe": "lm_head"}
 
 
 @pytest.mark.parametrize("family", sorted(PINNED))
@@ -291,7 +310,7 @@ def test_no_shared_reader_names_a_family():
     shipped = {f[:-3] for f in os.listdir(os.path.join(
         root, "dense_products")) if f != "__init__.py" and f.endswith(".py")}
     assert shipped <= families and {"transformer_lm", "mellum_moe",
-                                    "kimi_linear"} <= shipped
+                                    "kimi_linear", "afmoe"} <= shipped
     for path in ["dense_groups.py", "model_scopes.py",
                  os.path.join("tools", "matmul_table.py")] + [
             os.path.join("layer_metrics", f"{m}.py") for m in METRICS]:

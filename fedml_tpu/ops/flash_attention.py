@@ -29,8 +29,17 @@ are rounded to the input dtype only as matmul operands.
 
 Grids (a ``vmap`` prepends its batch axis).  ``flash_fwd`` runs (head
 blocks, q blocks) with that head block's k and v whole in VMEM, and walks
-the kv blocks in the kernel's own loop.  ``flash_bwd`` runs (head blocks,
-kv blocks) with q and dO whole in VMEM (in one buffer each where two
+the kv blocks in the kernel's own loop.  Its running max ``m``, sum ``l``
+and accumulator ``acc`` are float32 VMEM scratch that each block pair
+updates in place, as the backward's sums are: ``m`` and ``l`` [block, 128]
+with every lane alike, so that they broadcast over the score block's and
+the accumulator's lanes as whole vregs, ``acc`` [block, Wv]; each head of
+the column block resets them.  A loop carry of the three cost a copy a
+block pair, and a branch that must yield it (a choice's per-tile skip) a
+copy in and out: at [8192, 32 q heads on 4, 128] the forward over chosen
+keys took 7.45 ms and the causal one 5.02, in scratch 4.55 and 4.04
+(PERF.md §6, PR 42).  ``flash_bwd`` runs
+(head blocks, kv blocks) with q and dO whole in VMEM (in one buffer each where two
 would not fit: their block changes only with the head block), walks the
 q blocks, and computes ``s``, ``p``, ``dp`` and ``ds`` once a block pair
 for all three gradients: five matmuls and one ``exp`` pass.  It works on
@@ -148,22 +157,25 @@ def _keep(row0, col0, shape, kv_axis: int, window=None):
     return (kpos <= qpos) & (qpos - kpos < window)
 
 
-def _col_to_row(col):
-    """[n, 1] -> [1, n]."""
-    return jnp.broadcast_to(col, (col.shape[0], LANES)).T[:1]
+def _wide(x, width):
+    """[n, LANES] with every lane alike -> [n, width]: whole copies of its
+    vregs, no lane broadcast (a slice where a toy block is narrower)."""
+    if width > LANES:
+        x = jnp.tile(x, (1, -(-width // LANES)))
+    return x if x.shape[1] == width else x[:, :width]
 
 
-def _loop_blocks(bounds, masked, body, carry):
-    """Run ``body(j, carry, masked=m)`` over the consecutive block ranges
+def _loop_blocks(bounds, masked, body):
+    """Run ``body(j, masked=m)`` over the consecutive block ranges
     [bounds[i], bounds[i + 1]) with ``m = masked[i]``: the blocks the
     causal diagonal or a window's edge crosses apply the mask, wholly
-    visible ones skip it, wholly masked ones are in no range."""
+    visible ones skip it, wholly masked ones are in no range.  ``body``
+    returns nothing: every running sum lives in a scratch ref."""
     for lo, hi, m in zip(bounds, bounds[1:], masked):
         if isinstance(lo, int) and isinstance(hi, int) and lo == hi:
             continue  # no causal mask: the masked range is empty
-        carry = jax.lax.fori_loop(
-            lo, hi, functools.partial(body, masked=m), carry)
-    return carry
+        jax.lax.fori_loop(
+            lo, hi, lambda j, carry, m=m: body(j, masked=m), None)
 
 
 # how ``_loop_blocks`` masks the three ranges a walk is cut into: the
@@ -215,8 +227,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale: float,
                 causal: bool, window, block_k: int, head_dim: int,
                 v_head_dim: int, group: int, chosen: bool = False):
     # with a choice: its tiles of this q block [nk, bq, bk], the table [nq, nk]
-    keep_ref, tiles_ref, o_ref, lse_ref = refs if chosen else (None, None,
-                                                               *refs)
+    keep_ref, tiles_ref, *refs = refs if chosen else (None, None, *refs)
+    # the running max and sum [bq, 128] (every lane alike) and the
+    # accumulator [bq, Wv], float32 scratch a head block's head at a time
+    o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     block_q, width = o_ref.shape
     qi, nk = pl.program_id(1), k_ref.shape[0] // block_k
     bounds = _kv_range(qi, block_q, block_k, nk, causal, window)
@@ -228,9 +242,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale: float,
         q_lanes, lanes = _lanes_of(q.shape, o_ref.shape, a, head_dim,
                                    v_head_dim, group)
         qa = _only(q, q_lanes)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-        def body(j, carry, masked):
-            m_prev, l_prev, acc = carry
+        def body(j, masked):
             k = k_ref[pl.ds(j * block_k, block_k), :]
             v = v_ref[pl.ds(j * block_k, block_k), :]
             s = _dot(qa, k, _NT) * sm_scale                  # [BQ, BK]
@@ -238,31 +254,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale: float,
                 seen = keep_ref[j] != 0 if chosen else _keep(
                     qi * block_q, j * block_k, s.shape, 1, window)
                 s = jnp.where(seen, s, NEG_INF)
+            m_prev = m_ref[...]
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)                  # [BQ, 1]
-            l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
-            return m_new, l_new, acc * alpha + _dot(p.astype(v.dtype), v)
+            p = jnp.exp(s - _wide(m_new, s.shape[1]))
+            alpha = jnp.exp(m_prev - m_new)                  # [BQ, 128]
+            m_ref[...] = m_new
+            l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * _wide(alpha, width) + _dot(
+                p.astype(v.dtype), v)
 
         if chosen:  # a block pair with no chosen pair is stepped over
             visit = body
 
-            def body(j, carry, masked):
-                return jax.lax.cond(
-                    tiles_ref[qi, j] != 0,
-                    functools.partial(visit, j, masked=masked),
-                    lambda carry: carry, carry)
+            def body(j, masked):
+                pl.when(tiles_ref[qi, j] != 0)(
+                    functools.partial(visit, j, masked))
 
-        m, l, acc = _loop_blocks(bounds, MASKED, body, (
-            jnp.full((block_q, 1), NEG_INF, jnp.float32),
-            jnp.zeros((block_q, 1), jnp.float32),
-            jnp.zeros((block_q, width), jnp.float32)))
-        l = jnp.maximum(l, 1e-30)
-        o_a = acc / l
+        _loop_blocks(bounds, MASKED, body)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_a = acc_ref[...] / _wide(l, width)
         out = o_a if lanes is None else jnp.where(lanes, o_a, out)
         # log-sum-exp per query row: all the backward needs to recompute
         # p.  Stored as a lane-dense row.
-        lse_ref[a:a + 1, :] = _col_to_row(m + jnp.log(l))
+        lse_ref[a:a + 1, :] = (m_ref[...] + jnp.log(l)).T[:1]
     o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -293,7 +307,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                                      v_head_dim, group)
         heads.append((_only(k, k_lanes), _only(v, v_lanes)))
 
-    def body(i, carry, masked):
+    def body(i, masked):
         rows = pl.ds(i * block_q, block_q)
         q, do = q_ref[rows, :], do_ref[rows, :]
         if masked:
@@ -313,17 +327,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                    ).astype(q.dtype)
             dk_acc[...] += _dot(dst, _only(q, q_lanes))
             dq_acc[rows, :] += _dot(dst, ka, _TN)            # [BQ, W]
-        return carry  # nothing: every sum lives in a scratch ref
 
     if chosen:  # a block pair with no chosen pair is stepped over
         visit = body
 
-        def body(i, carry, masked):
-            pl.when(tiles_ref[i, ki] != 0)(
-                functools.partial(visit, i, carry, masked))
-            return carry
+        def body(i, masked):
+            pl.when(tiles_ref[i, ki] != 0)(functools.partial(visit, i, masked))
 
-    _loop_blocks(bounds, MASKED, body, None)
+    _loop_blocks(bounds, MASKED, body)
     dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
     dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
@@ -445,6 +456,8 @@ def _flash_heads_impl(q, k, v, causal, block_q, block_k, interpret,
          pl.BlockSpec((None, pn.group, pn.bq), lambda h, i: (h, 0, i))],
         [jax.ShapeDtypeStruct((pn.Lq, pn.H * pn.Dv), q.dtype),
          jax.ShapeDtypeStruct((pn.nh, pn.group, pn.Lq), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in (
+            (pn.bq, LANES), (pn.bq, LANES), (pn.bq, pn.Wv))],
         block_k=pn.bk, **chosen,
     )(pn.flat(q), pn.flat(k), pn.flat(v), *choice)
     return out.reshape(pn.Lq, pn.H, pn.Dv), lse.reshape(pn.H, pn.Lq)

@@ -51,6 +51,23 @@ def test_flash_multi_qkv_blocks_carry_state():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("causal", [False, True])
+def test_the_heads_of_a_column_block_keep_their_own_state(causal):
+    """Two heads of 64 in one 128-lane block, four q blocks, two head blocks:
+    each head restarts the forward's running max, sum and accumulator.  Head
+    0's scores are far larger than head 1's, so a max or a sum left over from
+    it would show in head 1's ``o`` and ``lse``."""
+    L, H, D, block = 256, 4, 64, 64
+    q, k, v = _qkv(L, H, D, seed=9)
+    q = q.at[:, ::2].multiply(30.0)
+    want_o, want_lse = _dense_with_lse(q, k, v, causal)
+    o, lse = flash_attention_with_lse(q, k, v, causal, block, block, True)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=2e-5, atol=2e-4)
+
+
 def test_flash_rejects_ragged():
     q, k, v = _qkv(L=60)
     with pytest.raises(ValueError):

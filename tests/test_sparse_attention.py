@@ -101,6 +101,49 @@ def test_an_empty_tile_is_never_read(choice):
     np.testing.assert_allclose(o2, want, atol=2e-5)
 
 
+# (row block, its tiles the table marks empty) at L = 64 in 16-blocks: an
+# empty tile between two live ones (the kernel steps over it with the running
+# state in scratch), and a row block whose first live tile is not tile 0 (the
+# state a head starts from is the scratch's reset, not a tile's)
+SKIPS = [
+    pytest.param(3, (1,), id="empty_tile_between_live_tiles"),
+    pytest.param(2, (0,), id="first_live_tile_not_tile_0"),
+    pytest.param(3, (0, 2), id="both"),
+]
+
+
+@pytest.mark.parametrize("row, empty", SKIPS)
+@pytest.mark.parametrize("H, G, D", SHARING)
+def test_the_forwards_o_and_lse_over_tiles_it_steps_over(row, empty, H, G, D):
+    """The forward kernel's ``o`` and ``lse`` against the lax path and an
+    explicit log-sum-exp over the chosen pairs, with a table that marks tiles
+    of a row block empty between or before its live ones."""
+    from fedml_tpu.ops.flash_attention import _flash_heads_impl
+
+    ks = jax.random.split(jax.random.PRNGKey(11 * row + H), 4)
+    keep = (jax.random.uniform(ks[0], (L, L)) < 0.4) & jnp.tril(
+        jnp.ones((L, L), bool))
+    rows = slice(row * BLOCK, (row + 1) * BLOCK)
+    for t in empty:
+        keep = keep.at[rows, t * BLOCK:(t + 1) * BLOCK].set(False)
+    keep = (keep | jnp.eye(L, dtype=bool)).astype(jnp.int8)
+    tiles = ss.live_tiles(keep, BLOCK)
+    live = np.asarray(tiles)[row, :row + 1]
+    assert not live[list(empty)].any() and live.sum() == row + 1 - len(empty)
+    q = jax.random.normal(ks[1], (L, H, D))
+    k, v = (jax.random.normal(ks[i], (L, G, D)) for i in (2, 3))
+    o, lse = _flash_heads_impl(q, k, v, True, BLOCK, BLOCK, True, keep=keep,
+                               tiles=tiles)
+    np.testing.assert_allclose(o, blockwise_attention(
+        q, k, v, causal=True, block_size=BLOCK, keep=keep), atol=2e-5)
+    np.testing.assert_allclose(o, explicit(q, k, v, keep), atol=2e-5)
+    s = jnp.einsum("qhd,khd->hqk", q, jnp.repeat(k, H // G, axis=1)) \
+        / np.sqrt(D)
+    want = jax.scipy.special.logsumexp(
+        jnp.where(keep[None] != 0, s, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(lse, want, rtol=1e-5, atol=1e-5)
+
+
 def test_a_choice_wants_its_table_the_causal_mask_and_no_window(choice):
     keep, tiles = choice
     q = jnp.zeros((L, 8, 16))

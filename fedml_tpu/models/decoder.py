@@ -69,9 +69,10 @@ leaves out the rest, which is what expert parallelism asks of one chip's
 layer (without its exchange: nothing here stands in for the other chips).
 Holding every expert, it is the whole layer.  No token is dropped: the row
 buffer follows the rows routed.  A share of the experts sorts its rows into
-a buffer of twice what a level router sends it when the count of a call
-allows, and runs every held expert over every token, with no buffer, when it
-does not; where that buffer would be no shorter than the worst case,
+a buffer of twice what a level router sends it, each expert's group from a
+row tile of the grouped products, when the count of a call allows, and runs
+every held expert over every token, with no buffer, when it does not; where
+that buffer would be no shorter than the worst case,
 ``tokens x min(top_k, experts held)``, the layer is one path through the
 worst case (``buffer_capacities``).  Rows move between tokens and buffer
 through ``ops/expert_rows.py``'s pair, which moves the rows held and builds
@@ -95,7 +96,9 @@ from fedml_tpu.models.transformer import (
     AttnFn, MultiHeadAttention, RMSNorm, _default_attn, scoped,
 )
 from fedml_tpu.obs import scopes
-from fedml_tpu.ops.expert_rows import from_buffer, sorted_route, to_buffer
+from fedml_tpu.ops.expert_rows import (
+    from_buffer, sort_assignments, sorted_route, to_buffer,
+)
 from fedml_tpu.ops.linear_attention import gated_delta_rule, short_causal_conv
 from fedml_tpu.ops.sparse_select import select_topk, tiles_scored
 
@@ -107,6 +110,7 @@ SPARSE, DENSE = "sparse", "dense"
 ASSIGNMENTS_HELD = "moe_assignments_held"  # token-expert pairs on held experts
 EXPERT_TOKENS_MAX = "moe_expert_tokens_max"  # the fullest held expert's rows
 ROWS_BUFFERED = "moe_rows_buffered"  # rows of the buffer the layer took
+ROWS_COMPUTED = "moe_rows_computed"  # rows the grouped products computed
 # a linear-attention layer's mean log decay over tokens, heads and channels
 KDA_LOG_DECAY_MEAN = "kda_log_decay_mean"
 # a SELECTED layer's count of 512 x 512 causal tiles that hold a chosen pair
@@ -351,34 +355,60 @@ def make_rope_fn(params: dict, head_dim: int) -> Callable:
 
 # -- layers -------------------------------------------------------------------
 
-def gmm_tiling(m: int, k: int, n: int):
+# A group on row tiles wastes half a tile on average, where groups one after
+# another compute a shared tile once for each: on row tiles a 256-row tile
+# runs the nine products of a layer-step 1-9 % faster than a 512-row one in
+# every decoder cell (256-1024 rows a group), though a tile runs ~10 % slower
+# a row (PERF.md §6, ``benchmark/tools/expert_tiles_bench.py``)
+ALIGNED_ROW_TILE = 256
+
+
+def gmm_tiling(m: int, k: int, n: int, aligned: bool = False):
     """(rows, contraction, columns) tile of the grouped-product kernel for
     ``[m, k] @ [groups, k, n]``, or None where it does not take the shape:
-    the largest row tile of 512, 256, 128 that divides ``m`` and, along
-    ``k`` and ``n``, the widest multiple of 128 up to 1152 that divides the
-    dim.  At the published expert shapes (65536 x 2304 x 896) a
-    (512, 1152, 896) tile ran forward + backward 2.2x faster than
-    (128, 128, 128) tiles times nine (PERF.md §6, PR 32)."""
+    the largest row tile of 512, 256, 128 that divides ``m``, and no longer
+    than ``ALIGNED_ROW_TILE`` where every group starts on a row tile
+    (``aligned``), and, along ``k`` and ``n``, the widest multiple of 128 up
+    to 1152 that divides the dim.  At the published expert shapes (65536 x
+    2304 x 896) a (512, 1152, 896) tile ran forward + backward 2.2x faster
+    than (128, 128, 128) tiles times nine (PERF.md §6)."""
     def tile(dim):
         return next((t for t in range(1152, 0, -128) if dim % t == 0), 0)
 
-    tiling = (next((t for t in (512, 256, 128) if m % t == 0), 0),
-              tile(k), tile(n))
+    largest = ALIGNED_ROW_TILE if aligned else 512
+    tiling = (next((t for t in (512, 256, 128)
+                    if t <= largest and m % t == 0), 0), tile(k), tile(n))
     return tiling if all(tiling) else None
 
 
-def _grouped_dot(rows, weights, group_sizes):
+
+def rows_computed(group_sizes, tile: int):
+    """Rows the grouped products compute for groups of ``group_sizes`` rows
+    laid one after another from row 0: the row tiles of ``tile`` rows their
+    grid visits, times ``tile``.  By ``megablox``'s rule
+    (``make_group_metadata``) a group covers its first row rounded down to a tile through its last
+    rounded up, so a tile that two groups share is computed once for each,
+    and an empty group covers nothing.  Where every group starts on a tile
+    and takes whole tiles it is their sum."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    tiles = jnp.where(group_sizes > 0, -(-ends // tile) - starts // tile, 0)
+    return tiles.sum() * tile
+
+
+def _grouped_dot(rows, weights, group_sizes, aligned):
     """``rows[r] @ weights[e]`` for the rows of group ``e``: groups lie one
-    after another from row 0; rows past their total cost no matmul and
-    hold nothing a caller may read.
+    after another from row 0 (each on a row tile where ``aligned``); rows
+    past their total cost no matmul and hold nothing a caller may read.
 
     On a TPU this is the Pallas grouped matmul (``megablox.gmm``), whose
-    grid holds only the row tiles the groups cover, forward and in both
-    gradients.  ``lax.ragged_dot`` compiles there to a product of every
-    row with every group under a mask when the sizes are not constants
-    (38 ms a product at the published shapes against 0.5, PERF.md §6); it
-    is what other backends and shapes the kernel does not tile run."""
-    tiling = gmm_tiling(rows.shape[0], *weights.shape[1:])
+    grid holds only the row tiles the groups cover (``rows_computed``),
+    forward and in both gradients.  ``lax.ragged_dot`` compiles there to a
+    product of every row with every group under a mask when the sizes are
+    not constants (38 ms a product at the published shapes against 0.5,
+    PERF.md §6); it is what other backends and shapes the kernel does not
+    tile run."""
+    tiling = gmm_tiling(rows.shape[0], *weights.shape[1:], aligned)
     if jax.default_backend() == "tpu" and tiling:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
@@ -419,18 +449,22 @@ _kept_for.defvjp(lambda computed, kept: (kept, None),
                  lambda _, g: (g, None))
 
 
-def _expert_rows(capacity, kept, x, top_p, w_gate, w_up, w_down, local, order,
-                 rank, group_sizes):
+def _expert_rows(capacity, aligned, kept, x, top_p, w_gate, w_up, w_down,
+                 local, order, rank, group_sizes, group_rows):
     """(the held experts' part of the layer's output [T, h] in the experts'
     dtype, the buffers a backward reads again: the rows, their weights, their
     two grouped products and the gated product) through a row buffer of
-    ``capacity`` rows (static; at least ``group_sizes.sum()``).
+    ``capacity`` rows (static; at least ``group_rows.sum()``), each group on
+    a row tile of the grouped products where ``aligned`` (static).
 
     ``x`` [T, h], ``top_p`` [T, k]; ``local`` [T, k] is the local index of
     each assignment's expert (the count of experts held: not here), ``order``
-    [T x k] sorts the assignments by it and ``rank`` [T, k] is the inverse.
-    ``kept``: those buffers from an earlier pass, to be read and not computed
-    again, or None.
+    and ``rank`` lay the assignments out in the buffer (``sort_assignments``),
+    ``group_sizes`` [E] counts each held expert's assignments and
+    ``group_rows`` [E] the rows its group takes: the size the grouped products
+    are handed, whose pad rows hold zeros in every buffer and add exact zeros
+    to every gradient.  ``kept``: those buffers from an earlier pass, to be
+    read and not computed again, or None.
 
     Rows cross between tokens and buffer four times, and each crossing moves
     the rows held and nothing else (``ops/expert_rows.py``): ``to_buffer``
@@ -446,19 +480,21 @@ def _expert_rows(capacity, kept, x, top_p, w_gate, w_up, w_down, local, order,
         return _kept_for(computed, next(keep)) if kept else computed
 
     with jax.named_scope(scopes.MOE_DISPATCH):
-        route = sorted_route(local, order, rank, group_sizes, capacity)
+        route = sorted_route(local, order, rank, group_sizes, capacity,
+                             group_rows)
         rows = buffer(to_buffer(x.astype(w_gate.dtype), route))
+        # a pad's entry of ``order`` is past the assignments: it reads 0
         row_w = buffer(jnp.where(
             route.row_live, top_p.reshape(-1).at[order[:capacity]].get(
-                unique_indices=True), 0))
+                unique_indices=True, mode="fill", fill_value=0), 0))
 
     with jax.named_scope(scopes.MOE_EXPERTS):
-        gate = buffer(_grouped_dot(rows, w_gate, group_sizes))
-        up = buffer(_grouped_dot(rows, w_up, group_sizes))
+        gate = buffer(_grouped_dot(rows, w_gate, group_rows, aligned))
+        up = buffer(_grouped_dot(rows, w_up, group_rows, aligned))
         act = buffer((jax.nn.silu(gate.astype(jnp.float32))
                       * up.astype(jnp.float32) * row_w[:, None]
                       ).astype(w_down.dtype))
-        out = _grouped_dot(act, w_down, group_sizes)
+        out = _grouped_dot(act, w_down, group_rows, aligned)
 
     with jax.named_scope(scopes.MOE_COMBINE):
         y = from_buffer(out, route)
@@ -490,8 +526,8 @@ def _every_expert(x, top_p, w_gate, w_up, w_down, local):
     return y.astype(w_down.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _expert_rows_fitted(capacity, fits, *operands):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _expert_rows_fitted(capacity, aligned, fits, *operands):
     """``_expert_rows`` through ``capacity`` rows where ``fits`` (a traced
     bool), else ``_every_expert``.
 
@@ -501,11 +537,11 @@ def _expert_rows_fitted(capacity, fits, *operands):
     residuals, and has each branch zero-fill the other's and copy the
     operands it reads again.  Here the buffered branch keeps its own five
     buffers, the other keeps nothing, and neither copies an operand."""
-    return _fitted_fwd(capacity, fits, *operands)[0]
+    return _fitted_fwd(capacity, aligned, fits, *operands)[0]
 
 
-def _fitted_fwd(capacity, fits, *operands):
-    buffered = functools.partial(_expert_rows, capacity, None)
+def _fitted_fwd(capacity, aligned, fits, *operands):
+    buffered = functools.partial(_expert_rows, capacity, aligned, None)
     _, kept = jax.eval_shape(buffered, *operands)
 
     def unbuffered(x, top_p, w_gate, w_up, w_down, local, *_):
@@ -516,8 +552,8 @@ def _fitted_fwd(capacity, fits, *operands):
     return y, (fits, operands, kept)
 
 
-def _fitted_bwd(capacity, res, g):
-    fits, (*floats, local, order, rank, group_sizes), kept = res
+def _fitted_bwd(capacity, aligned, res, g):
+    fits, (*floats, local, order, rank, group_sizes, group_rows), kept = res
 
     def grads(path):
         return jax.vjp(path, *floats)[1](g)
@@ -525,9 +561,10 @@ def _fitted_bwd(capacity, res, g):
     d_floats = jax.lax.cond(
         fits,
         lambda: grads(lambda *floats: _expert_rows(
-            capacity, kept, *floats, local, order, rank, group_sizes)[0]),
+            capacity, aligned, kept, *floats, local, order, rank,
+            group_sizes, group_rows)[0]),
         lambda: grads(lambda *floats: _every_expert(*floats, local)))
-    return (None, *d_floats, None, None, None, None)
+    return (None, *d_floats, None, None, None, None, None)
 
 
 _expert_rows_fitted.defvjp(_fitted_fwd, _fitted_bwd)
@@ -539,7 +576,7 @@ class ExpertLayer(nn.Module):
     ``x`` [B, L, h] float32 (the normed input).  Router and selection run in
     float32; the expert products run in the dtype of the expert weights.
     Returns (``sum over e in top-k and held of w_e f_e(x)`` in the experts'
-    dtype, the three counters as float32 scalars).  The scores are a softmax
+    dtype, the four counters as float32 scalars).  The scores are a softmax
     over all routed experts or a sigmoid an expert (``router_activation``);
     the ``top_k`` largest are chosen, renormalised to sum to 1 where
     ``norm_topk_prob`` and multiplied by ``routed_scaling_factor``.
@@ -550,20 +587,27 @@ class ExpertLayer(nn.Module):
     chosen experts' scores **without** it.  The bias is outside the gradient:
     it enters under ``stop_gradient`` and only through the discrete choice,
     so the loss gives its leaf exactly zero (a rule of the model's own would
-    move it between steps; none is built).  A fourth counter then counts the
+    move it between steps; none is built).  A fifth counter then counts the
     tokens whose chosen set is not the ``top_k`` largest of the scores alone
     (one whose least chosen score is under its greatest unchosen one).
 
     The row buffer is as long as ``buffer_capacities`` says.  Where the short
-    one is shorter than the worst case, a ``lax.cond`` on this call's count of
-    routed rows (``_expert_rows_fitted``) takes it when the count fits, and
-    else runs every held expert over every token (``_every_expert``: the
-    worst case's products, no buffer, nothing kept for the backward, which
+    one is shorter than the worst case, every held expert's group in it
+    starts on a row tile of the grouped products (``gmm_tiling``'s at the
+    buffer's length, at most ``ALIGNED_ROW_TILE``), and they are handed each group's size rounded up to
+    that tile: a tile that two groups would share is computed once, not once
+    for each.  The pad rows are zeros, add zeros to every gradient and reach
+    no token.  A ``lax.cond`` on this call's rows with their pads
+    (``_expert_rows_fitted``) takes the short buffer when they fit, and else
+    runs every held expert over every token (``_every_expert``: the worst
+    case's products, no buffer, nothing kept for the backward, which
     computes the forward again).  Both paths compute the same sums, and no
     token is dropped on either; ``moe_rows_buffered`` says which ran: the
-    short buffer's rows, or ``tokens x experts held``.  Where the short
+    short buffer's rows, or ``tokens x experts held``, and
+    ``moe_rows_computed`` how many rows the products computed
+    (``rows_computed``, or ``tokens x experts held``).  Where the short
     buffer is no shorter (every expert held) there is no branch and the
-    buffer is the worst case.
+    buffer is the worst case, its groups one after another from row 0.
 
     Two transfers carry rows between the tokens [T, h] and the buffer [C, h],
     each the other's backward (``ops/expert_rows.py``): ``to_buffer``, a
@@ -636,32 +680,44 @@ class ExpertLayer(nn.Module):
             # for a caller that asks for "intermediates": the selection
             self.sow("intermediates", "top_e", top_e)
 
+        # the grouped products' row tile at the buffer's length: the short
+        # buffer starts every group on one, the worst case has no room for pads
+        aligned = short < worst
+        tiling = gmm_tiling(min(short, worst), h, self.intermediate, aligned)
+        tile = tiling[0] if tiling else 1
+        align = tile if aligned else 1
         with jax.named_scope(scopes.MOE_DISPATCH):
             # local index of each assignment's expert; ``held`` = not here
             local_of = np.full((self.num_experts_routed,), held, np.int32)
             local_of[list(self.experts_held)] = np.arange(held)
             local = jnp.asarray(local_of)[top_e]  # [T, k]
-            # a counting sort by expert: held assignments first, by expert
-            order = jnp.argsort(local.reshape(A), stable=True)
-            rank = jnp.argsort(order).reshape(T, k)  # the inverse
             group_sizes = (local.reshape(A, 1) == jnp.arange(held)).sum(
                 axis=0, dtype=jnp.int32)
             routed = group_sizes.sum()
+            group_rows = (group_sizes if align == 1
+                          else -(-group_sizes // align) * align)
+            # a counting sort by expert: held assignments first, by expert,
+            # each group followed by its pads
+            order, rank = sort_assignments(local, group_rows - group_sizes,
+                                           align - 1)
+            computed = rows_computed(group_rows, tile)
 
         operands = (x, top_p, w_gate, w_up, w_down, local, order, rank,
-                    group_sizes)
-        if short >= worst:
-            y, _ = _expert_rows(worst, None, *operands)
+                    group_sizes, group_rows)
+        if not aligned:
+            y, _ = _expert_rows(worst, False, None, *operands)
             buffered = float(worst)
         else:
-            fits = routed <= short
-            y = _expert_rows_fitted(short, fits, *operands)
+            fits = group_rows.sum() <= short
+            y = _expert_rows_fitted(short, True, fits, *operands)
             buffered = jnp.where(fits, float(short), float(T * held))
+            computed = jnp.where(fits, computed, T * held)
 
         counters = {
             ASSIGNMENTS_HELD: routed.astype(jnp.float32),
             EXPERT_TOKENS_MAX: group_sizes.max().astype(jnp.float32),
             ROWS_BUFFERED: jnp.asarray(buffered, jnp.float32),
+            ROWS_COMPUTED: computed.astype(jnp.float32),
         }
         if moved is not None:
             counters[TOKENS_BIAS_MOVED] = moved

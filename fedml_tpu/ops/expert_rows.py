@@ -2,9 +2,13 @@
 the row buffer ``[C, h]`` that the grouped products read and write.
 
 A ``Route`` says where the rows go: the (token, slot) assignments sorted by
-the local index of their expert, held experts first, so that the buffer's
-first ``group_sizes.sum()`` rows are the held assignments, an expert after
-another and by token inside an expert.  Then
+the local index of their expert, held experts first, so that the buffer holds
+the held assignments an expert after another and by token inside an expert.
+Each expert's group takes ``group_rows[e]`` rows from ``starts[e]``: its
+``group_sizes[e]`` assignments and then pad rows, where the grouped products
+are handed sizes rounded up to their row tile so that every group starts on
+one (``sort_assignments`` lays them out; without pads the groups lie one
+after another from row 0).  Then
 
     to_buffer(x)[c]     = x[tok[c]]                     where row ``c`` is live, else 0
     from_buffer(buf)[t] = sum over the slots j of token t that are here
@@ -23,8 +27,9 @@ buffer, at most ``TOKEN_TILE`` long and about ``C / (experts x tiles)`` under
 a level router.  A grid step takes one window of ``window`` rows an expert
 (a ``BlockSpec`` whose element offset comes from the prefetched range starts,
 so the pipeline fetches a tile's windows while the tile before is computed),
-zeroes the rows past the routed count (the grouped product never wrote them
-and they may hold anything), and places all of them with one product on the
+zeroes the rows past the last group's live rows (the grouped product never
+wrote the rows past its groups and they may hold anything; the pad rows it
+did write are zeros), and places all of them with one product on the
 MXU: a [tile, experts x window] matrix of ones where ``rank[t, j]`` names the
 window's row, times the windows.  A product of a one with a row is the row,
 and the sum has at most one term an expert, so the result is the float32 sum
@@ -63,25 +68,58 @@ class Route(NamedTuple):
     rank: jax.Array  # [T, k] int32: the buffer row of a slot; any row < C where not here
     local: jax.Array  # [T, k] int32: the local index of a slot's expert; E: not here
     group_sizes: jax.Array  # [E] int32: rows of each held expert
+    group_rows: jax.Array  # [E] int32: rows its group takes, pad rows included
 
     @property
     def here(self):  # [T, k] bool: the slot's expert is held
         return self.local < self.group_sizes.shape[0]
 
     @property
+    def starts(self):  # [E] int32: the first buffer row of each group
+        return jnp.cumsum(self.group_rows) - self.group_rows
+
+    @property
     def row_live(self):  # [C] bool: row c is a held assignment
-        return jnp.arange(self.tok.shape[0]) < self.group_sizes.sum()
+        row = jnp.arange(self.tok.shape[0])[:, None]
+        return ((row >= self.starts) & (row < self.starts + self.group_sizes)
+                ).any(axis=1)
 
 
-def sorted_route(local, order, rank, group_sizes, capacity: int) -> Route:
+def sort_assignments(local, pads, most: int):
+    """(``order``, ``rank``): the stable sort that lays out the buffer.
+
+    ``local`` [T, k] is each slot's local expert index (``E`` where not
+    held), ``pads`` [E] the pad rows behind each held expert's assignments,
+    each at most ``most`` (static).  The sort's keys are the assignments'
+    ``local`` and, past them, ``E x most`` pad entries, of which expert
+    ``e``'s first ``pads[e]`` take key ``e`` and the rest key ``E``: a stable
+    sort puts each expert's pads behind its assignments, which stay by token.
+    ``order`` [T x k + E x most] is the sort (an entry past ``T x k`` is a
+    pad), ``rank`` [T, k] its inverse on the assignments: a held one's buffer
+    row.  With ``most`` 0 it is the sort by ``local`` alone."""
+    T, k = local.shape
+    keys = local.reshape(T * k)
+    if most:
+        held = pads.shape[0]
+        keys = jnp.concatenate([keys, jnp.where(
+            jnp.arange(most) < pads[:, None], jnp.arange(held)[:, None],
+            held).astype(keys.dtype).reshape(held * most)])
+    order = jnp.argsort(keys, stable=True)
+    return order, jnp.argsort(order)[:T * k].reshape(T, k)
+
+
+def sorted_route(local, order, rank, group_sizes, capacity: int,
+                 group_rows=None) -> Route:
     """The route through ``capacity`` rows of the assignments ``local`` [T, k]
-    (each slot's local expert index, ``E`` where not held) sorted by it:
-    ``order`` [T x k] the stable sort, ``rank`` [T, k] its inverse,
-    ``group_sizes`` [E] the held experts' counts, ``capacity`` at least their
-    sum."""
-    return Route(tok=order[:capacity] // local.shape[1],
+    laid out by ``sort_assignments``: ``order`` and ``rank`` are its sort and
+    inverse, ``group_sizes`` [E] the held experts' counts, ``group_rows`` [E]
+    the rows each group takes with its pads (None: none),
+    ``capacity`` at least their sum."""
+    T, k = local.shape
+    return Route(tok=jnp.minimum(order[:capacity], T * k - 1) // k,
                  rank=jnp.minimum(rank, capacity - 1), local=local,
-                 group_sizes=group_sizes)
+                 group_sizes=group_sizes,
+                 group_rows=group_sizes if group_rows is None else group_rows)
 
 
 @jax.custom_vjp
@@ -93,7 +131,9 @@ def to_buffer(x, route: Route):
 @jax.custom_vjp
 def from_buffer(buf, route: Route):
     """``buf`` [C, h] -> [T, h]: a token's rows, those its slots that are here
-    name, added in float32 and returned in ``buf``'s dtype."""
+    name, added in float32 and returned in ``buf``'s dtype.  A group's pad
+    rows must be finite (the grouped products write zeros there); rows past
+    the groups may hold anything."""
     tokens, width = route.rank.shape[0], buf.shape[1]
     window = window_rows(tokens, buf.shape[0], width,
                          route.group_sizes.shape[0], buf.dtype.itemsize)
@@ -137,14 +177,14 @@ def range_starts(route: Route):
     """[(tiles + 1) x E] int32, flat: entry ``i * E + e`` is the first buffer
     row of expert ``e`` that belongs to a token of tile ``i`` or later, so
     the rows tile ``i`` wants from ``e`` run from entry ``i * E + e`` up to
-    entry ``(i + 1) * E + e``; the last entry is the count of routed rows."""
+    entry ``(i + 1) * E + e``; the last entry is one past the last group's
+    live rows (the routed count where no group has pads)."""
     (T, k), held = route.local.shape, route.group_sizes.shape[0]
     in_tile = (route.local.reshape(T // TOKEN_TILE, TOKEN_TILE * k, 1)
                == jnp.arange(held)).sum(axis=1, dtype=jnp.int32)
-    first = jnp.cumsum(route.group_sizes) - route.group_sizes
     before = jnp.concatenate([jnp.zeros((1, held), jnp.int32),
                               jnp.cumsum(in_tile, axis=0)])
-    return (first + before).astype(jnp.int32).reshape(-1)
+    return (route.starts + before).astype(jnp.int32).reshape(-1)
 
 
 def _range(starts, i, e, held):
@@ -166,7 +206,7 @@ def _windows_kernel(starts, slots_ref, *refs, held, window, capacity):
     acc_ref, rows_ref, arrived = refs[held + 2:]
     i = pl.program_id(0)
     ranges = [_range(starts, i, e, held) for e in range(held)]
-    live = starts[pl.num_programs(0) * held + held - 1]  # rows routed
+    live = starts[pl.num_programs(0) * held + held - 1]  # past the last live row
     slot_of = [pl.ds(e * window, window) for e in range(held)]
     # a router that is not level makes a range longer than its window: the
     # rest of it takes further rounds, a window a round
@@ -212,8 +252,9 @@ def _windows_kernel(starts, slots_ref, *refs, held, window, capacity):
         # the row each entry of ``rows_ref`` holds, less its place in it
         offsets = [first - e * window for e, first in enumerate(firsts)]
 
-        # a row past the routed count was never written and may hold a NaN; a
-        # row another expert owns is finite and meets a zero of ``hit``
+        # a row past the groups was never written and may hold a NaN; a row
+        # another expert owns, or a pad row, is finite and meets a zero of
+        # ``hit``
         @pl.when(functools.reduce(jnp.maximum, firsts) + window > live)
         def _():
             at = entries(0)

@@ -6,8 +6,10 @@ toy model, its parameter tree by path and shape, and the ungated
 ``test_afmoe_decoder.py`` and ``test_attention_gate.py``, which hold the
 working tree to ``testdata/lowered_parent_pr40.json``: the copy taken from the
 commit before the gate, the post-norms, the embedding's factor and the
-selection bias came (every one off by default).  The compile cache's key is
-the program: a key that moves costs every accepted cell a cold set-up.
+selection bias came (every one off by default), with the rounds of the
+families that have an expert layer taken again when that layer came to count
+the rows its grouped products compute.  The compile cache's key is the
+program: a key that moves costs every accepted cell a cold set-up.
 
 To take the copy again from a checkout (after a change that means to move
 the programs):

@@ -427,7 +427,8 @@ def test_an_accepted_familys_tree_and_round_program_are_the_parents(
     parameter tree, by path and shape, and the lowered round program of a toy
     of each accepted configuration's kinds are those of the commit before
     this family came: the copy in ``testdata/`` was taken from it
-    (``lowered_programs.py`` says how)."""
+    (``lowered_programs.py`` says how), and the expert families' rounds again
+    when the expert layer came to count the rows its products compute."""
     got = lowered_programs.program_of(family)
     assert got["tree"] == pinned[family]["tree"]
     assert got["round_sha256"] == pinned[family]["round_sha256"], (

@@ -17,8 +17,8 @@ from benchmark.families import mellum_moe_plain as plain  # noqa: E402
 from fedml_tpu.models import decoder  # noqa: E402
 from fedml_tpu.models.base import COUNTERS  # noqa: E402
 from fedml_tpu.models.decoder import (  # noqa: E402
-    ASSIGNMENTS_HELD, EXPERT_TOKENS_MAX, ROWS_BUFFERED, ExpertLayer,
-    buffer_capacities, decoder_lm,
+    ASSIGNMENTS_HELD, EXPERT_TOKENS_MAX, ROWS_BUFFERED, ROWS_COMPUTED,
+    ExpertLayer, buffer_capacities, decoder_lm,
 )
 
 YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
@@ -346,47 +346,75 @@ def test_the_fallback_hands_the_backward_no_buffer_of_its_own():
 from fedml_tpu.ops import expert_rows  # noqa: E402
 
 
-def route_of(key, tokens, k, routed, held, capacity, skew=0.0):
+def route_of(key, tokens, k, routed, held, capacity, skew=0.0, tile=1):
     """The ``Route`` the expert layer builds for a router with random scores
-    (``skew`` added to expert ``held[0]``'s), through ``capacity`` rows."""
+    (``skew`` added to expert ``held[0]``'s), through ``capacity`` rows, each
+    group from a multiple of ``tile``."""
     scores = jax.random.normal(key, (tokens, routed)).at[:, held[0]].add(skew)
     _, top_e = jax.lax.top_k(scores, k)
     local_of = np.full((routed,), len(held), np.int32)
     local_of[list(held)] = np.arange(len(held))
-    local = jnp.asarray(local_of)[top_e]
-    order = jnp.argsort(local.reshape(-1), stable=True)
-    rank = jnp.argsort(order).reshape(tokens, k)
-    sizes = (local.reshape(-1, 1) == jnp.arange(len(held))).sum(
+    return route_from(jnp.asarray(local_of)[top_e], len(held), capacity, tile)
+
+
+def route_from(local, held, capacity, tile=1):
+    """The ``Route`` of the assignments ``local`` [T, k] (``held``: not
+    here), laid out as the expert layer lays them."""
+    sizes = (local.reshape(-1, 1) == jnp.arange(held)).sum(
         axis=0, dtype=jnp.int32)
-    assert int(sizes.sum()) <= capacity
-    return expert_rows.sorted_route(local, order, rank, sizes, capacity)
+    rows = -(-sizes // tile) * tile
+    order, rank = expert_rows.sort_assignments(local, rows - sizes, tile - 1)
+    assert int(rows.sum()) <= capacity
+    return expert_rows.sorted_route(local, order, rank, sizes, capacity, rows)
+
+
+def crafted_local(tile):
+    """[64, 2] slots of 3 held experts: expert 0 holds exactly ``tile`` rows,
+    expert 1 none, expert 2 ``tile + 5``; every other slot is not here."""
+    local = np.full((64, 2), 3, np.int32)
+    local[:tile, 0] = 0
+    local[:tile + 5, 1] = 2
+    return jnp.asarray(local)
 
 
 # (tokens, k, experts routed, held, buffer rows): a share through a short
 # buffer with dead rows past the routed count; tokens with none, one and all k
 # of their slots here; every expert held, C = T x k and no dead row
 PAIRS = [
-    pytest.param(64, 4, 16, (3, 9), 128, id="share_with_dead_rows"),
-    pytest.param(96, 2, 5, (0, 2, 4), 192, id="none_one_and_k_slots_here"),
-    pytest.param(48, 4, 8, tuple(range(8)), 192, id="every_expert_held"),
+    pytest.param(64, 4, 16, (3, 9), 128, 1, id="share_with_dead_rows"),
+    pytest.param(96, 2, 5, (0, 2, 4), 192, 1, id="none_one_and_k_slots_here"),
+    pytest.param(48, 4, 8, tuple(range(8)), 192, 1, id="every_expert_held"),
+    pytest.param(64, 4, 16, (3, 9), 128, 16, id="groups_on_row_tiles"),
+    pytest.param(64, 2, None, 3, 64, 16,
+                 id="row_tiles_with_an_empty_and_a_whole_tile_group"),
 ]
 
 
-@pytest.mark.parametrize("tokens, k, routed, held, capacity", PAIRS)
+@pytest.mark.parametrize("tokens, k, routed, held, capacity, tile", PAIRS)
 def test_the_two_row_transfers_are_each_others_transpose(tokens, k, routed,
-                                                         held, capacity):
+                                                         held, capacity, tile):
     """``to_buffer`` and ``from_buffer`` against the [C, T] matrix of ones
     they stand for, and each one's vjp against the other and against what JAX
-    derives from the plain gather."""
-    route = route_of(jax.random.PRNGKey(20), tokens, k, routed, held, capacity)
+    derives from the plain gather; groups one after another, or each from a
+    row tile with pad rows behind it."""
+    if routed is None:  # crafted: sizes 16, 0 and 21
+        route = route_from(crafted_local(tile), held, capacity, tile)
+        assert route.group_sizes.tolist() == [tile, 0, tile + 5]
+        assert route.starts.tolist() == [0, tile, tile]
+    else:
+        route = route_of(jax.random.PRNGKey(20), tokens, k, routed, held,
+                         capacity, tile=tile)
     here = np.asarray(route.here).sum(axis=1)
     live = int(route.group_sizes.sum())
-    if len(held) == routed:
+    if routed is None:
+        assert live < capacity
+    elif len(held) == routed:
         assert live == capacity == tokens * k and (here == k).all()
     else:
         assert live < capacity and {0, 1}.issubset(here)
     if capacity == 192 and routed == 5:
         assert k in here
+    assert int(np.asarray(route.row_live).sum()) == live
     ones = (np.asarray(route.row_live)[:, None]
             & (np.asarray(route.tok)[:, None] == np.arange(tokens)))
     ones = jnp.asarray(ones, jnp.float32)  # [C, T]
@@ -416,28 +444,36 @@ def test_the_two_row_transfers_are_each_others_transpose(tokens, k, routed,
 # held, narrow rows); float32 rows; a router that sends every token to one
 # held expert, whose range of a tile is four windows long
 KERNEL_SHAPES = [
-    pytest.param(8192, 8, 64, 8, 16384, 128, jnp.bfloat16, 0.0,
+    pytest.param(8192, 8, 64, 8, 16384, 128, jnp.bfloat16, 0.0, 1,
                  id="mellum2_silo_code8k_buffer"),
-    pytest.param(8192, 8, 256, 8, 4096, 128, jnp.bfloat16, 0.0,
+    pytest.param(8192, 8, 256, 8, 4096, 128, jnp.bfloat16, 0.0, 1,
                  id="kimilin_silo_doc8k_buffer"),
-    pytest.param(512, 4, 16, 4, 1024, 256, jnp.float32, 0.0, id="float32"),
-    pytest.param(512, 2, 8, 2, 1024, 128, jnp.float32, 9.0,
+    pytest.param(512, 4, 16, 4, 1024, 256, jnp.float32, 0.0, 1, id="float32"),
+    pytest.param(512, 2, 8, 2, 1024, 128, jnp.float32, 9.0, 1,
                  id="a_range_of_four_windows"),
+    pytest.param(8192, 8, 256, 8, 4096, 128, jnp.bfloat16, 0.0, 256,
+                 id="kimilin_silo_doc8k_buffer_on_row_tiles"),
+    pytest.param(512, 2, 8, 2, 1024, 128, jnp.float32, 9.0, 512,
+                 id="a_whole_tile_group_of_four_windows_on_row_tiles"),
 ]
 
 
 @pytest.mark.parametrize(
-    "tokens, k, routed, held, capacity, h, dtype, skew", KERNEL_SHAPES)
+    "tokens, k, routed, held, capacity, h, dtype, skew, tile", KERNEL_SHAPES)
 def test_the_from_buffer_kernel_matches_the_lax_form(tokens, k, routed, held,
-                                                     capacity, h, dtype, skew):
+                                                     capacity, h, dtype, skew,
+                                                     tile):
     """The Pallas kernel in interpret mode against the gather, select and
-    sum, with NaN in the buffer's rows past the routed count."""
+    sum, with NaN in the buffer's rows past the groups and zeros in their pad
+    rows (what the grouped products leave there)."""
     route = route_of(jax.random.PRNGKey(23), tokens, k, routed,
-                     tuple(range(held)), capacity, skew)
+                     tuple(range(held)), capacity, skew, tile)
     if skew:
         assert int(route.group_sizes[0]) == tokens
+    past = jnp.arange(capacity)[:, None] >= route.group_rows.sum()
     buf = jnp.where(route.row_live[:, None], jax.random.normal(
-        jax.random.PRNGKey(24), (capacity, h)), jnp.nan).astype(dtype)
+        jax.random.PRNGKey(24), (capacity, h)), jnp.where(past, jnp.nan, 0)
+    ).astype(dtype)
     # the window the layer would pick; 64 rows where a range must outgrow it
     window = 64 if skew else expert_rows.window_rows(
         tokens, capacity, h, held, buf.dtype.itemsize)
@@ -471,6 +507,213 @@ def test_the_kernel_refuses_tokens_that_are_no_whole_tiles_by_name():
     with pytest.raises(ValueError, match="300 tokens in tiles of 256"):
         expert_rows.from_buffer_windows(jnp.zeros((256, 128)), route, 64,
                                         interpret=True)
+
+
+# -- each group from a row tile of the grouped products ---------------------------
+
+@pytest.mark.parametrize("case, tile", [("random", 16), ("crafted", 16),
+                                        ("random", 1)],
+                         ids=["random_tile_16", "empty_and_whole_tile_groups",
+                              "no_pads"])
+def test_every_group_starts_on_a_row_tile_and_keeps_its_rows_by_token(case,
+                                                                      tile):
+    """Each held expert's group starts on a multiple of ``tile`` and takes
+    whole tiles; its first ``group_sizes[e]`` rows are its assignments by
+    token, and a slot's rank names its row; the rest of the group is pad
+    rows, which are not live.  With ``tile`` 1 it is the sort by expert."""
+    if case == "crafted":
+        local, held = crafted_local(tile), 3
+    else:
+        held = 2
+        scores = jax.random.normal(jax.random.PRNGKey(26), (64, 16))
+        local_of = np.full((16,), held, np.int32)
+        local_of[[3, 9]] = np.arange(held)
+        local = jnp.asarray(local_of)[jax.lax.top_k(scores, 4)[1]]
+    route = route_from(local, held, 128, tile)
+    sizes, rows = np.asarray(route.group_sizes), np.asarray(route.group_rows)
+    starts, live = np.asarray(route.starts), np.asarray(route.row_live)
+    assert (starts % tile == 0).all() and (rows % tile == 0).all()
+    assert (rows - sizes < tile).all() and (rows[sizes == 0] == 0).all()
+    tok, rank, loc = (np.asarray(a) for a in (route.tok, route.rank, local))
+    for e in range(held):
+        group = slice(starts[e], starts[e] + sizes[e])
+        want = np.nonzero((loc == e).any(axis=1))[0]  # its tokens, in order
+        assert tok[group].tolist() == want.tolist()
+        assert live[group].all()
+        assert not live[starts[e] + sizes[e]:starts[e] + rows[e]].any()
+        t, j = np.nonzero(loc == e)
+        assert (tok[rank[t, j]] == t).all()
+        assert ((rank[t, j] >= starts[e])
+                & (rank[t, j] < starts[e] + sizes[e])).all()
+    assert not live[rows.sum():].any() and live.sum() == sizes.sum()
+    if tile == 1:
+        order = jnp.argsort(local.reshape(-1), stable=True)
+        assert tok.tolist() == (np.asarray(order[:128]) // 4).tolist()
+
+
+WIDE = dict(h=128, f=128)  # widths the grouped products tile
+
+
+def wide_layer(held=(3, 9)):
+    return ExpertLayer(16, held, 4, WIDE["f"])
+
+
+def wide_case(key, sizes=None):
+    """(params of the share of experts 3 and 9, x [1, 1024, 128]): a level
+    router, or one that sends ``sizes`` tokens to experts 3 and 9 (the first
+    ``sizes[0]`` to 3, the next ``sizes[1]`` to 9, none to either else)."""
+    params = expert_params(key, h=WIDE["h"], f=WIDE["f"])
+    x = jax.random.normal(jax.random.fold_in(key, 1), (1, BRANCH_T, WIDE["h"]))
+    if sizes is not None:
+        t = jnp.arange(BRANCH_T)
+        x = x.at[0, :, 0].set(jnp.where(t < sizes[0], 1.0, -1.0))
+        x = x.at[0, :, 1].set(jnp.where(
+            (t >= sizes[0]) & (t < sizes[0] + sizes[1]), 1.0, -1.0))
+        one_hot = jnp.zeros((WIDE["h"],))
+        params["router"] = params["router"].at[:, 3].set(
+            one_hot.at[0].set(40.0)).at[:, 9].set(one_hot.at[1].set(40.0))
+    return share_of(params, BRANCH_HELD), x
+
+
+def layer_and_grads(layer, params, x, probe):
+    def loss(p, x):
+        y, counters = layer.apply({"params": p}, x)
+        return (y.reshape(-1, WIDE["h"]) * probe).sum(), (y, counters)
+
+    return jax.grad(loss, argnums=(0, 1), has_aux=True)(params, x)
+
+
+def reference_and_grads(params, x, probe):
+    cfg = {"num_experts": 2, "num_experts_routed": 16,
+           "experts_held": BRANCH_HELD, "num_experts_per_tok": 4}
+
+    def loss(p, x):
+        y, chosen = plain.expert_layer(cfg, x.reshape(-1, WIDE["h"]), p)
+        return (y * probe).sum(), (y, chosen)
+
+    return jax.grad(loss, argnums=(0, 1), has_aux=True)(params, x)
+
+
+def test_groups_on_row_tiles_give_the_layer_and_its_gradients_unmoved(
+        monkeypatch):
+    """At widths the grouped products tile (256-row tiles in a 1024-row short
+    buffer) the layer lays its groups out from row tiles, and its output and
+    every gradient match the family's plain reference and the same layer
+    with its groups one after another (no row tile to align to)."""
+    assert decoder.gmm_tiling(1024, WIDE["h"], WIDE["f"])[0] == 512
+    assert decoder.gmm_tiling(1024, WIDE["h"], WIDE["f"], True)[0] == 256
+    params, x = wide_case(jax.random.PRNGKey(27))
+    probe = jax.random.normal(jax.random.PRNGKey(28), (BRANCH_T, WIDE["h"]))
+    g_ours, (y, counters) = layer_and_grads(wide_layer(), params, x, probe)
+    g_ref, (want, chosen) = reference_and_grads(params, x, probe)
+    by_hand = np.bincount(np.asarray(chosen).reshape(-1),
+                          minlength=16)[BRANCH_HELD]
+    assert (0 < by_hand).all() and (by_hand <= 512).all()
+    assert float(counters[ROWS_BUFFERED]) == 1024  # the short buffer ran
+    # whole tiles, none shared
+    assert float(counters[ROWS_COMPUTED]) == (-(-by_hand // 256) * 256).sum()
+    assert float(counters[ASSIGNMENTS_HELD]) == by_hand.sum()
+    monkeypatch.setattr(decoder, "gmm_tiling", lambda *shape: None)
+    g_flat, (y_flat, c_flat) = layer_and_grads(wide_layer(), params, x, probe)
+    assert float(c_flat[ROWS_COMPUTED]) == by_hand.sum()  # no row tile
+    assert rel(y.reshape(-1, WIDE["h"]), want) < 1e-5
+    assert rel(y, y_flat) < 1e-6
+    for (path, a), b, c in zip(jax.tree_util.tree_leaves_with_path(g_ours),
+                               jax.tree_util.tree_leaves(g_ref),
+                               jax.tree_util.tree_leaves(g_flat)):
+        assert rel(a, b) < 1e-5, jax.tree_util.keystr(path)
+        assert rel(a, c) < 1e-6, jax.tree_util.keystr(path)
+
+
+def test_rows_that_fit_but_whose_pads_do_not_take_the_fallback(monkeypatch):
+    """800 + 100 routed rows fit the 1024-row short buffer, but from 256-row
+    tiles they take 1024 + 256: the layer runs every held expert over every
+    token, and says so in both counters; its output and gradients still
+    match the reference.  Without a row tile the same rows take the buffer."""
+    params, x = wide_case(jax.random.PRNGKey(29), sizes=(800, 100))
+    probe = jax.random.normal(jax.random.PRNGKey(30), (BRANCH_T, WIDE["h"]))
+    g_ours, (y, counters) = layer_and_grads(wide_layer(), params, x, probe)
+    g_ref, (want, chosen) = reference_and_grads(params, x, probe)
+    by_hand = np.bincount(np.asarray(chosen).reshape(-1),
+                          minlength=16)[BRANCH_HELD]
+    assert by_hand.tolist() == [800, 100]
+    assert float(counters[ASSIGNMENTS_HELD]) == 900  # fits 1024 unpadded
+    assert float(counters[ROWS_BUFFERED]) == BRANCH_T * 2  # the fallback ran
+    assert float(counters[ROWS_COMPUTED]) == BRANCH_T * 2
+    assert rel(y.reshape(-1, WIDE["h"]), want) < 1e-5
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_ours),
+                            jax.tree_util.tree_leaves(g_ref)):
+        assert rel(a, b) < 1e-5, jax.tree_util.keystr(path)
+    monkeypatch.setattr(decoder, "gmm_tiling", lambda *shape: None)
+    _, counters = wide_layer().apply({"params": params}, x)
+    assert float(counters[ROWS_BUFFERED]) == 1024
+    assert float(counters[ROWS_COMPUTED]) == 900
+
+
+@pytest.mark.parametrize("sizes, tile", [
+    ([1024, 1031, 998, 1010, 1040, 1019, 1002, 990], 512),
+    ([256, 270, 0, 243, 251, 512, 0, 260], 512),
+    ([256, 270, 0, 243, 251, 512, 0, 260], 256),
+    ([0, 0, 17, 0], 128),
+    ([5, 600, 3], 1),
+], ids=["mellum2_level", "kimilin_with_empty_groups", "kimilin_tile_256",
+        "one_group_in_empties", "no_row_tile"])
+@pytest.mark.parametrize("aligned", [False, True], ids=["one_after_another",
+                                                        "on_row_tiles"])
+def test_rows_computed_is_the_kernels_tiles_times_the_tile(sizes, tile,
+                                                           aligned):
+    """``rows_computed`` against ``megablox``'s own ``make_group_metadata``
+    (the grid's row tiles) for the sizes handed to the products: the
+    routed counts, or those rounded up to the tile (then their sum)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata,
+    )
+
+    given = np.asarray(sizes, np.int32)
+    if aligned:
+        given = -(-given // tile) * tile
+    m = -(-max(int(given.sum()), 1) // tile) * tile
+    _, tiles = make_group_metadata(
+        group_sizes=jnp.asarray(given), m=m, tm=tile,
+        start_group=jnp.zeros((), jnp.int32), num_nonzero_groups=len(sizes),
+        visit_empty_groups=False)
+    got = int(decoder.rows_computed(jnp.asarray(given), tile))
+    assert got == int(tiles) * tile
+    if aligned:
+        assert got == int(given.sum())
+    assert got >= sum(sizes)
+
+
+@pytest.mark.parametrize("counters, want", [
+    ({ASSIGNMENTS_HELD: [8192.0, 8200.0], ROWS_COMPUTED: [10240.0, 10752.0]},
+     100 * 16392 / 20992),
+    ({ASSIGNMENTS_HELD: [8192.0, 20000.0], ROWS_COMPUTED: [10240.0, 65536.0]},
+     100 * 28192 / 75776),  # a fallback computes every token for each expert
+    ({ASSIGNMENTS_HELD: [8192.0, 8200.0]}, None),  # the parent's program
+    ({}, None),
+], ids=["on_row_tiles", "one_fallback", "no_rows_computed", "no_counter"])
+def test_tile_fill_is_held_assignments_over_rows_computed(counters, want):
+    """``benchmark/layer_metrics/expert_tile_fill_pct.py`` on a made-up
+    context: two traced calls' metrics, one entry a round."""
+    import types
+
+    from benchmark import cells
+
+    calls = [(0.0, 0.1, 1, {"count": np.array([8192.0]),
+                            **{k: np.array([v[i]]) for k, v in counters.items()}})
+             for i in range(2)]
+    metric = cells.load_layer_metric("expert_tile_fill_pct")
+    assert metric.COUNTER == ROWS_COMPUTED
+    got = metric.read(types.SimpleNamespace(calls=calls))
+    assert got == (want if want is None else pytest.approx(want))
+    entry = {m["name"]: m for m in cells.manifest()["per_layer"]}[
+        "expert_tile_fill_pct"]
+    assert entry["workloads"] == ["mellum2_silo_code8k", "kimilin_silo_doc8k",
+                                  "keyevl2_silo_text8k",
+                                  "trinitymini_silo_chat8k"]
+    assert (entry["source"], entry["layer"], entry["moves"], entry["unit"],
+            entry["better"]) == ("program_counter", "kernels", "tokens_per_s",
+                                 "%", "higher")
 
 
 # -- through the round path ---------------------------------------------------------
@@ -518,8 +761,10 @@ def test_counters_leave_with_the_metrics_and_never_enter_variables():
     # 2 clients x 2 steps x 4 layers x 64 tokens x top 2, 3 of 8 experts held
     assert 0 < held < 2 * 2 * 4 * 64 * 2 and held == int(held)
     assert held / 3 <= fullest <= held
-    # one path at these shapes: every layer-step buffers its worst case
+    # one path at these shapes: every layer-step buffers its worst case, and
+    # widths the grouped products do not tile compute the rows routed
     assert float(metrics[ROWS_BUFFERED][0]) == 2 * 2 * 4 * 64 * 2
+    assert float(metrics[ROWS_COMPUTED][0]) == held
     assert float(metrics["count"][0]) == 2 * 2 * 2 * 32
 
 

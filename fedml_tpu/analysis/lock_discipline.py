@@ -1,8 +1,8 @@
 """lock-discipline — guarded attributes only touched under their lock.
 
-The threaded modules (hub reader threads + sender pool, the server's
-reader-thread fold + deadline timer, the chaos wrapper's held-message
-tables) protect shared state with per-instance locks, but nothing
+The threaded modules (the hub's event loop and its off-loop callers,
+the server's reader-thread fold + deadline timer, the chaos wrapper's
+held-message tables) protect shared state with per-instance locks, but nothing
 enforced the association — a new code path touching ``self.pending``
 without ``self._round_lock`` compiles, passes single-threaded tests,
 and corrupts a round under load.

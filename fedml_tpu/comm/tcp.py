@@ -17,26 +17,18 @@ id.  Two frame generations share the stream:
   one hub and the 4/3x base64 inflation is gone from model traffic.
 
 Design notes vs the reference's MPI threads (SURVEY.md §5.2): the hub
-data plane runs in one of two modes (``FEDML_TPU_HUB_MODE``, or the
-``mode=`` kwarg):
+data plane is a reactor — a single ``selectors``-based event-loop
+thread multiplexes every connection non-blocking: accepts, streaming
+header/payload reads (``comm/reactor.py``'s ``FrameParser``), shm
+doorbells, and writability-driven queue drains.  O(1) threads per hub
+regardless of connection count, and inbound TCP payloads land in
+pooled refcounted buffers (``BufRegion``) that ride the send queues as
+pins, so routing is zero-copy end to end on BOTH transports.
 
-- **reactor** (default) — a single ``selectors``-based event-loop
-  thread multiplexes every connection non-blocking: accepts, streaming
-  header/payload reads (``comm/reactor.py``'s ``FrameParser``), shm
-  doorbells, and writability-driven queue drains.  O(1) threads per
-  hub regardless of connection count — the scaling plane for the
-  512-conn-and-up regime — and inbound TCP payloads land in pooled
-  refcounted buffers (``BufRegion``) that ride the send queues as
-  pins, so routing is zero-copy end to end on BOTH transports.
-- **threaded** — the original one-blocking-reader-thread-per-connection
-  model with a sender pool; kept as the A/B byte-identity baseline
-  (both modes build frame bytes through the same drain helpers, so
-  federations pin sha256-identical across them).
-
-Either way: shutdown via sentinel frame and socket close — no ctypes
-thread kills, no polling sleeps.  A dead or misbehaving peer only
-loses its own frames: routing errors are caught, the stale connection
-is dropped, and other nodes keep flowing.
+Shutdown via sentinel frame and socket close — no ctypes thread kills,
+no polling sleeps.  A dead or misbehaving peer only loses its own
+frames: routing errors are caught, the stale connection is dropped,
+and other nodes keep flowing.
 """
 
 from __future__ import annotations
@@ -46,7 +38,6 @@ import itertools
 import json
 import logging
 import os
-import queue
 import selectors
 import socket
 import threading
@@ -80,17 +71,13 @@ from fedml_tpu.obs.telemetry import get_telemetry
 _SENTINEL = {HUB_KEY: "stop"}
 _ACK = {HUB_KEY: "ack"}
 
-# hub data-plane selection: "reactor" (selector event loop, default) or
-# "threaded" (one blocking reader thread per connection + sender pool)
-ENV_HUB_MODE = "FEDML_TPU_HUB_MODE"
-
 
 class _HubConnError(Exception):
     """Connection-fatal condition noticed mid-serve (lane errors,
-    doorbells on lane-less conns, invalid hellos): the reactor and the
-    threaded reader both translate it into 'drop this connection',
-    which is the established policy for every unrecoverable per-conn
-    fault — the peer pays one reconnect, the router never wedges."""
+    doorbells on lane-less conns, invalid hellos): the reactor
+    translates it into 'drop this connection', which is the
+    established policy for every unrecoverable per-conn fault — the
+    peer pays one reconnect, the router never wedges."""
 
 
 def _retry_jitter(node_id: int, attempt: int) -> float:
@@ -199,26 +186,25 @@ class _Conn:
     Since hello v2 a connection may carry MANY node ids (a muxer process
     driving hundreds of virtual clients over one socket): ``ids`` is the
     live set of node ids currently routed here (ids can be re-claimed by
-    a later connection — the rebind policy in ``_serve_conn``), ``mux``
-    marks a v2 registration (broadcast copies to it are wrapped in a
-    ``__hub__: mux`` outer header naming the co-located target ids so
-    the demuxing backend can fan out locally), ``cid`` is a small
+    a later connection — the rebind policy in ``_register_conn``),
+    ``mux`` marks a v2 registration (broadcast copies to it are wrapped
+    in a ``__hub__: mux`` outer header naming the co-located target ids
+    so the demuxing backend can fan out locally), ``cid`` is a small
     process-unique connection id for per-connection telemetry series,
-    and ``dead`` tells a sender-pool worker to stop draining (set when
-    the reader exits or every id was rebound away).
+    and ``dead`` tells the loop's drain to stop (set when the conn is
+    torn down or every id was rebound away).
 
-    ``scheduled`` enforces a single drainer at a time (a connection is
-    only ever serviced by the one sender worker it was handed to), so
-    per-connection order is FIFO and frames can never interleave
-    mid-payload — the invariant the old per-conn send locks provided,
-    now without serializing the fan-out behind the router thread.
+    ``scheduled`` is the drain queue's dedup flag: True from the
+    enqueue that hands the conn to the loop's drain queue until a drain
+    visit empties it (a conn parked on EVENT_WRITE mid-frame stays
+    scheduled), so ``_forward`` wakes the loop once per idle→busy edge.
 
     Queue entries are ``(msg_type, parts, hdr, nbytes, rids, region)``:
     for an untraced frame ``hdr`` is None and ``parts`` is the complete
     wire frame; for a TRACED frame ``hdr`` is the parsed header dict (shared
     across an mcast's receiver queues) — or a deferred ``(kind, meta,
     inner header)`` tuple — and ``parts`` holds only the payload tail:
-    the sender worker re-encodes the header line with a fresh
+    the drain re-encodes the header line with a fresh
     ``hub_out`` stamp at drain time, so ``hub_out - hub_in`` is this
     frame's real queue wait and the payload bytes are still the one
     shared immutable object.  ``rids`` is the tuple of node ids the
@@ -234,20 +220,17 @@ class _Conn:
     and never before.
 
     ``heads`` is a strict-priority queue in front of ``frames``: a
-    striped mcast enqueues every receiver's stripe 0 there, and a
-    sender worker always drains heads — across ALL connections — before
-    tail frames, requeuing its connection after the last head so every
-    other connection's pending head drains before any connection's
-    tail.  That is the head-start contract: all K receivers START
-    streaming within one head round instead of the last one waiting
-    behind K-1 whole fan-outs (enqueue order alone cannot guarantee
-    this — tails land while heads are still draining and a paced visit
-    would drain head+tail together).
+    striped mcast enqueues every receiver's stripe 0 there, and the
+    loop's drain batch drains heads — across ALL scheduled connections
+    — before any connection's tail (``_drain_batch``).  That is the
+    head-start contract: all K receivers START streaming within one
+    head round instead of the last one waiting behind K-1 whole
+    fan-outs (enqueue order alone cannot guarantee this — tails land
+    while heads are still draining).
 
-    Reactor-plane fields (``parser``..``want_write``) exist on every
-    conn but are live only under ``mode="reactor"``, where they are
-    touched EXCLUSIVELY by the event-loop thread (single-threaded by
-    construction — the reactor's analogue of the single-drainer rule):
+    The loop-thread fields (``parser``..``want_write``) are touched
+    EXCLUSIVELY by the event-loop thread, so they need no lock and one
+    conn never has two writers interleaving its stream:
     ``parser`` is the conn's streaming ``FrameParser``, ``phase`` the
     handshake state machine (0 = awaiting hello, 1 = clock-sync, 2 =
     registered), ``wpend`` the partial-write continuation (memoryviews
@@ -286,7 +269,7 @@ class _Conn:
         # directions ride its rings while every header stays on this
         # socket (order, control frames, and fallback are the stream's)
         self.lane = lane
-        # reactor-plane state (loop-thread-only; see class docstring)
+        # loop-thread-only state (see class docstring)
         self.parser = None
         self.phase = 0
         self.nid0 = None  # the hello's primary node id (reply target)
@@ -313,18 +296,19 @@ class _Conn:
 class TcpHub:
     """Central router: node_id → connection. Start once per federation.
 
-    Outbound frames ride per-connection bounded queues drained by a
-    small sender pool — a reader thread only ever ENQUEUES (O(1)), so
-    one slow receiver no longer stalls routing for everyone else, and a
-    ``__hub__: mcast`` frame (one payload + a receiver list) fans out
-    by enqueueing the SAME immutable bytes to every receiver: the
-    server→hub broadcast leg carries each sync exactly once."""
+    Outbound frames ride per-connection bounded queues that the event
+    loop drains as each socket turns writable — routing only ever
+    ENQUEUES (O(1)), so one slow receiver parks on EVENT_WRITE instead
+    of stalling routing for everyone else, and a ``__hub__: mcast``
+    frame (one payload + a receiver list) fans out by enqueueing the
+    SAME immutable bytes to every receiver: the server→hub broadcast
+    leg carries each sync exactly once."""
 
-    # lock-discipline contract (fedlint): reader threads, the sender
-    # pool, and the accept path all share these — every touch goes
-    # through self._lock (per-conn payload state lives on _Conn and is
-    # protected by the same lock; the single-drainer rule serializes
-    # the socket itself)
+    # lock-discipline contract (fedlint): the event loop and off-loop
+    # callers (``stats()``, ``sample_telemetry()``, ``stop()``, a
+    # direct ``_forward``) share these — every touch goes through
+    # self._lock (per-conn queue state lives on _Conn and is protected
+    # by the same lock; only the loop thread writes a conn's socket)
     _GUARDED_BY = {
         "_conns": "_lock",
         "_range_conns": "_lock",
@@ -341,33 +325,25 @@ class TcpHub:
         "shm_hub_copies": "_lock",
         "zero_copy_forwards": "_lock",
         "_drainq": "_lock",
-        "_reader_count": "_lock",
     }
 
-    def __init__(self, host: str = "127.0.0.1", port: int = 0,
-                 senders: int = 4, max_queue_bytes: int = 256 << 20,
+    def __init__(self, host: str = "127.0.0.1", port: int = 0, *,
+                 max_queue_bytes: int = 256 << 20,
                  max_queue_frames: int = 4096,
                  stripe_bytes: int = 0, max_inflight_stripes: int = 8,
-                 shm_min_bytes: int = DEFAULT_MIN_BYTES,
-                 mode: Optional[str] = None):
-        self._mode = (mode or os.environ.get(ENV_HUB_MODE)
-                      or "reactor").strip().lower()
-        if self._mode not in ("reactor", "threaded"):
-            raise ValueError(f"unknown hub mode {self._mode!r} "
-                             f"(want 'reactor' or 'threaded')")
+                 shm_min_bytes: int = DEFAULT_MIN_BYTES):
         self._srv = socket.create_server((host, port))
         self.host, self.port = self._srv.getsockname()
         # striped fan-out: an mcast payload larger than ``stripe_bytes``
         # (0 = off, ship whole frames) is split into fixed-size
         # ``__hub__: mcast_stripe`` continuation frames that receivers
-        # reassemble (TcpBackend).  ``max_inflight_stripes`` is the
-        # pacing quantum: a sender-pool worker writes at most that many
-        # frames to ONE connection before rotating to the back of the
-        # ready queue, so all receivers stream concurrently instead of
-        # the last one waiting behind K whole-frame sends — the PR-6
-        # measured fan-out wall (bcast_queue 436.7 ms at 32 clients).
+        # reassemble (TcpBackend), so all receivers stream concurrently
+        # instead of the last one waiting behind K whole-frame sends —
+        # the PR-6 measured fan-out wall (bcast_queue 436.7 ms at 32
+        # clients).  ``max_inflight_stripes`` is read by nothing: the
+        # loop paces every conn by its socket's writability, and the
+        # value is still accepted because ``--stripe-pace`` passes it.
         self._stripe_bytes = max(0, int(stripe_bytes))
-        self._pace = max(1, int(max_inflight_stripes))
         self._sid = itertools.count(1)  # process-unique stripe-stream ids
         self.striped_mcasts = 0
         self.stripe_frames = 0
@@ -424,22 +400,8 @@ class TcpHub:
         self._range_conns: List[_Conn] = []
         self._cids = itertools.count(1)  # per-connection telemetry ids
         self._lock = make_lock("TcpHub._lock")
-        self._ready: "queue.SimpleQueue" = queue.SimpleQueue()
         self._running = True
-        self._reader_count = 0  # live threaded-mode reader threads
-        self._senders: List[threading.Thread] = []
-        if self._mode == "threaded":
-            self._senders = [
-                threading.Thread(target=self._sender_loop, daemon=True)
-                for _ in range(max(1, int(senders)))
-            ]
-            for t in self._senders:
-                t.start()
-            self._accept_thread = threading.Thread(
-                target=self._accept_loop, daemon=True)
-            self._accept_thread.start()
-            return
-        # reactor mode: ONE event-loop thread owns accepts, reads, and
+        # ONE event-loop thread owns accepts, reads, and
         # writability-driven drains for every connection.  The wakeup
         # pipe is the documented off-loop entry point: stop() and any
         # cross-thread _forward tickle it so the sleeping select wakes
@@ -467,16 +429,6 @@ class TcpHub:
         self._loop_thread = threading.Thread(
             target=self._reactor_loop, daemon=True)
         self._loop_thread.start()
-
-    def _accept_loop(self):
-        while self._running:
-            try:
-                conn, _ = self._srv.accept()
-            except OSError:
-                return
-            threading.Thread(
-                target=self._serve_conn, args=(conn,), daemon=True
-            ).start()
 
     @staticmethod
     def _parse_hello(hello_obj: dict):
@@ -526,154 +478,12 @@ class TcpHub:
             )
             return None
 
-    def _serve_conn(self, conn: socket.socket):
-        node_id = None
-        ids: List[int] = []
-        st = None
-        lane = None
-        with self._lock:
-            self._reader_count += 1
-        try:
-            _tune_socket(conn)
-            f = conn.makefile("rb")
-            hello = f.readline()
-            if not hello:
-                return
-            hello_obj = json.loads(hello)
-            try:
-                ids, ranges, mux, node_id = self._parse_hello(hello_obj)
-            except _HubConnError:
-                return
-            lane = self._attach_lane(hello_obj, node_id)
-            # ACK BEFORE registering: once registered, the sender pool
-            # may write to this conn concurrently, and an ACK
-            # interleaved with a routed frame would hand the dialing
-            # client garbage as its handshake line.  A frame routed in
-            # the ack→register window is dropped — but nobody can have
-            # observed this node as registered yet (await_peers reads
-            # the registry), so that is the normal unregistered-
-            # receiver drop, not a race.  Old dialers ignore the extra
-            # "shm" confirmation key.
-            conn.sendall((json.dumps(
-                {**_ACK, "shm": lane is not None}
-            ) + "\n").encode())
-            # clock-sync phase: still UNREGISTERED (no sender worker can
-            # touch this conn), so ping replies may be written directly
-            # by this reader thread and are guaranteed to be the next
-            # line the dialer reads — the request/reply RTT is pure
-            # wire + scheduling, never queue wait.  The dialer ends the
-            # phase with ``ping_done``; registration happens then, so
-            # its min-RTT offset estimate is in hand before any routed
-            # frame can arrive.
-            while True:
-                line = f.readline()
-                if not line:
-                    return
-                try:
-                    frame = json.loads(line)
-                except json.JSONDecodeError:
-                    return  # garbled handshake: connection-fatal
-                kind = frame.get(HUB_KEY)
-                if kind == "ping":
-                    conn.sendall((json.dumps({
-                        HUB_KEY: "pong",
-                        "t0": frame.get("t0"),
-                        "th": time.perf_counter(),
-                    }) + "\n").encode())
-                    continue
-                if kind == "ping_done":
-                    break
-                # pre-handshake peers (an old dialer): fall through to
-                # registration and let the main loop service this line
-                break
-            st = _Conn(conn, ids=ids, mux=mux, lane=lane, ranges=ranges)
-            stale_conns = self._register_conn(st, ids)
-            for old in stale_conns:
-                # drop the fully-displaced conn: its reader sees EOF and
-                # cleans up; queued frames die with it (straggler
-                # semantics, same as any dead receiver)
-                try:
-                    old.sock.shutdown(socket.SHUT_RDWR)
-                except OSError:
-                    pass
-                try:
-                    old.sock.close()
-                except OSError:
-                    pass
-            pending = None if frame.get(HUB_KEY) == "ping_done" \
-                else (line, frame)
-            while True:
-                if pending is not None:
-                    line, frame = pending
-                    pending = None
-                else:
-                    line = f.readline()
-                    if not line:
-                        break
-                    try:
-                        frame = json.loads(line)
-                    except json.JSONDecodeError:
-                        # a garbled header is fatal for the CONNECTION,
-                        # not just the frame: since frames may carry
-                        # binary payloads, the stream cannot
-                        # resynchronize — the "bytes" that follow could
-                        # be an unannounced payload whose tail would
-                        # parse as bogus headers (worst case: a
-                        # fabricated __binlen__ blocks this thread on
-                        # bytes that never arrive).  Dropping the conn
-                        # costs the peer one reconnect (its retry/
-                        # auto_reconnect path), never a wedged router.
-                        break
-                # v2 binary frame: the header announces exactly how many
-                # raw payload bytes follow — read them here so routing
-                # forwards header+payload as ONE unit and the readline
-                # loop never parses payload bytes as lines.  A header
-                # carrying the shm doorbell key maps the payload out of
-                # the connection's slab instead; a torn descriptor is
-                # connection-fatal, exactly like a garbled header.
-                payload = b""
-                region = None
-                binlen = frame.get(FRAME_BINLEN_KEY)
-                sseq = frame.pop(SHM_SEQ_KEY, None)
-                if binlen and sseq is not None:
-                    try:
-                        payload, region = self._laned_payload(
-                            st, node_id, binlen, sseq)
-                    except _HubConnError:
-                        break
-                elif binlen:
-                    payload = f.read(binlen)
-                    if len(payload) < binlen:
-                        break  # peer died mid-payload: torn frame == EOF
-                try:
-                    keep = self._route_frame(st, node_id, frame, line,
-                                             payload, sseq, region)
-                finally:
-                    if region is not None:
-                        region.release()
-                if not keep:
-                    break
-        except OSError:
-            pass  # peer vanished: fall through to cleanup
-        finally:
-            with self._lock:
-                self._reader_count -= 1
-            if st is not None:
-                self._conn_cleanup(st, ids)
-            elif lane is not None:
-                lane.close(unlink=True)
-            try:
-                conn.close()
-            except OSError:
-                pass
-
-    def _register_conn(self, st: _Conn, ids: List[int]) -> List["_Conn"]:
+    def _register_conn(self, st: _Conn) -> List["_Conn"]:
         """Register a handshake-complete connection: apply the rebind
         policy (new conn wins; range claims displace as atoms), install
         the id/range routes, and return the fully-displaced stale conns
-        for the caller to dispose of — the threaded reader shuts their
-        sockets down (their own reader threads then clean up on EOF),
-        the reactor closes them inline through ``_close_conn_r``."""
+        for the caller to close (``_finish_register_r``)."""
+        ids = sorted(st.ids)
         rebound: List[int] = []
         stale_conns: List[_Conn] = []
         ranges = st.ranges
@@ -735,7 +545,7 @@ class TcpHub:
             )
         return stale_conns
 
-    def _laned_payload(self, st: _Conn, node_id, binlen: int, sseq):
+    def _laned_payload(self, st: _Conn, binlen: int, sseq):
         """Resolve one shm doorbell to its payload bytes: a refcounted
         slab pin (``ShmRegion``) on the fast path, a counted
         materialization under pin pressure.  Returns ``(payload,
@@ -745,7 +555,7 @@ class TcpHub:
         if st.lane is None:
             logging.warning(
                 "hub: node %s sent an shm doorbell on a "
-                "lane-less connection — dropping it", node_id,
+                "lane-less connection — dropping it", st.nid0,
             )
             raise _HubConnError("doorbell on a lane-less connection")
         region = None
@@ -764,7 +574,7 @@ class TcpHub:
                                     reason="pin_pressure")
             else:
                 # zero-copy: the routing queues hold refcounted PINS
-                # into the slab — the drain plane releases each
+                # into the slab — the drain releases each
                 # entry's reference, and the reader's own reference
                 # dies right after routing
                 region = st.lane.read(sseq, binlen)
@@ -772,7 +582,7 @@ class TcpHub:
         except ShmLaneError as e:
             logging.warning(
                 "hub: shm lane error from node %s (%s) — "
-                "dropping connection", node_id, e,
+                "dropping connection", st.nid0, e,
             )
             raise _HubConnError(str(e)) from None
         with self._lock:
@@ -784,10 +594,9 @@ class TcpHub:
         """Release every queued entry of a connection that will never
         drain again: count the drops, release each entry's payload
         pin.  The one place queued refcounts die outside the drain
-        path — called on conn death (both planes) and at ``stop()``,
-        so a churn soak's pin count provably returns to zero.  (The
-        PR-13-era leak this closes: ``stop()`` exited the sender
-        workers by sentinel with pinned entries still queued.)"""
+        path — called on conn death and by a ``stop()`` whose loop
+        wedged, so a churn soak's pin count provably returns to
+        zero."""
         with self._lock:
             leftovers = [(e[0], e[4], e[5]) for e in st.heads]
             leftovers += [(e[0], e[4], e[5]) for e in st.frames]
@@ -800,12 +609,11 @@ class TcpHub:
             if reg_ is not None:
                 reg_.release()
 
-    def _conn_cleanup(self, st: _Conn, ids) -> None:
+    def _conn_cleanup(self, st: _Conn) -> None:
         """Tear one connection down: deregister the ids/ranges still
         mapping here, flush + release its queued entries, dump the
         black box for a live death, and close the lane + socket.
-        Shared by the threaded reader's ``finally`` and the reactor's
-        ``_close_conn_r``."""
+        The tail of ``_close_conn_r``."""
         lost: List[int] = []
         lost_ranges = 0
         with self._lock:
@@ -813,7 +621,7 @@ class TcpHub:
             # identity guard: a re-registered node may have
             # been rebound to a newer conn; deregister only the
             # ids still mapping HERE
-            for nid in ids:
+            for nid in list(st.ids):
                 if self._conns.get(nid) is st:
                     self._conns.pop(nid, None)
                     lost.append(nid)
@@ -824,10 +632,8 @@ class TcpHub:
                 self._range_conns.remove(st)
                 lost_ranges = sum(
                     hi - lo + 1 for lo, hi in st.ranges)
-        # release queued pins NOW: in threaded mode a scheduled sender
-        # worker also clears dead conns (whoever pops under the lock
-        # wins — never a double release), but a conn dying between
-        # visits, or at stop(), must not strand its regions
+        # release queued pins NOW: a dead conn is never drained again
+        # (_drain_conn returns on st.dead), so nothing else would
         self._flush_conn_queues(st)
         if lost_ranges and self._running:
             flight.note("events", "conn_death", cid=st.cid,
@@ -871,20 +677,19 @@ class TcpHub:
         except OSError:
             pass
 
-    # -- reactor data plane --------------------------------------------------
+    # -- event loop ----------------------------------------------------------
     #
     # Everything below runs on the ONE event-loop thread (plus _wake,
-    # which any thread may call).  Per-conn reactor fields (parser,
-    # phase, wpend, wcommit, wregion, want_write) are loop-thread-only
-    # by construction — the reactor's analogue of the single-drainer
-    # rule — while the routing/queue state stays under self._lock
-    # exactly as in threaded mode.
+    # which any thread may call).  Per-conn loop fields (parser, phase,
+    # wpend, wcommit, wregion, want_write) are loop-thread-only by
+    # construction, while the routing/queue state stays under
+    # self._lock.
 
     def _reactor_loop(self):
         """The hub's event loop: one thread multiplexing accepts,
         streaming reads (header/payload/doorbell), and writability-
         driven queue drains for every connection — O(1) threads per
-        hub where the threaded plane burns one reader per conn."""
+        hub."""
         self._loop_tid = threading.get_ident()
         tel = get_telemetry()
         sel = self._sel
@@ -1023,8 +828,7 @@ class TcpHub:
                 except Exception:
                     # never lose the LOOP to a routing bug — that
                     # would be every connection wedged at once; the
-                    # faulting conn dies alone (threaded parity: an
-                    # unexpected error killed that reader thread)
+                    # faulting conn dies alone
                     logging.exception(
                         "hub: reactor error on conn cid=%s", st.cid)
                     keep = False
@@ -1039,9 +843,9 @@ class TcpHub:
                   region) -> bool:
         """Handle ONE parsed frame: the handshake state machine
         (hello → clock-sync → registered), then the routing dispatch
-        shared with the threaded reader.  Returns False when the
-        connection must close.  Owns ``region`` (the parser handed the
-        reader's reference over) and releases it before returning."""
+        (``_route_frame``).  Returns False when the connection must
+        close.  Owns ``region`` (the parser handed the reader's
+        reference over) and releases it before returning."""
         if st.phase == 0:
             if region is not None:
                 region.release()  # a hello never carries a payload
@@ -1051,9 +855,10 @@ class TcpHub:
             st.mux = mux
             st.nid0 = nid0
             st.lane = self._attach_lane(frame, nid0)
-            # ACK BEFORE registering (same contract as the threaded
-            # plane: nothing can be routed here until registration, so
-            # the queued ACK is the next bytes the dialer reads)
+            # ACK BEFORE registering: nothing can be routed here until
+            # registration, so the queued ACK is the next bytes the
+            # dialer reads.  Old dialers ignore the "shm" confirmation
+            # key.
             self._ctrl_reply(st, {**_ACK, "shm": st.lane is not None})
             st.phase = 1
             return True
@@ -1080,20 +885,18 @@ class TcpHub:
         binlen = frame.get(FRAME_BINLEN_KEY)
         sseq = frame.pop(SHM_SEQ_KEY, None)
         if binlen and sseq is not None:
-            payload, region = self._laned_payload(
-                st, st.nid0, binlen, sseq)
+            payload, region = self._laned_payload(st, binlen, sseq)
         try:
-            return self._route_frame(st, st.nid0, frame, line,
-                                     payload, sseq, region)
+            return self._route_frame(st, frame, line, payload, sseq,
+                                     region)
         finally:
             if region is not None:
                 region.release()
 
     def _finish_register_r(self, st: _Conn) -> None:
-        """Registration, reactor side: install the routes (shared
-        rebind policy) and dispose of the displaced conns inline —
-        there is no per-conn reader thread to notice their EOF."""
-        stale = self._register_conn(st, sorted(st.ids))
+        """Registration: install the routes (rebind policy) and close
+        the displaced conns inline."""
+        stale = self._register_conn(st)
         for old in stale:
             self._close_conn_r(old)
         st.phase = 2
@@ -1101,8 +904,7 @@ class TcpHub:
     def _ctrl_reply(self, st: _Conn, obj: dict) -> None:
         """Queue a handshake control line (ACK, pong) on the conn's own
         frame queue: before registration nothing else can enqueue to
-        it, so the reply is the next bytes the dialer reads — the
-        threaded plane's direct-write guarantee, in queue form."""
+        it, so the reply is the next bytes the dialer reads."""
         data = (json.dumps(obj) + "\n").encode()
         with self._lock:
             if st.dead:
@@ -1114,13 +916,13 @@ class TcpHub:
                 self._drainq.append(st)
 
     def _drain_batch(self):
-        """Drain every conn the batch scheduled.  Head-start contract,
-        reactor form: a strict-priority first pass drains queued heads
-        (stripe 0s) across ALL conns before any conn's tail pass — so
-        every receiver starts streaming within one batch — then tails
-        drain to each socket until it would block; the kernel's socket
-        buffer is the pacing, and a blocked conn parks on EVENT_WRITE
-        instead of holding a worker."""
+        """Drain every conn the batch scheduled.  Head-start contract:
+        a strict-priority first pass drains queued heads (stripe 0s)
+        across ALL conns before any conn's tail pass — so every
+        receiver starts streaming within one batch — then tails drain
+        to each socket until it would block; the kernel's socket buffer
+        is the pacing, and a blocked conn parks on EVENT_WRITE instead
+        of holding up the loop."""
         with self._lock:
             if not self._drainq:
                 return
@@ -1134,9 +936,8 @@ class TcpHub:
     def _drain_conn(self, st: _Conn, heads_only: bool = False) -> None:
         """Drain one conn's queues onto its non-blocking socket until
         empty (unschedule + unpark) or the socket would block (park on
-        EVENT_WRITE).  Frame bytes are built by the SAME helpers as
-        the threaded sender pool (``_entry_wire``/``_prepare_send``),
-        so the two planes are byte-identical by construction."""
+        EVENT_WRITE).  Frame bytes are built by ``_entry_wire`` and
+        ``_prepare_send``."""
         if st.dead:
             return
         while True:
@@ -1191,8 +992,7 @@ class TcpHub:
                     st, hdr_dict, line, body)
             except Exception:
                 # never lose the loop to a drain bug: the frame dies
-                # (counted), the conn keeps draining — threaded-plane
-                # parity with the sender worker's catch-all
+                # (counted), the conn keeps draining
                 logging.exception("hub: reactor drain error for "
                                   "conn cid=%s", st.cid)
                 if region is not None:
@@ -1235,8 +1035,7 @@ class TcpHub:
             return False
         except OSError:
             # dead receiver: count the in-flight frame, tear the conn
-            # down (cleanup flushes + releases everything queued) —
-            # the sender pool's OSError contract, reactor form
+            # down (cleanup flushes + releases everything queued)
             self._count_drop(st.nid0 if st.nid0 is not None else -1,
                              st.wmsg_type)
             self._close_conn_r(st)
@@ -1281,9 +1080,9 @@ class TcpHub:
         st.want_write = False
 
     def _close_conn_r(self, st: _Conn) -> None:
-        """Reactor-side conn teardown: selector deregistration, the
-        parser's in-progress region, the in-flight frame's pin, then
-        the shared cleanup (deregister ids, flush queued pins, flight
+        """Conn teardown: selector deregistration, the parser's
+        in-progress region, the in-flight frame's pin, then
+        ``_conn_cleanup`` (deregister ids, flush queued pins, flight
         triggers, lane + socket close).  Idempotent — displacement and
         a same-batch EOF may both reach here."""
         if st.parser is None and st.dead:
@@ -1301,19 +1100,15 @@ class TcpHub:
         st.wcommit = None
         st.wpend = []
         st.want_write = False
-        self._conn_cleanup(st, list(st.ids))
+        self._conn_cleanup(st)
 
-    def _wake(self, st: _Conn, receiver) -> None:
-        """Hand a newly-scheduled conn to its drain plane: the sender
-        pool's ready queue (threaded), or the loop's drain queue plus
+    def _wake(self, st: _Conn) -> None:
+        """Hand a newly-scheduled conn to the loop's drain queue plus
         — when called OFF the loop thread — a wakeup-pipe tickle so a
-        sleeping select services it now.  That pipe is the reactor's
-        one cross-thread entry point: stop() uses it too, and chaos
+        sleeping select services it now.  That pipe is the loop's one
+        cross-thread entry point: stop() uses it too, and chaos
         timer-delayed deliveries need nothing special (they arrive as
         ordinary socket readability on the injecting backend's conn)."""
-        if self._mode == "threaded":
-            self._ready.put((receiver, st))
-            return
         with self._lock:
             self._drainq.append(st)
         if threading.get_ident() != self._loop_tid:
@@ -1325,15 +1120,15 @@ class TcpHub:
         except (BlockingIOError, OSError):
             pass  # pipe full: a wakeup is already pending
 
-    def _route_frame(self, st: _Conn, node_id: int, frame: dict,
-                     line: bytes, payload, sseq, region) -> bool:
-        """Route ONE inbound frame (header parsed, payload in hand) —
-        the body of the reader loop's dispatch.  Returns False when the
+    def _route_frame(self, st: _Conn, frame: dict, line: bytes,
+                     payload, sseq, region) -> bool:
+        """Route ONE inbound frame (header parsed, payload in hand)
+        from ``st``'s primary node id.  Returns False when the
         connection must close (a ``stop`` frame), True otherwise.
 
         ``payload`` is bytes on the inline-TCP path and a slab
         MEMORYVIEW on the zero-copy lane path; ``region`` is the
-        backing pin (or None).  The caller releases the reader's own
+        backing pin (or None).  The caller releases the parser's own
         reference right after this returns, so every enqueue below
         retains per entry (``_forward`` / ``_forward_stripes``) — the
         routing layer itself never copies a laned payload."""
@@ -1370,11 +1165,11 @@ class TcpHub:
             # traced mcast (outer header flags it): split the
             # inner frame at its header line ONCE, stamp hub_in,
             # and queue (parsed header, shared payload-tail
-            # view) per receiver — the sender worker re-encodes
+            # view) per receiver — the drain re-encodes
             # the small header per copy with its own hub_out
             # stamp while the multi-MB tail stays one object.
             # Mux wraps (traced AND untraced) are DEFERRED
-            # (kind, meta, hdr) entries: the worker builds the
+            # (kind, meta, hdr) entries: the drain builds the
             # outer line at drain, filtering the target nodes
             # against the conn's live id set — a rebind while
             # the copy waits must not be fanned out to the
@@ -1424,7 +1219,7 @@ class TcpHub:
                 # enumerated — the whole point is O(edges) state
                 reply_obj["ranges"] = peer_ranges
             self._forward(
-                node_id,
+                st.nid0,
                 ((json.dumps(reply_obj) + "\n").encode(),),
             )
             return True
@@ -1452,7 +1247,7 @@ class TcpHub:
                 # the authority on the claim either way)
                 reply["conn_ranges"] = range_cids
             self._forward(
-                node_id,
+                st.nid0,
                 ((json.dumps(reply) + "\n").encode(),),
             )
             return True
@@ -1462,8 +1257,8 @@ class TcpHub:
         if receiver is not None:
             if trace_ctx.TRACE_KEY in frame:
                 # traced unicast: the line IS the header — stamp
-                # hub_in on the parsed dict and let the sender
-                # worker re-encode it with hub_out at drain
+                # hub_in on the parsed dict and let the drain
+                # re-encode it with hub_out
                 trace_ctx.hub_stamp(frame, "hub_in")
                 self._forward(receiver,
                               (payload,) if payload else (),
@@ -1540,13 +1335,13 @@ class TcpHub:
     def _forward(self, receiver: int, parts: Tuple, msg_type=None,
                  hdr=None, nbytes=None, rids=None, conn=None,
                  region=None) -> bool:
-        """Enqueue one frame for ``receiver``; the sender pool writes
+        """Enqueue one frame for ``receiver``; the loop's drain writes
         it.  Untraced (``hdr=None``): ``parts`` is the COMPLETE frame
         (header line [+ payload]).  Traced: ``hdr`` is the parsed
         header dict (already ``hub_in``-stamped; shared across an
         mcast's receiver queues) — or a deferred ``(kind, meta, inner
         header)`` tuple — and ``parts`` holds only the payload tail:
-        the sender worker re-encodes the header line at drain time.
+        the drain re-encodes the header line.
         Unknown receivers and over-bound queues drop the frame —
         counted, by design (the round deadline treats the receiver as a
         straggler).  Returns False when the frame was dropped (mux-group
@@ -1558,8 +1353,8 @@ class TcpHub:
         representative id in between — the wrong peer entirely.
 
         ``region`` is the slab pin backing a zero-copy laned payload:
-        retained BEFORE the entry becomes drainable (a sender worker
-        may pop and release it the instant the lock drops) and released
+        retained BEFORE the entry becomes drainable (the loop may pop
+        and release it the instant the lock drops) and released
         back on the drop path — the entry's reference must exist
         exactly when the entry does."""
         if nbytes is None:
@@ -1596,7 +1391,7 @@ class TcpHub:
             get_telemetry().inc("hub.zero_copy_forwards",
                                 msg_type=msg_type or "?")
         if wake:
-            self._wake(st, receiver)
+            self._wake(st)
         return True
 
     def _fan_out_striped(self, frame: dict, groups, mt,
@@ -1613,7 +1408,7 @@ class TcpHub:
 
         Traced frames keep the per-receiver queue-wait measurement:
         stripe 0 carries the parsed inner header dict instead of bytes,
-        and the sender worker re-encodes that line with a fresh
+        and the drain re-encodes that line with a fresh
         ``hub_out`` stamp at drain time (crc computed then) — exactly
         the whole-frame traced contract, per copy.
         """
@@ -1644,19 +1439,15 @@ class TcpHub:
         with self._lock:
             self.striped_mcasts += 1
         # head-start scheduling: EVERY connection's stripe 0 rides the
-        # strict-priority head queue, so the pool drains all head
-        # stripes (small) before any tail — every receiver starts
+        # strict-priority head queue, so the drain batch writes all
+        # head stripes (small) before any tail — every receiver starts
         # streaming within one head round (bcast_queue ≈ that round,
-        # not K-1 whole-frame sends) — and then drains tails at
-        # ``max_inflight_stripes`` locality, which staggers COMPLETION
-        # times so receivers that finish early overlap their downstream
-        # work with the rest of the fan-out (measured: full round-robin
-        # equalizes completion and serializes the cohort's post-receive
-        # compute AFTER the fan-out window; see PROFILE.md round-9).
+        # not K-1 whole-frame sends) — and then drains each conn's
+        # tails until its socket would block (``_drain_batch``).
         for cst, rids in groups:
             if hdr is not None:
                 # deferred stripe 0: (kind, outer meta, inner header
-                # dict) — the worker builds outer line + restamped
+                # dict) — the drain builds outer line + restamped
                 # inner line at drain.  nbytes is the original line's
                 # length (queue accounting only; the restamp grows it
                 # by one hop).
@@ -1667,7 +1458,7 @@ class TcpHub:
                 head_entry = (mt, (), (MCAST_STRIPE_KIND, meta0, hdr),
                               len(payload) - len(body) + 64)
             elif cst.mux:
-                # untraced mux stripe 0 is ALSO deferred: the worker
+                # untraced mux stripe 0 is ALSO deferred: the drain
                 # re-encodes the small outer header at drain with the
                 # ``nodes`` list filtered to the conn's live ids (a
                 # rebind mid-queue-wait must drop the stolen id from
@@ -1705,7 +1496,6 @@ class TcpHub:
         nbytes = sum(e[3] for e in entries)
         wake = False
         dropped = False
-        receiver = receivers[0]
         # tag the (shared-buffer) entries with this connection's target
         # ids — the drain's rebind re-check needs them; the buffers
         # themselves stay shared across connections
@@ -1738,149 +1528,13 @@ class TcpHub:
                 self._count_drop(r, msg_type)
             return
         if wake:
-            self._wake(st, receiver)
-
-    def _sender_loop(self):
-        """Sender-pool worker: drain the one connection handed to it.
-        A worker only ever services the exact ``_Conn`` it was
-        scheduled for (never a same-id replacement), so a reconnecting
-        node can't end up with two drainers interleaving its stream.
-
-        Pacing: after ``max_inflight_stripes`` frames to one conn the
-        worker re-queues it at the BACK of the ready queue and moves
-        on (``scheduled`` stays True, so there is still exactly one
-        drainer).  Combined with the head-start enqueue order
-        (``_fan_out_striped``) this bounds how long any receiver's
-        stream can monopolize a worker: small pace = round-robin fair
-        streaming (equal completion), large pace = locality draining
-        (staggered completion, better when receivers share cores with
-        the hub).
-        """
-        while True:
-            item = self._ready.get()
-            if item is None:
-                return
-            nid, st = item
-            quantum = 0
-            while True:
-                requeue = False
-                from_head = False
-                stale_rids = False
-                live_nodes = None  # filtered mux/stripe-0 target list
-                stale_subset: Tuple = ()
-                dead_leftovers = None
-                region = None
-                with self._lock:
-                    if st.dead:
-                        # replaced/deregistered: frames die with it —
-                        # COUNTED, like the OSError path's leftovers
-                        # (the rebind policy promises visible drops)
-                        dead_leftovers = [(e[0], e[4], e[5])
-                                          for e in st.heads]
-                        dead_leftovers += [(e[0], e[4], e[5])
-                                           for e in st.frames]
-                        st.heads.clear()
-                        st.frames.clear()
-                        st.nbytes = 0
-                    elif st.heads:
-                        # strict priority, quantum-exempt: heads are
-                        # small and the head-start contract wants all
-                        # of them out before any conn's tail
-                        msg_type, parts, hdr, nbytes, rids, region = \
-                            st.heads.popleft()
-                        st.nbytes -= nbytes
-                        from_head = True
-                    elif not st.frames:
-                        st.scheduled = False
-                        break
-                    elif quantum >= self._pace:
-                        requeue = True
-                    else:
-                        msg_type, parts, hdr, nbytes, rids, region = \
-                            st.frames.popleft()
-                        st.nbytes -= nbytes
-                    if not requeue and dead_leftovers is None:
-                        stale_rids, live_nodes, stale_subset = \
-                            self._stale_check_locked(st, rids, hdr)
-                if dead_leftovers is not None:
-                    for mt_, rids_, reg_ in dead_leftovers:
-                        for r in rids_ or ():
-                            self._count_drop(r, mt_)
-                        if reg_ is not None:
-                            reg_.release()
-                    break
-                if requeue:
-                    self._ready.put((nid, st))
-                    break
-                # a head send exhausts the visit's quantum: the worker
-                # requeues before touching this conn's TAIL, so every
-                # other conn's pending head drains first (the requeue
-                # lands behind them in the FIFO ready queue)
-                quantum = self._pace if from_head else quantum + 1
-                if stale_rids:
-                    # every id this entry addressed was rebound away
-                    if region is not None:
-                        region.release()
-                    for r in rids:
-                        self._count_drop(r, msg_type)
-                    continue
-                if live_nodes is not None:
-                    # partially-rebound mux copy: the stolen ids lose
-                    # this frame (counted), the live ones still get it
-                    for r in stale_subset:
-                        self._count_drop(r, msg_type)
-                try:
-                    hdr_dict, line, body = self._entry_wire(
-                        parts, hdr, live_nodes)
-                    self._conn_send(st, hdr_dict, line, body, msg_type)
-                except OSError:
-                    # dead receiver: count this frame + everything still
-                    # queued, deregister (its reader thread finishes
-                    # cleanup when it sees EOF)
-                    if region is not None:
-                        region.release()
-                    self._count_drop(nid, msg_type)
-                    with self._lock:
-                        st.dead = True
-                        for i in list(st.ids):
-                            if self._conns.get(i) is st:
-                                self._conns.pop(i, None)
-                        leftovers = [(e[0], e[4], e[5])
-                                     for e in st.heads]
-                        leftovers += [(e[0], e[4], e[5])
-                                      for e in st.frames]
-                        st.heads.clear()
-                        st.frames.clear()
-                        st.nbytes = 0
-                    for mt_, rids_, reg_ in leftovers:
-                        for r in rids_ or (nid,):
-                            self._count_drop(r, mt_)
-                        if reg_ is not None:
-                            reg_.release()
-                    break
-                except Exception:
-                    # never lose a pool worker to an unexpected bug —
-                    # the hub would silently shrink its send capacity.
-                    # Count the frame lost and KEEP DRAINING: breaking
-                    # here would leave st.scheduled=True with frames
-                    # queued and no drainer — a permanently wedged
-                    # receiver (worse than the bug being survived)
-                    logging.exception("hub: sender worker error for "
-                                      "node %s", nid)
-                    if region is not None:
-                        region.release()
-                    self._count_drop(nid, msg_type)
-                    continue
-                if region is not None:
-                    # sent: this entry's slab pin dies here — when the
-                    # LAST queue's copy drains, the ring reclaims
-                    region.release()
+            self._wake(st)
 
     def _stale_check_locked(self, st: _Conn, rids,
                             hdr):  # fedlint: holds=_lock
-        """Drain-time rebind re-check, shared by both drain planes: any
-        id a queued entry targets may have been claimed by a NEWER
-        connection while the frame sat queued — the displaced owner
+        """Drain-time rebind re-check: any id a queued entry targets
+        may have been claimed by a NEWER connection while the frame
+        sat queued — the displaced owner
         must not deliver to it (straggler drop, exactly the policy's
         "old conn loses it").  Deferred mux/stripe-0 entries carry
         their target list in ``meta['nodes']`` and get it FILTERED to
@@ -1904,10 +1558,7 @@ class TcpHub:
 
     @staticmethod
     def _entry_wire(parts, hdr, live_nodes):
-        """Build ONE queue entry's wire form — a byte-identity
-        chokepoint shared by both drain planes (the sender pool and the
-        reactor build identical frames by construction).  Three entry
-        shapes:
+        """Build ONE queue entry's wire form.  Three entry shapes:
 
         - deferred tuple ``(kind, meta, inner_hdr)``: build the outer
           header at drain time — around the hub_out-restamped inner
@@ -1964,10 +1615,10 @@ class TcpHub:
 
     def _prepare_send(self, st: _Conn, hdr_dict, line, body):
         """Lane-or-inline decision + final header encoding for one
-        outbound frame — the second shared chokepoint: both drain
-        planes ship exactly what this returns.  Exactly one of
-        ``hdr_dict`` (still a dict — drain-built outer headers, traced
-        restamps) and ``line`` (already-encoded bytes) is set on entry;
+        outbound frame: the drain ships exactly what this returns.
+        Exactly one of ``hdr_dict`` (still a dict — drain-built outer
+        headers, traced restamps) and ``line`` (already-encoded bytes)
+        is set on entry;
         ``body`` holds the payload parts.  Lane refusal (ring full,
         descriptor queue full, oversized) falls back to the inline
         write, per frame, counted — never an error and never a stall.
@@ -1996,19 +1647,6 @@ class TcpHub:
         if hdr_dict is not None:
             line = (json.dumps(hdr_dict) + "\n").encode()
         return ([line, *body] if body else [line]), None
-
-    def _conn_send(self, st: _Conn, hdr_dict, line, body, msg_type) -> None:
-        """Threaded-plane frame write: blocking vectored send of
-        whatever ``_prepare_send`` decided, then the lane commit.
-        OSErrors propagate to the caller's dead-receiver handling."""
-        parts, commit = self._prepare_send(st, hdr_dict, line, body)
-        _sendall_parts(st.sock, parts)
-        if commit is not None:
-            lane, pending, nbody = commit
-            lane.commit(pending)
-            with self._lock:
-                self.shm_frames += 1
-                self.shm_bytes += nbody
 
     def _count_drop(self, receiver: int, msg_type) -> None:
         mt = msg_type or HUB_KEY
@@ -2052,36 +1690,21 @@ class TcpHub:
                  if c.lane is not None}
             )
             snap["range_conns"] = len(self._range_conns)
-            snap["mode"] = self._mode
-            snap["threads"] = self._thread_count_locked()
+            snap["threads"] = 1  # the loop: O(1) in connections
             snap["open_fds"] = self._open_fds_locked()
         return snap
 
-    def _thread_count_locked(self) -> int:  # fedlint: holds=_lock
-        """Hub-owned thread count — the reactor's O(1)-in-connections
-        claim as a measurement: 1 loop thread, vs the threaded plane's
-        accept thread + sender pool + one reader per live conn."""
-        assert_held(self._lock, "TcpHub._thread_count_locked")
-        if self._mode == "reactor":
-            return 1
-        return 1 + len(self._senders) + self._reader_count
-
     def _open_fds_locked(self) -> int:  # fedlint: holds=_lock
-        """Descriptors the hub holds open (reactor: everything the
-        selector watches — server + wakeup pipe + conns; threaded:
-        conn sockets + the server)."""
+        """Descriptors the hub holds open: everything the selector
+        watches — server + wakeup pipe + conns."""
         assert_held(self._lock, "TcpHub._open_fds_locked")
-        if self._mode == "reactor":
-            try:
-                fd_map = self._sel.get_map()
-            except RuntimeError:
-                fd_map = None
-            # a closed selector returns None (3.11) or raises — either
-            # way stop() raced the sample and the honest answer is 0
-            return len(fd_map) if fd_map is not None else 0
-        conns = set(map(id, self._conns.values()))
-        conns.update(map(id, self._range_conns))
-        return len(conns) + 1
+        try:
+            fd_map = self._sel.get_map()
+        except RuntimeError:
+            fd_map = None
+        # a closed selector returns None (3.11) or raises — either way
+        # stop() raced the sample and the honest answer is 0
+        return len(fd_map) if fd_map is not None else 0
 
     def sample_telemetry(self, telemetry=None) -> dict:
         """Snapshot ``stats()`` + per-CONNECTION send-queue depths into
@@ -2110,7 +1733,6 @@ class TcpHub:
                 if st.lane is not None:
                     shm_conns += 1
             snap = self._counters_snapshot()
-            hub_threads = self._thread_count_locked()
             open_fds = self._open_fds_locked()
         for cid, (nframes, nbytes, nids) in depths.items():
             t.gauge_set("hub.send_queue_frames", nframes, conn=cid)
@@ -2132,10 +1754,10 @@ class TcpHub:
         t.gauge_set("hub.shm_frames_total", snap["shm_frames"])
         t.gauge_set("hub.shm_bytes_total", snap["shm_bytes"])
         t.gauge_set("hub.shm_fallbacks_total", snap["shm_fallbacks"])
-        # reactor-plane health: thread inventory (the O(1) claim as a
-        # series) and watched-descriptor count; hub.loop_lag_s (the
-        # per-batch histogram) is observed by the loop itself
-        t.gauge_set("hub.threads", hub_threads)
+        # loop health: thread inventory (the O(1) claim as a series)
+        # and watched-descriptor count; hub.loop_lag_s (the per-batch
+        # histogram) is observed by the loop itself
+        t.gauge_set("hub.threads", 1)
         t.gauge_set("hub.open_fds", open_fds)
         t.event(
             "hub_stats", t_m=trace_ctx.now(),
@@ -2152,44 +1774,26 @@ class TcpHub:
 
     def stop(self):
         self._running = False
-        if self._mode == "reactor":
-            # wake the sleeping select; the loop's exit path tears every
-            # conn down through _close_conn_r (pins flushed + released)
-            self._tickle()
-            self._loop_thread.join(timeout=10)
-            if self._loop_thread.is_alive():
-                # loop wedged (should not happen): at least release the
-                # queued pins so a soak's leak accounting stays clean
-                with self._lock:
-                    states = (set(self._conns.values())
-                              | set(self._range_conns))
-                for st in states:
-                    self._flush_conn_queues(st)
-                    try:
-                        st.sock.close()
-                    except OSError:
-                        pass
+        # wake the sleeping select; the loop's exit path tears every
+        # conn down through _close_conn_r (pins flushed + released)
+        self._tickle()
+        self._loop_thread.join(timeout=10)
+        if self._loop_thread.is_alive():
+            # loop wedged (should not happen): at least release the
+            # queued pins so a soak's leak accounting stays clean
+            with self._lock:
+                states = (set(self._conns.values())
+                          | set(self._range_conns))
+            for st in states:
+                self._flush_conn_queues(st)
                 try:
-                    self._srv.close()
+                    st.sock.close()
                 except OSError:
                     pass
-            return
-        with self._lock:
-            states = set(self._conns.values()) | set(self._range_conns)
-        for st in states:
             try:
-                st.sock.close()
+                self._srv.close()
             except OSError:
                 pass
-        for _ in self._senders:
-            self._ready.put(None)
-        # the workers exit via the sentinel WITHOUT visiting their
-        # queues: release every still-queued payload pin here, or a
-        # stop with in-flight laned frames leaks their slab/pool
-        # references (the dead-receiver cleanup satellite's stop() leg)
-        for st in states:
-            self._flush_conn_queues(st)
-        self._srv.close()
 
 
 class TcpBackend(CommBackend):
@@ -2197,8 +1801,8 @@ class TcpBackend(CommBackend):
 
     ``auto_reconnect`` (attempts; 0 = off) makes ``run()`` survive a
     dropped hub connection: on EOF/reset the backend dials again,
-    re-registers (the hub's identity guard swaps the live conn,
-    ``TcpHub._serve_conn``), and resumes the read loop.  Frames routed
+    re-registers (the hub's rebind policy swaps the live conn,
+    ``TcpHub._register_conn``), and resumes the read loop.  Frames routed
     while disconnected are lost — by design the round-deadline server
     (``fedavg_cross_device``) treats the node as a straggler for that
     round and it rejoins at the next sync.
@@ -2360,9 +1964,9 @@ class TcpBackend(CommBackend):
                     get_telemetry().inc("comm.shm_fallbacks",
                                         reason="attach")
                 # handshake phase 2: the hub does NOT register this
-                # conn until it reads ``ping_done`` (before that, its
-                # reader thread can reply to clock-sync pings directly
-                # with no sender-pool interleaving risk) — so EVERY
+                # conn until it reads ``ping_done`` (before that,
+                # nothing can be routed to this conn, so each pong is
+                # the next line it sends here) — so EVERY
                 # dialer must end the phase, even an untraced one that
                 # sends no pings, or a receive-only node would never
                 # register and every frame to it would drop
@@ -2409,9 +2013,9 @@ class TcpBackend(CommBackend):
 
     def _clock_sync(self, sock: socket.socket, f, pings: int = 8) -> None:
         """NTP-style handshake ping burst (tracing on only): the hub is
-        still in its pre-registration phase for this conn, so replies
-        are written directly by its reader thread — the RTT is pure
-        wire + scheduling, never sender-pool queue wait.  The min-RTT
+        still in its pre-registration phase for this conn, so nothing
+        else is queued ahead of a reply — the RTT is pure wire + one
+        hub loop batch, never routed-frame queue wait.  The min-RTT
         sample's midpoint estimates this process's monotonic-clock
         offset to the hub (``trace_ctx.estimate_offset``), recorded as
         a ``clock_sync`` telemetry event — what lets the timeline

@@ -100,8 +100,8 @@ GAUGES = {
     "hub.shm_fallbacks_total": "cumulative hub-side lane fallbacks to inline TCP",
     "hub.mcast_frames_total": "cumulative mcast frames (time series form)",
     "hub.stripe_frames_total": "cumulative enqueued mcast stripes (time series form)",
-    "hub.threads": "hub-owned OS threads (reactor: 1; threaded: accept + senders + readers)",
-    "hub.open_fds": "descriptors the hub holds open (reactor: selector map size)",
+    "hub.threads": "hub-owned OS threads (the one event-loop thread)",
+    "hub.open_fds": "descriptors the hub holds open (selector map size)",
     "jax.device_mem_bytes": "device memory in use {device=}",
     "jax.device_mem_peak_bytes": "high-water device memory {device=}",
     "digest.streams": "distinct digest source streams the rollup has seen",

@@ -963,13 +963,13 @@ def test_striped_traced_hop_chain_has_reasm_stamp():
 
 
 def test_sender_pool_pacing_single_worker_interleaves_receivers():
-    """With ONE sender worker and pace=1 every receiver still streams:
-    the worker rotates a connection to the back of the ready queue
-    after each stripe instead of draining one receiver's whole
-    sequence first."""
+    """Every receiver of a striped fan-out streams: the hub's one loop
+    drains every receiver's head stripe before any tail, then each
+    receiver's tails until its socket would block, so no receiver
+    starves behind another's whole sequence."""
     import time
 
-    hub = TcpHub(senders=1, stripe_bytes=8 << 10, max_inflight_stripes=1)
+    hub = TcpHub(stripe_bytes=8 << 10, max_inflight_stripes=1)
     got = {1: [], 2: [], 3: []}
 
     class Obs:
@@ -995,7 +995,7 @@ def test_sender_pool_pacing_single_worker_interleaves_receivers():
     while any(not got[i] for i in (1, 2, 3)) and time.monotonic() < deadline:
         time.sleep(0.02)
     for i in (1, 2, 3):
-        assert got[i], f"node {i} starved under pace=1/senders=1"
+        assert got[i], f"node {i} starved during the striped fan-out"
         np.testing.assert_array_equal(np.asarray(got[i][0].get("model")),
                                       payload)
     for b in receivers:

@@ -226,71 +226,6 @@ def test_mux_partial_rebind_keeps_other_virtual_ids_alive():
         hub.stop()
 
 
-def test_rebind_kills_already_queued_frames_for_stolen_id(monkeypatch):
-    """The rebind policy holds for IN-FLIGHT frames too: a frame queued
-    on the old connection for an id that is rebound while it waits is
-    dropped at drain (counted), never delivered to the displaced
-    owner.  Pinned to the THREADED plane: the gate below blocks a
-    sender-pool worker mid-write, which only exists there (the reactor
-    counterpart — would-block park + rebind — lives in
-    test_reactor.py)."""
-    import threading
-
-    from fedml_tpu.comm import tcp as tcp_mod
-
-    gate = threading.Event()
-    real_sendall = tcp_mod._sendall_parts
-    blocked_once = threading.Event()
-    hub = TcpHub(senders=1, mode="threaded")
-
-    def gated_sendall(sock, parts):
-        # block the hub's (single) sender worker on the FIRST test
-        # frame it writes, so the next one sits queued behind it while
-        # we rebind its target id — client-side writes and hub control
-        # replies (peers/ack) go through here too and must pass
-        if (threading.current_thread() in hub._senders
-                and b'"QF"' in bytes(parts[0])
-                and not blocked_once.is_set()):
-            blocked_once.set()
-            gate.wait(timeout=20)
-        real_sendall(sock, parts)
-    got = {}
-    mux = claimer = sender = None
-    try:
-        monkeypatch.setattr(tcp_mod, "_sendall_parts", gated_sendall)
-        mux = TcpMuxBackend([1, 2], hub.host, hub.port)
-        for i in (1, 2):
-            mux.virtual(i).add_observer(_Collect(got, i))
-        mux.run_in_thread()
-        sender = TcpBackend(9, hub.host, hub.port)
-        sender.await_peers([1, 2])
-        m1 = Message("QF", 9, 1)
-        m1.add_params("x", 1)
-        sender.send_message(m1)  # worker blocks mid-write of this one
-        _wait(lambda: blocked_once.is_set())
-        m2 = Message("QF", 9, 2)
-        m2.add_params("x", 2)
-        sender.send_message(m2)  # queued behind m1 on the mux conn
-        claimer = TcpBackend(2, hub.host, hub.port)  # rebinds id 2
-        claimer.add_observer(_Collect(got, "claimer"))
-        claimer.run_in_thread()
-        _wait(lambda: hub.stats()["node_rebinds"] == 1)
-        gate.set()  # un-block the drain
-        _wait(lambda: got.get(1))
-        time.sleep(0.3)
-        # the queued frame for the stolen id died (straggler drop) —
-        # neither the displaced muxer nor the new owner got THAT copy
-        assert not got.get(2)
-        assert not got.get("claimer")
-        assert hub.stats()["dropped_frames"].get("QF", 0) == 1
-    finally:
-        gate.set()
-        for b in (mux, claimer, sender):
-            if b is not None:
-                b.stop()
-        hub.stop()
-
-
 def test_per_virtual_node_chaos_decisions_on_shared_conn():
     """FaultRule parity: a recv drop rule scoped to virtual node 2
     drops ONLY node 2's copy of a broadcast that arrived as one shared

@@ -1,7 +1,7 @@
 """Reactor hub data plane (PR 20): the streaming frame parser, pooled
-refcounted payload buffers, reactor-vs-threaded parity behaviors, the
-dead-receiver/rebind pin-release contract, and the high-connection
-accept/churn soak.
+refcounted payload buffers, one-loop-thread behaviors, the
+dead-receiver/rebind/partial-write pin-release contract, and the
+high-connection accept/churn soak.
 
 Fast tests drive the parser/pool against hand-torn byte streams (no
 sockets) and a real reactor ``TcpHub`` over loopback with hand-rolled
@@ -237,10 +237,16 @@ def test_parser_close_releases_partial_payload():
 
 # -- reactor hub over loopback -----------------------------------------------
 
-def _dial_raw(host, port, node_id, timeout=10.0):
+def _dial_raw(host, port, node_id, timeout=10.0, rcvbuf=None):
     """Hand-rolled minimal dialer: hello v1 + ping_done, no reader
-    thread.  Returns the connected socket (registered at the hub)."""
-    s = socket.create_connection((host, port), timeout=timeout)
+    thread.  Returns the connected socket (registered at the hub).
+    ``rcvbuf`` sizes SO_RCVBUF before connecting (the window a
+    non-reading receiver offers)."""
+    s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+    if rcvbuf:
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+    s.settimeout(timeout)
+    s.connect((host, port))
     f = s.makefile("rb")
     s.sendall((json.dumps({"node_id": node_id}) + "\n").encode())
     ack = json.loads(f.readline())
@@ -254,7 +260,6 @@ def test_reactor_is_default_and_single_threaded():
     hub = TcpHub()
     socks = []
     try:
-        assert hub.stats()["mode"] == "reactor"
         for i in range(64):
             socks.append(_dial_raw(hub.host, hub.port, 100 + i))
         _wait(lambda: hub.stats()["connections"] == 64)
@@ -307,9 +312,8 @@ def test_reactor_garbled_header_drops_conn_only():
 def test_reactor_binary_garbage_drops_conn_only():
     """Regression: non-UTF-8 bytes with a newline used to raise
     UnicodeDecodeError past the FrameError handler and kill the single
-    reactor thread — wedging EVERY connection on the hub, where
-    threaded mode lost only the one conn.  The hostile conn must die
-    alone and the loop must keep accepting."""
+    reactor thread — wedging EVERY connection on the hub.  The hostile
+    conn must die alone and the loop must keep accepting."""
     hub = TcpHub()
     socks = []
     try:
@@ -353,7 +357,7 @@ def test_reactor_hostile_binlen_drops_conn_only():
 
 def test_reactor_rebind_kills_already_queued_frames_for_stolen_id(
         monkeypatch):
-    """Reactor counterpart of the threaded in-flight rebind test: a
+    """The rebind policy holds for IN-FLIGHT frames too: a
     frame still QUEUED for an id when the id rebinds to a newer conn is
     dropped at drain (counted), never delivered to the displaced owner.
     The drain visit for the target conn is held off (not blocked — the
@@ -362,7 +366,7 @@ def test_reactor_rebind_kills_already_queued_frames_for_stolen_id(
 
     gate = threading.Event()
     real_drain = tcp_mod.TcpHub._drain_conn
-    hub = TcpHub(mode="reactor")
+    hub = TcpHub()
     held = []
 
     def gated_drain(self, st, heads_only=False):
@@ -394,7 +398,7 @@ def test_reactor_rebind_kills_already_queued_frames_for_stolen_id(
         claimer.run_in_thread()
         _wait(lambda: hub.stats()["node_rebinds"] == 1)
         gate.set()
-        hub._wake(held[0], 2)  # re-offer the held conn to the loop
+        hub._wake(held[0])  # re-offer the held conn to the loop
         _wait(lambda: hub.stats()["dropped_frames"].get("QF", 0) == 1)
         time.sleep(0.2)
         # neither the displaced muxer nor the new owner got THAT copy
@@ -408,8 +412,7 @@ def test_reactor_rebind_kills_already_queued_frames_for_stolen_id(
         hub.stop()
 
 
-@pytest.mark.parametrize("mode", ["reactor", "threaded"])
-def test_rebind_soak_releases_every_queued_pin(mode):
+def test_rebind_soak_releases_every_queued_pin():
     """Satellite leak contract: soak rebinds of one id with pinned
     entries still queued on the displaced conn — every pin must be
     released (drained, dropped, or flushed at cleanup/stop) and the
@@ -433,7 +436,7 @@ def test_rebind_soak_releases_every_queued_pin(mode):
             with _Pin.lock:
                 _Pin.lives -= 1
 
-    hub = TcpHub(mode=mode)
+    hub = TcpHub()
     payload = b"p" * 2048
     line = (json.dumps(
         {"msg_type": "LK", FRAME_BINLEN_KEY: len(payload)}) + "\n"
@@ -455,8 +458,7 @@ def test_rebind_soak_releases_every_queued_pin(mode):
                 pin.release()  # the enqueuer's own reference
         hub.stop()  # flushes whatever is still queued
         assert _Pin.lives == 0
-        if mode == "reactor":
-            assert hub._bufpool.live == 0
+        assert hub._bufpool.live == 0
     finally:
         for s in socks:
             try:
@@ -466,13 +468,56 @@ def test_rebind_soak_releases_every_queued_pin(mode):
         hub.stop()
 
 
+@pytest.mark.parametrize("teardown", ["peer_closes", "hub_stops"])
+def test_partial_write_pin_released_on_teardown(teardown):
+    """A frame larger than the socket buffers, routed to a receiver
+    that stops reading, parks its conn on EVENT_WRITE mid-frame with
+    the payload's pooled region held as ``wregion``.  Whether the
+    receiver's socket closes or the hub stops, the teardown must
+    release that pin: ``_bufpool.live`` returns to 0."""
+    hub = TcpHub()
+    stopped = False
+    socks = []
+    try:
+        recv = _dial_raw(hub.host, hub.port, 5, rcvbuf=4096)
+        sender = _dial_raw(hub.host, hub.port, 9)
+        socks += [recv, sender]
+        _wait(lambda: hub.stats()["connections"] == 2)
+        st = hub._conns[5]
+        # larger than everything the kernel can hold in flight (the
+        # hub's send buffer plus the receiver's small window), so the
+        # drain must stop part-way through the frame
+        sndbuf = st.sock.getsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF)
+        payload = b"w" * (sndbuf + (4 << 20))
+        sender.sendall(_frame_bytes(
+            {"msg_type": "PW", "receiver": 5}, payload))
+        _wait(lambda: st.want_write and st.wregion is not None)
+        assert st.wpend  # parked mid-frame, bytes still to write
+        assert hub._bufpool.live == 1
+        assert hub.stats()["threads"] == 1
+        if teardown == "peer_closes":
+            recv.close()
+            _wait(lambda: hub.stats()["connections"] == 1)
+            assert hub.stats()["threads"] == 1
+        else:
+            hub.stop()
+            stopped = True
+        _wait(lambda: hub._bufpool.live == 0)
+        assert st.wregion is None and st.dead
+    finally:
+        for s in socks:
+            s.close()
+        if not stopped:
+            hub.stop()
+
+
 @pytest.mark.slow
 def test_512_conn_accept_churn_soak():
     """The scaling claim, measured: 512 live connections on ONE loop
-    thread (threaded mode would burn ~513 hub threads), accept latency
+    thread, accept latency
     flat through churn, and no pooled-buffer leak after 3 churn waves
     of 128 closes + 128 re-dials."""
-    hub = TcpHub(mode="reactor")
+    hub = TcpHub()
     socks = {}
     try:
         lat = []

@@ -29,6 +29,12 @@ Mixer kinds (``DecoderConfig.layer_types``, one a layer):
   (``ops/linear_attention.py``), behind short causal depthwise convolutions,
   with low-rank decay and output gates and a gated RMSNorm a head
   (``LinearAttention``).  Position comes from the recurrence.
+- ``mamba``: the Mamba-2 mixer (``Mamba2Mixer``), the SSD recurrence with a
+  scalar decay a head (``ops/ssd.py``) behind a biased causal depthwise
+  convolution, with a skip and an RMSNorm by groups gated by ``silu(z)``.
+- ``none``: no mixer; the layer is its MLP alone.  A layer of
+  ``hybrid_override_pattern`` holds a mixer alone or an MLP alone (MLP kind
+  ``none``): one pre-norm, one sublayer, one residual.
 
 Any softmax kind RMS-norms its q and k heads before the rotation where the
 configuration says ``qk_norm``.  Rotary positions come from ``rope_parameters``
@@ -55,7 +61,11 @@ not how it weighs them, outside the gradient.
 
 MLP kinds (``mlp_types``): ``sparse``, the expert layer below, with a shared
 expert (a ``GatedMLP`` every token passes) added outside the routed sum where
-the configuration has one; ``dense``, one ``GatedMLP``.  Router kinds
+the configuration has one; ``dense``, one ``GatedMLP``.  Where
+``mlp_act`` is ``relu2`` both are ``Relu2MLP`` and the experts too are a
+squared ReLU without a gate; where ``moe_latent_size`` is set the routed
+experts work in that width, between a down-projection of the normed input
+and an up-projection of their weighted sum.  Router kinds
 (``router_activation``): ``softmax`` over all routed experts, or ``sigmoid``
 scores; either renormalises the chosen ``top_k`` where ``norm_topk_prob``
 and scales them by ``routed_scaling_factor``.  With a ``selection_bias``
@@ -93,7 +103,7 @@ import numpy as np
 
 from fedml_tpu.models.base import COUNTERS, ModelBundle
 from fedml_tpu.models.transformer import (
-    AttnFn, MultiHeadAttention, RMSNorm, _default_attn, scoped,
+    AttnFn, MultiHeadAttention, RMSNorm, _default_attn, scaled_init, scoped,
 )
 from fedml_tpu.obs import scopes
 from fedml_tpu.ops.expert_rows import (
@@ -101,11 +111,19 @@ from fedml_tpu.ops.expert_rows import (
 )
 from fedml_tpu.ops.linear_attention import gated_delta_rule, short_causal_conv
 from fedml_tpu.ops.sparse_select import select_topk, tiles_scored
+from fedml_tpu.ops.ssd import ssd_chunked
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 LINEAR, LATENT = "linear_attention", "latent_attention"
 SELECTED = "sparse_attention"  # keys chosen by an indexer
+SSM = "mamba"  # a Mamba-2 state-space mixer
 SPARSE, DENSE = "sparse", "dense"
+NONE = "none"  # a layer without a mixer, or without an MLP
+# a layer kind a character of ``hybrid_override_pattern`` names: (mixer, MLP)
+PATTERN = {"M": (SSM, NONE), "*": (FULL, NONE), "E": (NONE, SPARSE),
+           "-": (NONE, DENSE)}
+# the activation of an MLP or an expert: a SiLU gate, or a squared ReLU
+SILU_GATED, RELU2 = "silu_gated", "relu2"
 # the scalar counters ``DecoderLM`` sows into ``COUNTERS`` in train mode
 ASSIGNMENTS_HELD = "moe_assignments_held"  # token-expert pairs on held experts
 EXPERT_TOKENS_MAX = "moe_expert_tokens_max"  # the fullest held expert's rows
@@ -113,6 +131,8 @@ ROWS_BUFFERED = "moe_rows_buffered"  # rows of the buffer the layer took
 ROWS_COMPUTED = "moe_rows_computed"  # rows the grouped products computed
 # a linear-attention layer's mean log decay over tokens, heads and channels
 KDA_LOG_DECAY_MEAN = "kda_log_decay_mean"
+# an SSM layer's mean of ``dt * A`` (the log decay) over tokens and heads
+SSM_LOG_DECAY_MEAN = "ssm_log_decay_mean"
 # a SELECTED layer's count of 512 x 512 causal tiles that hold a chosen pair
 ATTN_TILES_LIVE = "attn_tiles_live"
 # a SELECTED layer's count of tiles whose index scores were computed: the
@@ -138,7 +158,8 @@ class DecoderConfig:
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    layer_types: Tuple[str, ...]  # one a layer: SLIDING, FULL, LINEAR, LATENT, SELECTED
+    # one a layer: SLIDING, FULL, LINEAR, LATENT, SELECTED, SSM or NONE
+    layer_types: Tuple[str, ...]
     sliding_window: int
     rope: Tuple[Tuple[str, Tuple[Tuple[str, object], ...]], ...]  # by layer type
     rms_norm_eps: float
@@ -149,12 +170,14 @@ class DecoderConfig:
     norm_topk_prob: bool
     max_len: int
     remat: bool = False
-    mlp_types: Tuple[str, ...] = ()  # one a layer: SPARSE or DENSE; () all SPARSE
+    # one a layer: SPARSE, DENSE or NONE; () all SPARSE
+    mlp_types: Tuple[str, ...] = ()
     intermediate_size: int = 0  # the dense MLP's width
     shared_expert_size: int = 0  # the shared expert's width; 0: none
     router_activation: str = "softmax"  # or "sigmoid"
     routed_scaling_factor: float = 1.0
-    # LINEAR layers: heads, head size, convolution taps, the scan's chunk
+    # LINEAR layers: heads, head size; LINEAR and SSM layers: convolution
+    # taps, the scan's chunk
     linear_heads: int = 0
     linear_head_dim: int = 0
     conv_kernel: int = 4
@@ -176,6 +199,17 @@ class DecoderConfig:
     # the router's selection bias: None, no leaf; else the std of its initial
     # values (a leaf [routed], added to the scores for the choice alone)
     selection_bias: Optional[float] = None
+    # SSM layers: heads, head size, groups of B and C, state size, a bias on
+    # the convolution
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv_bias: bool = False
+    mlp_act: str = SILU_GATED  # of the dense MLP and every expert
+    moe_latent_size: int = 0  # routed experts in this width; 0: hidden size
+    # on the initial weights of every residual branch's last projection
+    residual_scale: float = 1.0
 
     @classmethod
     def from_dict(cls, c: dict) -> "DecoderConfig":
@@ -198,14 +232,39 @@ class DecoderConfig:
         ``route_scale``; experts in groups (``n_group`` / ``topk_group``
         other than 1) are refused.
         ``selection_bias_init_std`` states a selection bias and the std its
-        leaf starts from.  ``mup_enabled`` scales the embedding's output by
+        leaf starts from.
+
+        A ``hybrid_override_pattern`` gives every layer, a character each
+        (``PATTERN``): ``M`` a Mamba-2 mixer alone (``mamba_num_heads`` of
+        ``mamba_head_dim``, ``n_groups`` of B and C, ``ssm_state_size``,
+        ``conv_kernel``, ``chunk_size``, ``use_conv_bias``), ``*``
+        full attention alone, ``E`` the expert layer alone, ``-`` the dense
+        MLP alone.  ``mlp_hidden_act`` ``relu2`` makes every MLP and expert
+        a squared ReLU without a gate; ``moe_latent_size`` puts the routed
+        experts in that width between a down- and an up-projection;
+        ``moe_shared_expert_intermediate_size`` is the shared expert's width
+        where given; ``n_routed_experts`` the router's width and
+        ``layer_norm_epsilon`` every norm's eps.  ``rescale_prenorm_residual``
+        starts the last projection of every residual branch (the Mamba and
+        attention output projections, an MLP's down-projection, the routed
+        sum's up-projection) at its lecun-normal init over
+        ``sqrt(num_hidden_layers)``: GPT-2's rule, which the key names
+        (a linear-attention layer's output projection is not scaled).
+
+        ``mup_enabled`` scales the embedding's output by
         ``sqrt(hidden_size)``.  ``attention_gate`` switches the gate on
         the attention output, ``post_norm`` the norms after the sublayers;
         ``rope_layer_types`` names the layer kinds a top-level ``rope_theta``
         rotates (default: every softmax kind)."""
         depth = c.get("n_layer", c.get("num_hidden_layers"))
         linear = c.get("linear_attn_config") or {}
-        if linear:
+        pattern = c.get("hybrid_override_pattern")
+        if pattern:
+            if not set(pattern) <= set(PATTERN):
+                raise ValueError(f"hybrid_override_pattern {pattern!r}: only "
+                                 f"the layers {sorted(PATTERN)} are built")
+            kinds, mlp = zip(*(PATTERN[ch] for ch in pattern))
+        elif linear:
             kinds = [LINEAR if i + 1 in linear["kda_layers"]
                      else LATENT if c.get("kv_lora_rank") else FULL
                      for i in range(depth)]
@@ -213,17 +272,29 @@ class DecoderConfig:
             kinds = c.get("layer_types") or [
                 SELECTED if c.get("sa_config") else FULL]
         leading = c.get("first_k_dense_replace", c.get("num_dense_layers", 0))
-        mlp = c.get("mlp_layer_types") or [
-            DENSE if i < leading else SPARSE for i in range(depth)]
-        if not set(mlp) <= {SPARSE, DENSE}:
-            raise ValueError(f"MLP kinds {sorted(set(mlp))}: only "
-                             f"{SPARSE!r} and {DENSE!r} are built")
+        if not pattern:
+            mlp = c.get("mlp_layer_types") or [
+                DENSE if i < leading else SPARSE for i in range(depth)]
+            if not set(mlp) <= {SPARSE, DENSE}:
+                raise ValueError(f"MLP kinds {sorted(set(mlp))}: only "
+                                 f"{SPARSE!r} and {DENSE!r} are built")
+        act = {"silu": SILU_GATED, "relu2": RELU2}.get(
+            c.get("mlp_hidden_act", "silu"))
+        if act is None or c.get("mamba_hidden_act", "silu") != "silu" \
+                or c.get("mamba_proj_bias"):
+            raise ValueError(
+                f"mlp_hidden_act {c.get('mlp_hidden_act')!r} / "
+                f"mamba_hidden_act {c.get('mamba_hidden_act')!r} / "
+                f"mamba_proj_bias {c.get('mamba_proj_bias')!r}: only a SiLU "
+                "gate or relu2 MLP and a SiLU Mamba mixer without "
+                "projection biases are built")
         if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
             raise ValueError(
                 f"n_group {c.get('n_group')} / topk_group "
                 f"{c.get('topk_group')}: a choice among groups of experts is "
                 "not built, only a plain top-k (both 1)")
-        routed = c.get("num_experts_routed", c["num_experts"])
+        routed = c.get("num_experts_routed",
+                       c.get("n_routed_experts", c["num_experts"]))
         held = tuple(c.get("experts_held", range(c["num_experts"])))
         if len(held) != c["num_experts"] or not all(
                 0 <= e < routed for e in held):
@@ -255,7 +326,8 @@ class DecoderConfig:
             sliding_window=c.get("sliding_window"),
             rope=tuple(sorted((k, tuple(sorted(v.items())))
                               for k, v in rope.items())),
-            rms_norm_eps=c.get("rms_norm_eps", 1e-6),
+            rms_norm_eps=c.get("rms_norm_eps",
+                               c.get("layer_norm_epsilon", 1e-6)),
             moe_intermediate_size=c["moe_intermediate_size"],
             num_experts_routed=routed, experts_held=held,
             top_k=c.get("num_experts_per_tok", c.get("num_experts_per_token")),
@@ -265,16 +337,18 @@ class DecoderConfig:
             remat=c.get("remat", False),
             mlp_types=tuple(mlp[i % len(mlp)] for i in range(depth)),
             intermediate_size=c.get("intermediate_size", 0),
-            shared_expert_size=(c.get("num_shared_experts", 0)
-                                * c["moe_intermediate_size"]),
+            shared_expert_size=c.get(
+                "moe_shared_expert_intermediate_size",
+                c.get("num_shared_experts", 0) * c["moe_intermediate_size"]),
             router_activation=c.get("moe_router_activation_func",
                                     c.get("score_func", "softmax")),
             routed_scaling_factor=c.get("routed_scaling_factor",
                                         c.get("route_scale", 1.0)),
             linear_heads=c.get("linear_attn_heads", linear.get("num_heads", 0)),
             linear_head_dim=linear.get("head_dim", 0),
-            conv_kernel=linear.get("short_conv_kernel_size", 4),
-            chunk=c.get("chunk", 64),
+            conv_kernel=linear.get("short_conv_kernel_size",
+                                   c.get("conv_kernel", 4)),
+            chunk=c.get("chunk", c.get("chunk_size", 64)),
             kv_lora_rank=c.get("kv_lora_rank") or 0,
             qk_nope_head_dim=c.get("qk_nope_head_dim", 0),
             qk_rope_head_dim=c.get("qk_rope_head_dim", 0),
@@ -288,6 +362,15 @@ class DecoderConfig:
             embed_scale=(math.sqrt(c["hidden_size"])
                          if c.get("mup_enabled") else 1.0),
             selection_bias=c.get("selection_bias_init_std"),
+            ssm_heads=c.get("mamba_num_heads", 0),
+            ssm_head_dim=c.get("mamba_head_dim", 0),
+            ssm_groups=c.get("n_groups", 1),
+            ssm_state=c.get("ssm_state_size", 0),
+            ssm_conv_bias=c.get("use_conv_bias", False),
+            mlp_act=act,
+            moe_latent_size=c.get("moe_latent_size") or 0,
+            residual_scale=(1 / math.sqrt(c["num_hidden_layers"])
+                            if c.get("rescale_prenorm_residual") else 1.0),
         )
 
 
@@ -449,15 +532,29 @@ _kept_for.defvjp(lambda computed, kept: (kept, None),
                  lambda _, g: (g, None))
 
 
-def _expert_rows(capacity, aligned, kept, x, top_p, w_gate, w_up, w_down,
-                 local, order, rank, group_sizes, group_rows):
+def _activated(product, gated: bool):
+    """An expert's activation in float32 of its input products, ``product(i)``
+    the i-th: gated, ``silu(gate) * up`` (products 0 and 1), else
+    ``relu(up) ** 2`` (product 0).  A product is asked for where it is used,
+    so ``_every_expert`` computes the gate's before the up product's, as the
+    accepted cells' programs (and their compile cache's keys) have it."""
+    if gated:
+        return (jax.nn.silu(product(0).astype(jnp.float32))
+                * product(1).astype(jnp.float32))
+    return jnp.square(jax.nn.relu(product(0).astype(jnp.float32)))
+
+
+def _expert_rows(capacity, aligned, kept, x, top_p, weights, local, order,
+                 rank, group_sizes, group_rows):
     """(the held experts' part of the layer's output [T, h] in the experts'
     dtype, the buffers a backward reads again: the rows, their weights, their
-    two grouped products and the gated product) through a row buffer of
+    input products and the activated product) through a row buffer of
     ``capacity`` rows (static; at least ``group_rows.sum()``), each group on
     a row tile of the grouped products where ``aligned`` (static).
 
-    ``x`` [T, h], ``top_p`` [T, k]; ``local`` [T, k] is the local index of
+    ``x`` [T, h], ``top_p`` [T, k]; ``weights`` the stacked expert matrices:
+    the input products' (gate and up, or up alone: ``_activated``), then the
+    output product's; ``local`` [T, k] is the local index of
     each assignment's expert (the count of experts held: not here), ``order``
     and ``rank`` lay the assignments out in the buffer (``sort_assignments``),
     ``group_sizes`` [E] counts each held expert's assignments and
@@ -475,6 +572,7 @@ def _expert_rows(capacity, aligned, kept, x, top_p, w_gate, w_up, w_down,
     cross as ``C`` scalars out of ``T x k``, and come back as a scatter of
     ``C`` into ``T x k``."""
     keep = iter(kept or ())
+    *w_in, w_down = weights
 
     def buffer(computed):
         return _kept_for(computed, next(keep)) if kept else computed
@@ -482,26 +580,25 @@ def _expert_rows(capacity, aligned, kept, x, top_p, w_gate, w_up, w_down,
     with jax.named_scope(scopes.MOE_DISPATCH):
         route = sorted_route(local, order, rank, group_sizes, capacity,
                              group_rows)
-        rows = buffer(to_buffer(x.astype(w_gate.dtype), route))
+        rows = buffer(to_buffer(x.astype(w_down.dtype), route))
         # a pad's entry of ``order`` is past the assignments: it reads 0
         row_w = buffer(jnp.where(
             route.row_live, top_p.reshape(-1).at[order[:capacity]].get(
                 unique_indices=True, mode="fill", fill_value=0), 0))
 
     with jax.named_scope(scopes.MOE_EXPERTS):
-        gate = buffer(_grouped_dot(rows, w_gate, group_rows, aligned))
-        up = buffer(_grouped_dot(rows, w_up, group_rows, aligned))
-        act = buffer((jax.nn.silu(gate.astype(jnp.float32))
-                      * up.astype(jnp.float32) * row_w[:, None]
-                      ).astype(w_down.dtype))
+        products = [buffer(_grouped_dot(rows, w, group_rows, aligned))
+                    for w in w_in]
+        act = buffer((_activated(products.__getitem__, len(w_in) == 2)
+                      * row_w[:, None]).astype(w_down.dtype))
         out = _grouped_dot(act, w_down, group_rows, aligned)
 
     with jax.named_scope(scopes.MOE_COMBINE):
         y = from_buffer(out, route)
-    return y, (rows, row_w, gate, up, act)
+    return y, (rows, row_w, *products, act)
 
 
-def _every_expert(x, top_p, w_gate, w_up, w_down, local):
+def _every_expert(x, top_p, weights, local):
     """The same output with no row buffer: every held expert over every token,
     weighted by the token's routing weight for it or 0 (``local`` [T, k]: the
     local index of each assignment's expert).  The products of the worst case
@@ -510,20 +607,19 @@ def _every_expert(x, top_p, w_gate, w_up, w_down, local):
     and each expert computes its forward again in the backward.  (As three
     matmuls batched over the experts it held 0.22 GiB more and, never run,
     cost the cell 3 % of its tokens/s: PERF.md §6, PR 33.)"""
-    rows = x.astype(w_gate.dtype)
+    rows = x.astype(weights[-1].dtype)
 
     def one(y, expert):
-        e, gate_w, up_w, down_w = expert
+        e, *w_in, down_w = expert
         weight = jnp.where(local == e, top_p, 0).sum(axis=-1, keepdims=True)
-        act = (jax.nn.silu((rows @ gate_w).astype(jnp.float32))
-               * (rows @ up_w).astype(jnp.float32) * weight)
+        act = _activated(lambda i: rows @ w_in[i], len(w_in) == 2) * weight
         return y + (act.astype(down_w.dtype) @ down_w).astype(jnp.float32), None
 
     with jax.named_scope(scopes.MOE_EXPERTS):
         y, _ = jax.lax.scan(
             jax.checkpoint(one), jnp.zeros(x.shape, jnp.float32),
-            (jnp.arange(w_gate.shape[0]), w_gate, w_up, w_down))
-    return y.astype(w_down.dtype)
+            (jnp.arange(weights[0].shape[0]), *weights))
+    return y.astype(weights[-1].dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -535,7 +631,7 @@ def _expert_rows_fitted(capacity, aligned, fits, *operands):
     own rule splits each branch into a part that makes residuals and a part
     that reads them, hands the second ``cond`` the union of both branches'
     residuals, and has each branch zero-fill the other's and copy the
-    operands it reads again.  Here the buffered branch keeps its own five
+    operands it reads again.  Here the buffered branch keeps its own
     buffers, the other keeps nothing, and neither copies an operand."""
     return _fitted_fwd(capacity, aligned, fits, *operands)[0]
 
@@ -544,8 +640,8 @@ def _fitted_fwd(capacity, aligned, fits, *operands):
     buffered = functools.partial(_expert_rows, capacity, aligned, None)
     _, kept = jax.eval_shape(buffered, *operands)
 
-    def unbuffered(x, top_p, w_gate, w_up, w_down, local, *_):
-        return (_every_expert(x, top_p, w_gate, w_up, w_down, local),
+    def unbuffered(x, top_p, weights, local, *_):
+        return (_every_expert(x, top_p, weights, local),
                 tuple(jnp.zeros(b.shape, b.dtype) for b in kept))
 
     y, kept = jax.lax.cond(fits, buffered, unbuffered, *operands)
@@ -571,7 +667,13 @@ _expert_rows_fitted.defvjp(_fitted_fwd, _fitted_bwd)
 
 
 class ExpertLayer(nn.Module):
-    """Dropless top-k mixture of gated SiLU experts over the experts held.
+    """Dropless top-k mixture of gated SiLU experts over the experts held
+    (``expert_act`` ``RELU2``: squared-ReLU experts ``relu(x U)^2 D``, no
+    ``gate`` leaf).  With a ``latent_size`` the experts take and give rows of
+    that width: the normed input goes down to it (``latent_down``) before the
+    rows go to the buffer, and the weighted sum comes back up
+    (``latent_up``) once, under ``model.moe_latent``; the router reads the
+    input at full width.
 
     ``x`` [B, L, h] float32 (the normed input).  Router and selection run in
     float32; the expert products run in the dtype of the expert weights.
@@ -630,20 +732,30 @@ class ExpertLayer(nn.Module):
     router_activation: str = "softmax"  # or "sigmoid": a score an expert
     routed_scaling_factor: float = 1.0  # on the chosen experts' weights
     selection_bias: Optional[float] = None  # the std of the bias leaf's init
+    expert_act: str = SILU_GATED  # or RELU2: no gate leaf
+    latent_size: int = 0  # experts in this width between two projections
+    out_scale: float = 1.0  # on the initial weights of the last projection
 
     @nn.compact
     def __call__(self, x):
         B, L, h = x.shape
         T, k, held = B * L, self.top_k, len(self.experts_held)
         A = T * k  # assignments
+        width = self.latent_size or h  # the experts' input and output
         short, worst = buffer_capacities(T, k, held, self.num_experts_routed)
         init = nn.initializers.lecun_normal()
         stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                                batch_axis=0)
         router = self.param("router", init, (h, self.num_experts_routed))
-        w_gate = self.param("gate", stacked, (held, h, self.intermediate))
-        w_up = self.param("up", stacked, (held, h, self.intermediate))
-        w_down = self.param("down", stacked, (held, self.intermediate, h))
+        if self.expert_act not in (SILU_GATED, RELU2):
+            raise ValueError(f"unknown expert activation {self.expert_act!r}")
+        last = 1.0 if self.latent_size else self.out_scale
+        weights = tuple(
+            self.param(name, stacked, (held, width, self.intermediate))
+            for name in (("gate", "up") if self.expert_act == SILU_GATED
+                         else ("up",))
+        ) + (self.param("down", scaled_init(last, stacked),
+                        (held, self.intermediate, width)),)
         x = x.reshape(T, h)
 
         with jax.named_scope(scopes.MOE_ROUTER):
@@ -683,7 +795,8 @@ class ExpertLayer(nn.Module):
         # the grouped products' row tile at the buffer's length: the short
         # buffer starts every group on one, the worst case has no room for pads
         aligned = short < worst
-        tiling = gmm_tiling(min(short, worst), h, self.intermediate, aligned)
+        tiling = gmm_tiling(min(short, worst), width, self.intermediate,
+                            aligned)
         tile = tiling[0] if tiling else 1
         align = tile if aligned else 1
         with jax.named_scope(scopes.MOE_DISPATCH):
@@ -702,8 +815,13 @@ class ExpertLayer(nn.Module):
                                            align - 1)
             computed = rows_computed(group_rows, tile)
 
-        operands = (x, top_p, w_gate, w_up, w_down, local, order, rank,
-                    group_sizes, group_rows)
+        rows = x
+        if self.latent_size:
+            with jax.named_scope(scopes.MOE_LATENT):
+                w_in = self.param("latent_down", init, (h, width))
+                rows = x.astype(w_in.dtype) @ w_in
+        operands = (rows, top_p, weights, local, order, rank, group_sizes,
+                    group_rows)
         if not aligned:
             y, _ = _expert_rows(worst, False, None, *operands)
             buffered = float(worst)
@@ -721,7 +839,12 @@ class ExpertLayer(nn.Module):
         }
         if moved is not None:
             counters[TOKENS_BIAS_MOVED] = moved
-        return y.astype(w_down.dtype).reshape(B, L, h), counters
+        y = y.astype(weights[-1].dtype)
+        if self.latent_size:
+            with jax.named_scope(scopes.MOE_LATENT):
+                y = y @ self.param("latent_up", scaled_init(self.out_scale),
+                                   (width, h))
+        return y.reshape(B, L, h), counters
 
 
 class GatedMLP(nn.Module):
@@ -729,14 +852,38 @@ class GatedMLP(nn.Module):
     and the shared expert beside the routed ones."""
 
     width: int
+    out_scale: float = 1.0  # on the down-projection's initial weights
 
     @nn.compact
     def __call__(self, x):
         gate = nn.Dense(self.width, use_bias=False, name="gate")(x)
         up = nn.Dense(self.width, use_bias=False, name="up")(x)
         act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-        return nn.Dense(x.shape[-1], use_bias=False, name="down")(
+        return nn.Dense(x.shape[-1], use_bias=False, name="down",
+                        kernel_init=scaled_init(self.out_scale))(
             act.astype(x.dtype))
+
+
+class Relu2MLP(nn.Module):
+    """``relu(x Wu)^2 Wd`` with no bias and no gate: the dense MLP and the
+    shared expert where the configuration's activation is ``relu2``."""
+
+    width: int
+    out_scale: float = 1.0  # on the down-projection's initial weights
+
+    @nn.compact
+    def __call__(self, x):
+        up = nn.Dense(self.width, use_bias=False, name="up")(x)
+        act = jnp.square(jax.nn.relu(up.astype(jnp.float32)))
+        return nn.Dense(x.shape[-1], use_bias=False, name="down",
+                        kernel_init=scaled_init(self.out_scale))(
+            act.astype(x.dtype))
+
+
+def _mlp(c: DecoderConfig, width: int, name: str) -> nn.Module:
+    return (GatedMLP if c.mlp_act == SILU_GATED else Relu2MLP)(
+        width, c.residual_scale, name=name)
+
 
 
 class LatentQKV(nn.Module):
@@ -907,6 +1054,87 @@ class LinearAttention(nn.Module):
             return dense(h, "o_proj", o.astype(x.dtype)), g.mean()
 
 
+# a fresh Mamba-2 mixer's ``dt``: log-uniform in [1e-3, 1e-1], floored at
+# 1e-4 (Mamba-2's ``dt_min``, ``dt_max``, ``dt_init_floor``)
+DT_INIT = (1e-3, 1e-1, 1e-4)
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer.  ``x`` [B, L, h], the normed input; with ``d = H
+    P``:
+
+    ``[z | xBC | dt] = x W_in`` (widths d, d + 2 G N, H; no bias);
+    ``xBC = silu(conv(xBC) + b)``, a causal depthwise convolution of
+    ``conv_kernel`` taps (``short_causal_conv``) and its bias where
+    ``conv_bias``; ``[x | B | C] = xBC``; ``dt = softplus(dt + dt_bias)``
+    and ``A = -exp(A_log)`` a head; ``y = ssd(x, dt, A, B, C) + D x``
+    (``ops/ssd.py``, chunks of ``chunk``); ``y`` gated by ``silu(z)`` and
+    RMS-normed in ``groups`` groups of ``d / G`` channels, times a weight
+    [d]; projected back.  Convolution, ``dt``, the decay, the skip and the
+    gated norm are float32; the scan's products take ``x``'s dtype.
+    Initialised as Mamba-2 is: ``A_log = log U[1, 16]``, ``dt_bias`` the
+    inverse softplus of a ``dt`` log-uniform in ``DT_INIT``'s range and
+    floored at its floor, ``D = 1``.  Returns (the output [B, L, h], the
+    mean of ``dt A`` as a float32 scalar)."""
+
+    num_heads: int
+    head_dim: int
+    groups: int
+    state: int
+    conv_kernel: int
+    chunk: int
+    conv_bias: bool
+    eps: float
+    out_scale: float = 1.0  # on out_proj's initial weights
+
+    @nn.compact
+    def __call__(self, x):
+        (B, L, h), H, P = x.shape, self.num_heads, self.head_dim
+        G, N, d = self.groups, self.state, self.num_heads * self.head_dim
+        f32 = jnp.float32
+        low, high, floor = DT_INIT
+
+        def inverse_softplus(dt):
+            dt = jnp.maximum(dt, floor)
+            return dt + jnp.log(-jnp.expm1(-dt))
+
+        with jax.named_scope(scopes.SSM_PROJ):
+            z, xbc, dt = jnp.split(
+                nn.Dense(2 * d + 2 * G * N + H, use_bias=False,
+                         name="in_proj")(x), [d, 2 * d + 2 * G * N], axis=-1)
+            xbc = short_causal_conv(xbc.astype(f32), self.param(
+                "conv", _conv_taps, (self.conv_kernel, d + 2 * G * N)
+            ).astype(f32))
+            if self.conv_bias:
+                xbc = xbc + self.param("conv_bias", _conv_taps,
+                                       (d + 2 * G * N,)).astype(f32)
+            u, b, c = jnp.split(jax.nn.silu(xbc), [d, d + G * N], axis=-1)
+            u = u.reshape(B, L, H, P)
+            dt = jax.nn.softplus(dt.astype(f32) + self.param(
+                "dt_bias", _log_uniform(low, high, inverse_softplus), (H,)
+            ).astype(f32))
+            A = -jnp.exp(self.param("A_log", _log_uniform(1, 16, jnp.log),
+                                    (H,)).astype(f32))
+        with jax.named_scope(scopes.SSM_SCAN):
+            y = jax.vmap(functools.partial(ssd_chunked, chunk=self.chunk),
+                         in_axes=(0, 0, None, 0, 0))(
+                u.astype(x.dtype), dt, A,
+                b.reshape(B, L, G, N).astype(x.dtype),
+                c.reshape(B, L, G, N).astype(x.dtype))
+        with jax.named_scope(scopes.SSM_OUT):
+            skip = self.param("D", nn.initializers.ones, (H,)).astype(f32)
+            y = ((y.astype(f32) + skip[:, None] * u).reshape(B, L, d)
+                 * jax.nn.silu(z.astype(f32))).reshape(B, L, G, d // G)
+            y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                                  + self.eps)
+            y = y.reshape(B, L, d) * self.param(
+                "norm", nn.initializers.ones, (d,)).astype(f32)
+            out = nn.Dense(h, use_bias=False, name="out_proj",
+                           kernel_init=scaled_init(self.out_scale))(
+                y.astype(x.dtype))
+        return out, (dt * A).mean()
+
+
 class DecoderBlock(nn.Module):
     cfg: DecoderConfig
     kind: str
@@ -918,6 +1146,12 @@ class DecoderBlock(nn.Module):
         """The layer's sequence mixer over the normed input: (its output, its
         counters)."""
         c = self.cfg
+        if self.kind == SSM:
+            y, log_decay = Mamba2Mixer(
+                c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state,
+                c.conv_kernel, c.chunk, c.ssm_conv_bias, c.rms_norm_eps,
+                c.residual_scale)(a)
+            return y, {SSM_LOG_DECAY_MEAN: log_decay}
         if self.kind == LINEAR:
             y, log_decay = LinearAttention(
                 c.linear_heads, c.linear_head_dim, c.conv_kernel, c.chunk,
@@ -929,7 +1163,8 @@ class DecoderBlock(nn.Module):
                 c.num_heads, attn_fn=scoped(attn_fn, scopes.ATTN_LATENT),
                 qkv=LatentQKV(c.num_heads, c.kv_lora_rank, c.qk_nope_head_dim,
                               c.qk_rope_head_dim, c.v_head_dim,
-                              c.rms_norm_eps, parent=None))(a), {}
+                              c.rms_norm_eps, parent=None),
+                out_scale=c.residual_scale)(a), {}
         sliding = self.kind == SLIDING
         rope = dict(dict(c.rope).get(self.kind) or {})
 
@@ -939,7 +1174,8 @@ class DecoderBlock(nn.Module):
         shared = dict(
             num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
             rope_fn=rope_fn(c.head_dim),
-            qk_norm=c.rms_norm_eps if c.qk_norm else None, gate=c.attn_gate)
+            qk_norm=c.rms_norm_eps if c.qk_norm else None, gate=c.attn_gate,
+            out_scale=c.residual_scale)
         if self.kind == SELECTED:
             return MultiHeadAttention(
                 c.num_heads, attn_fn=chosen_keys(attn_fn, c.index_topk),
@@ -954,9 +1190,9 @@ class DecoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        """A pre-norm residual sublayer for the mixer and one for the MLP;
+        a layer whose mixer or MLP is ``NONE`` has the other alone."""
         c = self.cfg
-        with jax.named_scope(scopes.NORM):
-            a = RMSNorm(c.rms_norm_eps)(x).astype(x.dtype)
 
         def after(y, name):
             """The sublayer's output through its own norm, where a block has
@@ -967,23 +1203,30 @@ class DecoderBlock(nn.Module):
                 return RMSNorm(c.rms_norm_eps, POST_NORM_INIT, name=name)(
                     y).astype(x.dtype)
 
-        y, counters = self.mixer(a)
-        x = x + after(y, "post_attn_norm")
+        counters = {}
+        if self.kind != NONE:
+            with jax.named_scope(scopes.NORM):
+                a = RMSNorm(c.rms_norm_eps)(x).astype(x.dtype)
+            y, counters = self.mixer(a)
+            x = x + after(y, "post_attn_norm")
+        if self.mlp == NONE:
+            return x, counters
         with jax.named_scope(scopes.NORM):
             b = RMSNorm(c.rms_norm_eps)(x)
         if self.mlp == DENSE:
             with jax.named_scope(scopes.MLP_DENSE):
-                y = GatedMLP(c.intermediate_size, name="mlp")(b.astype(x.dtype))
+                y = _mlp(c, c.intermediate_size, "mlp")(b.astype(x.dtype))
             return x + after(y, "post_mlp_norm"), counters
         y, routed = ExpertLayer(
             c.num_experts_routed, c.experts_held, c.top_k,
             c.moe_intermediate_size, c.norm_topk_prob, c.router_activation,
-            c.routed_scaling_factor, c.selection_bias,
+            c.routed_scaling_factor, c.selection_bias, c.mlp_act,
+            c.moe_latent_size, c.residual_scale,
         )(b)
         y = y.astype(x.dtype)
         if c.shared_expert_size:
             with jax.named_scope(scopes.MOE_SHARED):
-                y = y + GatedMLP(c.shared_expert_size, name="shared_expert")(
+                y = y + _mlp(c, c.shared_expert_size, "shared_expert")(
                     b.astype(x.dtype))
         return x + after(y, "post_mlp_norm"), {**counters, **routed}
 
@@ -1014,10 +1257,13 @@ class DecoderLM(nn.Module):
         block_cls = nn.remat(DecoderBlock) if c.remat else DecoderBlock
         totals = {}
         for i, kind in enumerate(c.layer_types):
-            h, counters = block_cls(
-                c, kind, self.attn_fn, c.mlp_types[i] if c.mlp_types else SPARSE,
-                name=f"Block_{i}")(h)
-            totals = {n: totals.get(n, 0.0) + v for n, v in counters.items()}
+            mlp = c.mlp_types[i] if c.mlp_types else SPARSE
+            h, counters = block_cls(c, kind, self.attn_fn, mlp,
+                                    name=f"Block_{i}")(h)
+            summed = {n: totals.get(n, 0.0) + v for n, v in counters.items()}
+            # a layer of one sublayer passes the other kind's sums on; a
+            # layer of two keeps only the names it counts (PERF.md §7)
+            totals = {**totals, **summed} if NONE in (kind, mlp) else summed
         if train and not self.is_initializing():
             for n, v in totals.items():
                 self.sow(COUNTERS, n, v, reduce_fn=lambda _, new: new,

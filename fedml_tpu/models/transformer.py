@@ -131,6 +131,16 @@ class RMSNorm(nn.Module):
         return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
 
 
+def scaled_init(scale: float = 1.0, init=None):
+    """``init`` (lecun-normal, ``nn.Dense``'s own, by default) times ``scale``:
+    the init of a residual branch's last projection where a configuration
+    scales it."""
+    init = init or nn.initializers.lecun_normal()
+    if scale == 1.0:
+        return init
+    return lambda key, shape, dtype=jnp.float32: init(key, shape, dtype) * scale
+
+
 class MultiHeadAttention(nn.Module):
     """One fused q/k/v projection, the attention function under ``vmap``
     over the batch, the output projection.  By default every q head has its
@@ -170,6 +180,7 @@ class MultiHeadAttention(nn.Module):
     qk_norm: Optional[float] = None
     indexer: Optional[nn.Module] = None
     gate: bool = False
+    out_scale: float = 1.0  # on the output projection's initial weights
 
     @nn.compact
     def __call__(self, x):
@@ -208,7 +219,8 @@ class MultiHeadAttention(nn.Module):
                 out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                     z.astype(jnp.float32).reshape(out.shape))).astype(x.dtype)
         with jax.named_scope(scopes.ATTN_PROJ):
-            y = nn.Dense(E, use_bias=False)(
+            y = nn.Dense(E, use_bias=False,
+                         kernel_init=scaled_init(self.out_scale))(
                 out.reshape(B, L, H * out.shape[-1]))
         return y if sums is None else (y, sums)
 

@@ -57,9 +57,13 @@ ATTN_INDEXER = "model.attn_indexer"  # a sparse layer's three index projections,
 ATTN_SELECT = "model.attn_select"  # index scores, the choice of keys, the tile table, and nothing else
 ATTN_SPARSE = "model.attn_sparse"  # the attention function of a layer whose keys are chosen: kernels or lax
 ATTN_GATE = "model.attn_gate"  # a gated attention's fourth projection, its sigmoid and the multiply of the attention output, and nothing else
+SSM_PROJ = "model.ssm_proj"  # a Mamba-2 mixer's in_proj, convolution, SiLU, splits and dt's softplus
+SSM_SCAN = "model.ssm_scan"  # ops.ssd.ssd_chunked and nothing else
+SSM_OUT = "model.ssm_out"  # the D skip, the gated group norm and out_proj
+MOE_LATENT = "model.moe_latent"  # the routed experts' down-projection to their latent width and the sum's up-projection
 
 MODEL_SCOPES = (ROPE, ATTN_SLIDING, ATTN_FULL, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_COMBINE, MOE_SHARED, MLP_DENSE, ATTN_LATENT,
                 MLA_PROJ, KDA_PROJ, KDA_GATES, KDA_SCAN, KDA_OUT, EMBED,
                 ATTN_PROJ, NORM, HEAD, ATTN_INDEXER, ATTN_SELECT, ATTN_SPARSE,
-                ATTN_GATE)
+                ATTN_GATE, SSM_PROJ, SSM_SCAN, SSM_OUT, MOE_LATENT)

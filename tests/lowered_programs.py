@@ -15,6 +15,13 @@ To take the copy again from a checkout (after a change that means to move
 the programs):
 
     JAX_PLATFORMS=cpu python tests/lowered_programs.py <checkout> <out.json>
+
+and of the families that came later (``LATER``), each pinned in a copy of its
+own from the commit before the next family came
+(``testdata/lowered_parent_pr44.json``: ``afmoe``, held by
+``test_nemotron_h_decoder.py``):
+
+    JAX_PLATFORMS=cpu python tests/lowered_programs.py <checkout> <out.json> afmoe
 """
 
 import hashlib
@@ -23,16 +30,20 @@ import os
 import sys
 
 FAMILIES = ("transformer_lm", "mellum_moe", "kimi_linear", "keye_sparse")
+LATER = ("afmoe",)
 
 
 def toy_bundle(family):
     import test_model_scopes
 
-    if family != "keye_sparse":
+    if family not in ("keye_sparse", "afmoe"):
         return test_model_scopes.toy_bundle(family)
     from fedml_tpu.models.decoder import decoder_lm
-    from test_sparse_decoder import SHARE
 
+    if family == "afmoe":
+        from test_afmoe_decoder import SHARE
+    else:
+        from test_sparse_decoder import SHARE
     return decoder_lm(SHARE)
 
 
@@ -51,7 +62,10 @@ def program_of(family) -> dict:
                      variables["params"])[0]]}
 
 
-def programs() -> dict:
+def programs(families=()) -> dict:
+    """The accepted families' programs, or those of ``families`` alone."""
+    if families:
+        return {family: program_of(family) for family in families}
     import test_attention_gate
 
     return {"multi_head_attention_ungated":
@@ -60,7 +74,7 @@ def programs() -> dict:
 
 
 if __name__ == "__main__":
-    root, dst = sys.argv[1:3]
+    root, dst, *families = sys.argv[1:]
     here = os.path.dirname(os.path.abspath(__file__))
     # the checkout's program and its toys; this file's own helpers last
     sys.path[:0] = [root, os.path.join(root, "tests"), here]
@@ -71,5 +85,5 @@ if __name__ == "__main__":
 
     jax.config.update("jax_default_matmul_precision", "highest")
     with open(dst, "w") as f:
-        json.dump(programs(), f, indent=1, sort_keys=True)
+        json.dump(programs(families), f, indent=1, sort_keys=True)
         f.write("\n")

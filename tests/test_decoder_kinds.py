@@ -365,7 +365,7 @@ def test_the_new_scopes_are_the_models_not_a_stage():
            scopes.ATTN_LATENT, scopes.MLA_PROJ, scopes.MOE_SHARED,
            scopes.MLP_DENSE}
     assert new <= set(scopes.MODEL_SCOPES) and not new & set(scopes.SCOPES)
-    assert len(set(scopes.MODEL_SCOPES)) == len(scopes.MODEL_SCOPES) == 23
+    assert len(set(scopes.MODEL_SCOPES)) == len(scopes.MODEL_SCOPES) == 27
     assert not [s for s in scopes.MODEL_SCOPES
                 if re.search(r"fed\.[a-z_]+", s)]
     # and they are in the lowered program's op names, forward and backward

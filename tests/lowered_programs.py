@@ -22,6 +22,13 @@ own from the commit before the next family came
 ``test_nemotron_h_decoder.py``):
 
     JAX_PLATFORMS=cpu python tests/lowered_programs.py <checkout> <out.json> afmoe
+
+and, from the commit before the state-space scan's kernels came
+(``testdata/lowered_parent_pr47.json``: the ``nemotron_h`` toy, whose scan's
+shape the kernels refuse, and ``ssd_chunked`` alone at such a shape, forward
+and gradients; held by ``test_ssd.py``):
+
+    JAX_PLATFORMS=cpu python tests/lowered_programs.py <checkout> <out.json> nemotron_h ssd_chunked
 """
 
 import hashlib
@@ -31,20 +38,43 @@ import sys
 
 FAMILIES = ("transformer_lm", "mellum_moe", "kimi_linear", "keye_sparse")
 LATER = ("afmoe",)
+NEWEST = ("nemotron_h", "ssd_chunked")
 
 
 def toy_bundle(family):
     import test_model_scopes
 
-    if family not in ("keye_sparse", "afmoe"):
+    if family not in ("keye_sparse", "afmoe", "nemotron_h"):
         return test_model_scopes.toy_bundle(family)
     from fedml_tpu.models.decoder import decoder_lm
 
     if family == "afmoe":
         from test_afmoe_decoder import SHARE
+    elif family == "nemotron_h":
+        from test_nemotron_h_decoder import SHARE
     else:
         from test_sparse_decoder import SHARE
     return decoder_lm(SHARE)
+
+
+def ssd_program() -> dict:
+    """{"sha256"} of ``ssd_chunked``'s lowered value and gradients at a
+    toy shape (``x`` [40, 4, 8], two groups of a 16-wide state, chunks of
+    16) that the state-space kernels do not take."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops.ssd import ssd_chunked
+
+    shapes = [((40, 4, 8), jnp.float32), ((40, 4), jnp.float32),
+              ((4,), jnp.float32)] + [((40, 2, 16), jnp.float32)] * 2
+    args = [jax.ShapeDtypeStruct(*s) for s in shapes]
+    scan = functools.partial(ssd_chunked, chunk=16)
+    lowered = jax.jit(jax.value_and_grad(
+        lambda *a: (scan(*a) ** 2).sum(), argnums=range(5))).lower(*args)
+    return {"sha256": hashlib.sha256(lowered.as_text().encode()).hexdigest()}
 
 
 def program_of(family) -> dict:
@@ -65,7 +95,8 @@ def program_of(family) -> dict:
 def programs(families=()) -> dict:
     """The accepted families' programs, or those of ``families`` alone."""
     if families:
-        return {family: program_of(family) for family in families}
+        return {family: ssd_program() if family == "ssd_chunked"
+                else program_of(family) for family in families}
     import test_attention_gate
 
     return {"multi_head_attention_ungated":

@@ -255,6 +255,50 @@ def test_the_gated_delta_rules_kernels_compile_for_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2**30
 
 
+def test_the_ssd_kernels_compile_for_v5e(one_chip, monkeypatch):
+    """The Mamba-2 scan of the ``nemo3super_silo_agent8k`` cell under the
+    model's vmap: [1, 8192, 16, 64] bf16 in chunks of 128, one group of a
+    128-wide state, forward and backward: two Mosaic kernels and no
+    ``while`` over the chunks; no [n, H, C, C] array is left in HBM, and what
+    the scan keeps for its backward is its inputs and the chunks' entry
+    states (32 MiB)."""
+    import re
+
+    from fedml_tpu.ops import ssd
+
+    L, H, P, G, N, chunk = 8192, 16, 64, 1, 128, 128
+    assert ssd.kernels_tile(N, H * P // G, P, chunk)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def grads(x, dt, A, B, C, dy):
+        return jax.grad(lambda *a: (
+            jax.vmap(lambda x, dt, B, C: ssd.ssd_chunked(x, dt, a[2], B, C,
+                                                         chunk))(
+                a[0], a[1], a[3], a[4]).astype(jnp.float32)
+            * dy.astype(jnp.float32)).sum(), argnums=range(5))(
+                x, dt, A, B, C)
+
+    spec = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    args = (spec((1, L, H, P), jnp.bfloat16), spec((1, L, H)), spec((H,)),
+            spec((1, L, G, N), jnp.bfloat16), spec((1, L, G, N), jnp.bfloat16),
+            spec((1, L, H, P), jnp.bfloat16))
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(grads).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("ssd_fwd", "ssd_bwd"):
+        assert sum("tpu_custom_call" in line and name in line
+                   for line in text.splitlines()) == 1, name
+    assert " while(" not in text
+    squares = re.findall(rf"\w+\[(?:\d+,)*{chunk},{chunk}\]", text)
+    assert not squares, sorted(set(squares))
+    dx, ddt, dA, dB, dC = jax.eval_shape(grads, *args)
+    assert (dx.dtype, ddt.shape, dA.shape, dB.dtype) == (
+        jnp.bfloat16, (1, L, H), (H,), jnp.bfloat16)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2**30
+
+
 def test_the_expert_layers_grouped_products_compile_for_v5e(one_chip):
     """``megablox.gmm`` at the decoder cell's expert shapes and the tiling
     ``gmm_tiling`` picks, forward and both gradients, with group sizes
